@@ -30,6 +30,10 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from horovod_tpu.utils.chips import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 import jax  # noqa: E402
 
 

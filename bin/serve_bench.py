@@ -86,6 +86,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np  # noqa: E402
 
+from horovod_tpu.utils.chips import enable_compile_cache  # noqa: E402
+
+# In the environment too, so subprocess replicas share the directory.
+enable_compile_cache()
+
 
 def _percentile(xs, q):
     return float(np.percentile(xs, q * 100)) if xs else float("nan")
@@ -249,8 +254,8 @@ def _bench_adapters(args, cfg):
 
     from horovod_tpu.parallel.lora import LoraConfig, init_adapter
     lora = LoraConfig(rank=args.adapter_rank)
-    trees = {f"a{i}": init_adapter(jax.random.PRNGKey(100 + i), cfg,
-                                   lora, b_scale=0.5)
+    trees = {f"a{i}": None if args.replica_procs else init_adapter(
+                 jax.random.PRNGKey(100 + i), cfg, lora, b_scale=0.5)
              for i in range(args.adapters)}
     return lora, trees
 
@@ -267,7 +272,11 @@ def _build_gen_engine(args):
     # prefill/decode interleave, streaming), not model quality.
     cfg = TransformerConfig(**_gen_model(args), dtype=jnp.float32,
                             unembed_dtype=jnp.float32, attn_backend="xla")
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    # Subprocess replicas each need a chip of their own, so their parent
+    # must stay off the jax backend: the children re-derive params (and
+    # adapters) from the spec's seeds and the parent builds none.
+    params = None if args.replica_procs else init_params(
+        jax.random.PRNGKey(0), cfg)
     slots, n_blocks, cache_bytes = _gen_capacity(args)
     gcfg = serve.GenerationConfig(
         max_slots=slots, max_len=args.max_len,
@@ -532,7 +541,9 @@ def run_gen_point(eng, qps: float, duration: float,
         "max_len": snap["max_len"],
         "cache_bytes": getattr(eng, "bench_cache_bytes", None),
         "peak_concurrent_streams": snap["peak_active_slots"],
-        "peak_bytes_per_chip": _peak_bytes_per_chip(),
+        # The children hold the chips; the parent asks no device.
+        "peak_bytes_per_chip": (None if args.replica_procs
+                                else _peak_bytes_per_chip()),
         "rejected_slots_full": snap["rejected_slots_full"],
         "rejected_blocks_exhausted": snap["rejected_blocks_exhausted"],
         "prefix_hits_total": gen["prefix_hits_total"],

@@ -21,12 +21,6 @@ echo "== unit + multi-process test suite (8-device virtual CPU mesh) =="
 # well under its 870 s cap, so the slowest tests are named on every run.
 python -m pytest tests/ -q -m 'not slow' --durations=15
 
-echo "== compat leg: pre-export all_gather_invariant resolution =="
-# The version-matrix stand-in for this single-jax image (README "Version
-# matrix"): force the private-symbol fallback utils/compat.py keeps for
-# older jax and re-run the collective sweeps that depend on it.
-HVD_COMPAT_LEVEL=private python -m pytest tests/test_collectives.py -q
-
 echo "== shrunken examples end-to-end (integration tests) =="
 run_cpu() {
   PYTHONPATH= JAX_PLATFORMS=cpu \
@@ -1131,8 +1125,6 @@ wait "$MN_PID"
 trap - EXIT
 
 echo "== driver contracts =="
-PYTHONPATH= JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-  python __graft_entry__.py
 HVD_BENCH_SMOKE=1 PYTHONPATH= JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8 python bench.py
 HVD_BENCH_SMOKE=1 PYTHONPATH= JAX_PLATFORMS=cpu \

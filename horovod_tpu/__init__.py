@@ -18,11 +18,6 @@ plane (multi-process) eagerly. See ``runtime.py`` and ``ops/``.
 
 from .version import __version__  # noqa: F401
 
-# Imported for its side effects FIRST: grafts newer-jax API spellings
-# (jax.shard_map, lax.axis_size, pltpu.CompilerParams) onto older jax
-# installs before any framework module references them.
-from .utils import compat as _compat  # noqa: F401
-
 from .runtime import (  # noqa: F401
     AXIS,
     init,

@@ -3,7 +3,7 @@
 This is the TPU-native analog of the reference's background-thread MPI
 negotiation (``BackgroundThreadLoop``, ``mpi_ops.cc:1248-1512``): name-keyed
 Request/Response messages to a rank-0 coordinator over DCN/TCP, cross-rank
-validation with the reference's error taxonomy (``ConstructMPIResponse``,
+validation with the reference's error classification (``ConstructMPIResponse``,
 ``mpi_ops.cc:266-474``), stall detection, tensor-fusion response batching and
 host-side execution of eager op-at-a-time collectives. The native core lives
 in ``coordinator.cc`` (built lazily into ``libhvdcoord.so``); this module is
@@ -18,6 +18,7 @@ mesh) span processes via XLA itself.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import socket
 import struct
@@ -173,10 +174,19 @@ _DTYPES = {
 def _build_and_load() -> ctypes.CDLL:
     here = os.path.dirname(os.path.abspath(__file__))
     so = os.path.join(here, "libhvdcoord.so")
-    src = os.path.join(here, "coordinator.cc")
-    if not os.path.exists(so) or (
-            os.path.exists(src)
-            and os.path.getmtime(src) > os.path.getmtime(so)):
+    stamp = os.path.join(here, "libhvdcoord.src.sha256")
+    with open(os.path.join(here, "coordinator.cc"), "rb") as f:
+        want = hashlib.sha256(f.read()).hexdigest()
+
+    def _stale() -> bool:
+        # Keyed by the source's CONTENT, not mtimes: after a copy or a
+        # checkout a stale binary can look newer than coordinator.cc.
+        if not (os.path.exists(so) and os.path.exists(stamp)):
+            return True
+        with open(stamp) as f:
+            return f.read().strip() != want
+
+    if _stale():
         # Concurrently launched ranks all reach this on a fresh checkout;
         # serialize the build with an exclusive lock so nobody dlopens a
         # half-written .so.
@@ -184,11 +194,14 @@ def _build_and_load() -> ctypes.CDLL:
         with open(os.path.join(here, ".build.lock"), "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
             try:
-                if not os.path.exists(so) or (
-                        os.path.exists(src)
-                        and os.path.getmtime(src) > os.path.getmtime(so)):
+                if _stale():
+                    subprocess.run(["make", "-C", here, "clean"],
+                                   check=True, capture_output=True,
+                                   text=True)
                     subprocess.run(["make", "-C", here], check=True,
                                    capture_output=True, text=True)
+                    with open(stamp, "w") as f:
+                        f.write(want + "\n")
             finally:
                 fcntl.flock(lock, fcntl.LOCK_UN)
     lib = ctypes.CDLL(so)

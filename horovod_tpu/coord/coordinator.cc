@@ -3,7 +3,7 @@
 // TPU-native analog of the reference's native runtime
 // (horovod/tensorflow/mpi_ops.cc): a rank-0 coordinator counts name-keyed
 // collective announcements from every rank, validates them across ranks with
-// the same error taxonomy (ConstructMPIResponse, mpi_ops.cc:266-474), detects
+// the same error classification (ConstructMPIResponse, mpi_ops.cc:266-474), detects
 // stalls (CheckForStalledTensors, mpi_ops.cc:1153-1196), plans tensor fusion
 // (mpi_ops.cc:1395-1422) and executes the *eager host data plane* — the
 // op-at-a-time collectives issued outside compiled XLA programs (metric
@@ -1244,7 +1244,7 @@ class Coordinator {
   }
 
   // ConstructMPIResponse parity (mpi_ops.cc:266-474): cross-rank validation
-  // with the reference's error taxonomy, then host execution.
+  // with the reference's error classification, then host execution.
   Response BuildResponse(const std::string& name) {
     auto it = table_.find(name);
     auto requests = std::move(it->second.requests);

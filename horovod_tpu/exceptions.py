@@ -1,4 +1,4 @@
-"""Error taxonomy.
+"""Error classification.
 
 Mirrors the reference's error surface:
 
@@ -95,7 +95,7 @@ class ServerOverloadedError(HorovodError):
     the serving backpressure contract (:mod:`horovod_tpu.serve`). Callers
     should treat it as retryable after backoff (HTTP 503 semantics; the
     bundled HTTP front end maps it exactly there). The reference has no
-    serving plane; this extends the taxonomy the same way
+    serving plane; this extends the classification the same way
     :class:`StalledError` extends the collective plane.
     """
 

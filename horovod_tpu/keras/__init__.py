@@ -179,8 +179,8 @@ def DistributedOptimizer(optimizer, *, average: bool = True,
                     return list(outs)
             elif backend == "jax":
                 import jax as _jax
-                import jax.core as _jcore
-                if any(isinstance(g, _jcore.Tracer) for g in grads):
+                from ..utils.compat import is_tracer
+                if any(is_tracer(g) for g in grads):
                     out_shapes = tuple(
                         _jax.ShapeDtypeStruct(g.shape, g.dtype)
                         for g in grads)

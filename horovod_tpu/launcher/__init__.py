@@ -33,6 +33,8 @@ import sys
 import time
 from typing import List, Optional
 
+from ..utils.chips import chips_on_host, one_chip_env
+
 # How long a worker gets between terminate() and kill() during teardown —
 # enough for JAX runtimes to flush, short enough that a wedged worker
 # cannot hold the job hostage.
@@ -55,26 +57,60 @@ def _free_port() -> int:
     return port
 
 
-def _chips_per_host() -> int:
-    """Local chip count (local_rank domain — the analog of
-    MPI_Comm_split_type(SHARED) sizing, mpi_ops.cc:1263-1267).
+def _local_rank(index: int, *, cpu: bool, cpu_world: int) -> int:
+    """Local rank of the ``index``-th process this host runs. On chips it
+    names the process's OWN chip, so it never wraps: more processes than
+    chips is refused (two ranks on one chip would fight over its lock)."""
+    if cpu:
+        return index % max(1, cpu_world)
+    try:
+        chips = chips_on_host()
+    except RuntimeError as e:
+        raise SystemExit(f"tpurun: {e}")
+    if index >= chips:
+        raise SystemExit(
+            f"tpurun: process {index} of this host needs its own chip but "
+            f"the host has {chips} (one process per chip; --cpu lifts "
+            f"this for CPU worlds)")
+    return index
 
-    Deliberately does NOT import jax: initializing a TPU backend in the
-    launcher would hold the chips and every spawned rank would fail with
-    "TPU already in use". Count device nodes instead.
-    """
-    import glob
-    override = os.environ.get("HVD_CHIPS_PER_HOST")
-    if override:
-        try:
-            return max(1, int(override))
-        except ValueError:
-            pass
-    for pattern in ("/dev/accel*", "/dev/vfio/[0-9]*"):
-        n = len(glob.glob(pattern))
-        if n:
-            return n
-    return 1
+
+def _rank_env(rank: int, local_rank: int, world: int, coord_addr: str, *,
+              cpu: bool, extra_env: Optional[dict] = None,
+              metrics_port: Optional[int] = None, restart_epoch: int = 0,
+              resize_generation: int = 0,
+              jd_addr: Optional[str] = None) -> dict:
+    """Environment of one spawned rank: the process grid, the rendezvous
+    address, and — on chips — exactly ONE chip, keyed by ``local_rank``."""
+    env = dict(os.environ)
+    env.update(extra_env or {})
+    if metrics_port:
+        # Each rank's obs listener binds metrics_port + rank
+        # (horovod_tpu.obs.http); the flag is the launcher-side
+        # spelling of HVD_METRICS_PORT.
+        env["HVD_METRICS_PORT"] = str(metrics_port)
+    env["HVD_RANK"] = str(rank)
+    env["HVD_SIZE"] = str(world)
+    env["HVD_LOCAL_RANK"] = str(local_rank)
+    env["HVD_COORD_ADDR"] = coord_addr
+    # Which (re)launch of the world this is; read by the elastic
+    # recovery API and the fault injector's @epoch condition.
+    env["HVD_RESTART_EPOCH"] = str(restart_epoch)
+    if resize_generation:
+        # Grow-spawned mid-resize: the rank joins the in-flight world
+        # over the wire (elastic.resize_join) instead of restoring.
+        env["HVD_RESIZE_GENERATION"] = str(resize_generation)
+    if cpu:
+        # CPU testing mode (reference CI: mpirun -np 2 on localhost
+        # CPU-only, .travis.yml:84-91).
+        env["JAX_PLATFORMS"] = "cpu"
+    else:
+        env.update(one_chip_env(local_rank))
+    if jd_addr:
+        env["JAX_COORDINATOR_ADDRESS"] = jd_addr
+        env["JAX_NUM_PROCESSES"] = str(world)
+        env["JAX_PROCESS_ID"] = str(rank)
+    return env
 
 
 def _reap(procs: List[subprocess.Popen],
@@ -424,46 +460,23 @@ def _launch_once(np_: int, command: List[str], *,
     fleet_stop = None   # set below; the finally must see it even when
     fleet_world = {"w": world}   # the spawn loop raises first
 
-    def _rank_env(rank: int, cur_world: int, addr: str,
-                  resize_generation: int = 0) -> dict:
-        env = dict(os.environ)
-        env.update(extra_env or {})
-        if metrics_port:
-            # Each rank's obs listener binds metrics_port + rank
-            # (horovod_tpu.obs.http); the flag is the launcher-side
-            # spelling of HVD_METRICS_PORT.
-            env["HVD_METRICS_PORT"] = str(metrics_port)
-        env["HVD_RANK"] = str(rank)
-        env["HVD_SIZE"] = str(cur_world)
-        env["HVD_LOCAL_RANK"] = str(
-            rank % max(1, _chips_per_host() if not cpu else cur_world))
-        env["HVD_COORD_ADDR"] = addr
-        # Which (re)launch of the world this is; read by the elastic
-        # recovery API and the fault injector's @epoch condition.
-        env["HVD_RESTART_EPOCH"] = str(restart_epoch)
-        if resize_generation:
-            # Grow-spawned mid-resize: the rank joins the in-flight world
-            # over the wire (elastic.resize_join) instead of restoring.
-            env["HVD_RESIZE_GENERATION"] = str(resize_generation)
-        if cpu:
-            # CPU testing mode (reference CI: mpirun -np 2 on localhost
-            # CPU-only, .travis.yml:84-91).
-            env["JAX_PLATFORMS"] = "cpu"
-        if jax_distributed:
-            env["JAX_COORDINATOR_ADDRESS"] = jd_addr
-            env["JAX_NUM_PROCESSES"] = str(cur_world)
-            env["JAX_PROCESS_ID"] = str(rank)
-        return env
+    def _spawn(rank: int, index: int, cur_world: int, addr: str,
+               cpu_world: int, resize_generation: int = 0):
+        procs[rank] = subprocess.Popen(command, env=_rank_env(
+            rank, _local_rank(index, cpu=cpu, cpu_world=cpu_world),
+            cur_world, addr, cpu=cpu, extra_env=extra_env,
+            metrics_port=metrics_port, restart_epoch=restart_epoch,
+            resize_generation=resize_generation,
+            jd_addr=jd_addr if jax_distributed else None))
 
     try:
-        for local_rank in range(np_):
-            rank = node_rank * np_ + local_rank
-            env = _rank_env(rank, world, coord_addr)
-            # Preserve the historical local_rank derivation for the
-            # initial spawn (rank-block layout across nodes).
-            env["HVD_LOCAL_RANK"] = str(
-                local_rank % max(1, _chips_per_host() if not cpu else np_))
-            procs[rank] = subprocess.Popen(command, env=env)
+        # Refuse more processes than chips before any rank is spawned.
+        _local_rank(np_ - 1, cpu=cpu, cpu_world=np_)
+        for index in range(np_):
+            # Rank-block layout across nodes; the local rank is the
+            # process's index on THIS node.
+            _spawn(node_rank * np_ + index, index, world, coord_addr,
+                   cpu_world=np_)
 
         # Supervision loop: any-order exit detection + resize supervision.
         resize = _ResizeSupervisor(
@@ -537,9 +550,8 @@ def _launch_once(np_: int, command: List[str], *,
                     f"tpurun: live grow — spawning rank {rank} into world "
                     f"{target} (generation {gen}, coordinator "
                     f"{addr})\n")
-                procs[rank] = subprocess.Popen(
-                    command, env=_rank_env(rank, target, addr,
-                                           resize_generation=gen))
+                _spawn(rank, rank, target, addr, cpu_world=target,
+                       resize_generation=gen)
             for r in resize.drain_reap():
                 # Spawned for a resize that was abandoned: never joined a
                 # world, so terminate and forget — their connect-timeout

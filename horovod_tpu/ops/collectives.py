@@ -33,7 +33,7 @@ Two execution contexts, one API:
    replicated/host inputs mean every rank contributes the same value. In
    multi-process mode the host coordination plane (``horovod_tpu.coord``)
    additionally validates name-keyed requests across processes, with the
-   reference's exact error taxonomy (``ConstructMPIResponse``,
+   reference's exact error classification (``ConstructMPIResponse``,
    ``mpi_ops.cc:266-474``).
 """
 
@@ -51,7 +51,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import runtime
 from ..runtime import AXIS
-from ..utils.compat import all_gather_invariant
+from ..utils.compat import all_gather_invariant, is_tracer
 
 
 class Op(enum.Enum):
@@ -328,7 +328,7 @@ def allgather_ragged(tensor, valid_size, max_size: int,
             f"Mismatched ALLGATHER tensor shapes: tensor has {n} rows but "
             f"max_size is {max_size}; allgather_ragged cannot truncate "
             f"(grow max_size or slice the input)")
-    if not isinstance(valid_size, jax.core.Tracer):
+    if not is_tracer(valid_size):
         vs = int(valid_size)
         if not 0 <= vs <= max_size:
             raise ValueError(

@@ -36,12 +36,13 @@ import math
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 
 from ..runtime import AXIS
 from ..utils import config as _config
-from ..utils.compat import all_gather_invariant, axis_size
+from ..utils.compat import all_gather_invariant
 from .collectives import Op, _reduce_in_trace
 
 
@@ -227,9 +228,9 @@ def probe_grad_order(grad_fn, *args, **kwargs) -> Optional[Tuple[int, ...]]:
     def _rank(k):
         v = outvars[k]
         # Literal outvars (e.g. the zero cotangent of a leaf the loss never
-        # reads) are unhashable on older jax — they take the flatten-order
-        # fallback, same as any other unrankable leaf.
-        if not isinstance(v, jax.core.Var):
+        # reads) take the flatten-order fallback, same as any other
+        # unrankable leaf.
+        if not isinstance(v, jax.extend.core.Var):
             return (-1, k)
         return (pos.get(v, -1), k)
 
@@ -398,7 +399,7 @@ def _wire_sum(flat, axis_names, wire, prescale=None):
     world = 1
     for a in ((axis_names,) if isinstance(axis_names, str)
               else tuple(axis_names)):
-        world *= int(axis_size(a))
+        world *= int(jax.lax.axis_size(a))
     return _wire_exchange(
         flat, axis_names, wire, world,
         lambda w: jax.lax.psum(w, axis_names), prescale=prescale)
@@ -736,7 +737,7 @@ def fused_allreduce(tree, average: bool = True,
         if _wire_applies(operand.dtype, wire):
             eff = prescale
             if op is Op.AVERAGE:
-                inv = 1.0 / int(axis_size(axis_name))
+                inv = 1.0 / int(jax.lax.axis_size(axis_name))
                 eff = inv if eff is None else eff * inv
             r = _wire_sum(operand, axis_name, wire, prescale=eff)
         else:

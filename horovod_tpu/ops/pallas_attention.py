@@ -25,12 +25,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_Q = 128    # minimum tile (tilability floor)
 BLOCK_K = 128
@@ -50,11 +46,13 @@ def _pick_block(t: int, want: int) -> int:
     return b
 
 
-def _grid_params(semantics):
+def _grid_params(semantics, vmem_limit_bytes=None):
     """dimension_semantics lets Mosaic pipeline HBM tile copies against
     compute across grid steps — without it every step stalls on its loads
-    (measured ~4x on the backward at T=2048)."""
-    return pltpu.CompilerParams(dimension_semantics=semantics)
+    (measured ~4x on the backward at T=2048). ``vmem_limit_bytes`` pins
+    the kernel's scoped-VMEM limit (None = the compiler's default)."""
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _causal_run(qi, kb, bq, bk):
@@ -384,8 +382,10 @@ def _dqkv_packed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dqkv_ref[0, :, 2 * d:3 * d] = dv_acc[:].astype(dqkv_ref.dtype)
 
 
-# Per-core VMEM the fused backward may claim (v4/v5 generations carry
-# ~16 MiB/core; override for parts that differ). Read once at import so
+# Scoped VMEM one fused-backward kernel may claim. 16 MiB is the v5e
+# compiler's own default scoped limit; the fused kernels also pass this
+# number as their ``vmem_limit_bytes``, so the gate below and the compiler
+# hold the same limit (an override moves both). Read once at import so
 # every rank traces the same graph — a trace-time env read could diverge
 # across ranks (the HVD_FUSED_PARTS lesson, ADVICE r5).
 _VMEM_BUDGET_BYTES = int(os.environ.get("HVD_VMEM_BUDGET_MB", "16")) * 2**20
@@ -393,27 +393,33 @@ _VMEM_BUDGET_BYTES = int(os.environ.get("HVD_VMEM_BUDGET_MB", "16")) * 2**20
 
 def _fused_bwd_fits(T: int, D: int, itemsize: int, *, bq: int, bk: int,
                     packed: bool) -> bool:
-    """Whether the fused single-pass backward's VMEM residents fit the
-    per-core budget — the gate deciding fused vs split dq/dkv kernels.
+    """Whether the fused single-pass backward fits the kernel's scoped
+    VMEM limit — the gate deciding fused vs split dq/dkv kernels.
 
     The fused kernel's full-T dk/dv accumulators make its footprint grow
-    with sequence length, so a static T ceiling (the old
-    ``_FUSED_BWD_MAX_T = 8192``, sized for D=128 bf16) admitted shapes
-    that failed to compile at larger D or f32 and rejected small-D shapes
-    that fit fine. Summing the actual residents instead:
+    with sequence length. The sum below is what the v5e compiler
+    allocates, checked against it at H=16 for D in {128, 256}, bf16 and
+    f32, T = 128..16384 (the smallest ``vmem_limit_bytes`` that compiles
+    sits within the margin of this sum; ``tests/test_tpu_compile.py``
+    keeps the near-boundary shapes compiling):
 
     * scratch: dq_acc [bq, D] + dk/dv accumulators 2×[T, D], all f32;
-    * output block(s), grid-constant so VMEM-resident for a whole
+    * output block(s) in the input dtype, DOUBLE-buffered like every
+      pipelined operand even though they are grid-constant over a
       (batch, head) visit: packed [T, 3D] vs split dq [bq, D] + full-T
-      dk/dv 2×[T, D], in the input dtype;
-    * streamed input tiles (q/do [bq, D], k/v [bk, D], two [bq, lanes]
-      f32 stat tiles), doubled — Mosaic double-buffers pipelined streams.
+      dk/dv 2×[T, D];
+    * streamed input tiles (q/do [bq, D], k/v [bk, D]) and the two f32
+      stat tiles, which occupy a full 128-lane tile in VMEM whatever
+      ``_STAT_LANES`` says — all double-buffered;
+    * margin: three [bq, bk] f32 intermediates (scores/probabilities, dp,
+      ds) the compiler keeps on its stack.
     """
     scratch = 4 * (bq * D + 2 * T * D)
     out = (T * 3 * D if packed else (bq + 2 * T) * D) * itemsize
     tiles = ((2 * bq + 2 * bk) * D * itemsize
-             + 2 * bq * _STAT_LANES * 4)
-    return scratch + out + 2 * tiles <= _VMEM_BUDGET_BYTES
+             + 2 * bq * 128 * 4)
+    margin = 3 * bq * bk * 4
+    return scratch + 2 * out + 2 * tiles + margin <= _VMEM_BUDGET_BYTES
 
 
 # Lane width of the per-row stat tensors (lse, delta) on the wire between
@@ -527,7 +533,8 @@ def _flash_core_bwd(causal, interpret, res, do):
                             pltpu.VMEM((T, D), jnp.float32),
                             pltpu.VMEM((T, D), jnp.float32)],
             compiler_params=_grid_params(
-                ("parallel", "arbitrary", "arbitrary")),
+                ("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_BUDGET_BYTES),
             interpret=interpret,
         )(q, k, v, do, lse, delta)
     dq = pl.pallas_call(
@@ -680,7 +687,8 @@ def _flash_qkv_core_bwd(H, causal, sm_scale, interpret, res, do):
                             pltpu.VMEM((T, D), jnp.float32),
                             pltpu.VMEM((T, D), jnp.float32)],
             compiler_params=_grid_params(
-                ("parallel", "parallel", "arbitrary", "arbitrary")),
+                ("parallel", "parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_BUDGET_BYTES),
             interpret=interpret,
         )(qkv, qkv, qkv, do, lse, delta)
         return (d_qkv,)
@@ -738,8 +746,7 @@ _flash_qkv_core.defvjp(_flash_qkv_core_fwd, _flash_qkv_core_bwd)
 
 def qkv_flash_tilable(T: int, d_head: int) -> bool:
     """Whether the packed-qkv kernel path tiles these dims."""
-    return (_HAS_PALLAS and T % BLOCK_Q == 0 and T % BLOCK_K == 0
-            and d_head % 128 == 0)
+    return T % BLOCK_Q == 0 and T % BLOCK_K == 0 and d_head % 128 == 0
 
 
 def flash_attention_qkv(qkv, n_heads: int, *, causal: bool = False,

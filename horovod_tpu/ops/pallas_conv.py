@@ -49,12 +49,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas ships with jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Rows of [M, C] processed per grid step. 1024 amortizes Mosaic's per-step
 # overhead while keeping the worst-case working set (stage-2 convC,
@@ -75,7 +71,7 @@ def _pick_rows(m: int, want: int = _WANT_BM) -> int:
 
 def fusable(m: int) -> bool:
     """Whether the fused kernel tiles an [M, C] problem (M = N*H*W)."""
-    return _HAS_PALLAS and m % 128 == 0
+    return m % 128 == 0
 
 
 def _fwd_kernel(x_ref, w_ref, ab_ref, y_ref, s1_ref, s2_ref, *,

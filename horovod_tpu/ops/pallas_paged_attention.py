@@ -3,25 +3,28 @@ training flash kernels in :mod:`.pallas_attention`.
 
 One query token per slot attends over that slot's KV *blocks*, gathered
 directly from the paged pool via the block table: the grid walks
-``(slot, head, logical_block)`` and a scalar-prefetched block table
-resolves each logical block to its physical pool index INSIDE the
-BlockSpec index map — the kernel never materializes the per-slot
+``(slot, logical_block)`` and a scalar-prefetched block table resolves
+each logical block to its physical pool index INSIDE the BlockSpec index
+map — the kernel never materializes the per-slot
 ``[max_blocks·block_size, H, dh]`` contiguous view the pure-lax fallback
 gathers (at real configs that view is the whole cache re-laid-out per
-step; the kernel streams exactly the blocks each slot owns). Online
-softmax (running max/sum, fp32 accumulation) across the block axis,
+step; the kernel streams exactly the blocks each slot owns). Each program
+takes ALL heads of one block: the q/out block is ``[H, d]`` and the K/V
+block ``[bs, H, d]``, so the last two block dims are the arrays' full
+``(H, d)`` extent — the TPU's (8, 128) tiling rule for block shapes — and
+one K/V fetch is a contiguous ``bs·H·d`` run of the pool. Online softmax
+(running max/sum per head, fp32 accumulation) across the block axis,
 per-slot length masking, blocks past the slot's position skipped
 entirely.
 
-Gating discipline mirrors ``pallas_attention``'s ``_fused_bwd_fits``
-pattern: the engine flips the kernel on only when
-:func:`paged_attention_supported` says the shapes tile on the running
-backend (``d_head`` a lane multiple on real TPUs; anything goes in
-interpreter mode), and the pure-lax gather fallback — the
-bit-identity-bearing reference — keeps the whole stack green everywhere
-else. :func:`paged_attention_reference` IS that fallback's math;
-``tests/test_paged_kv.py`` pins kernel-vs-reference allclose on CPU
-(interpret mode executes the same kernel program the TPU would run).
+The engine turns the kernel on only when asked (``paged_kernel=True``)
+and REFUSES at construction when :func:`paged_attention_supported` says
+the shapes cannot run on the backend (``d_head`` a lane multiple on real
+TPUs; anything goes in interpreter mode). The pure-lax gather path is
+the bit-identity-bearing reference; :func:`paged_attention_reference` IS
+its math. ``tests/test_paged_kv.py`` pins kernel-vs-reference allclose on
+CPU (interpret mode executes the same kernel program the TPU would run)
+and ``tests/test_tpu_compile.py`` compiles it for the described v5e.
 """
 
 from __future__ import annotations
@@ -31,41 +34,38 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def paged_attention_supported(d_head: int, block_size: int,
                               interpret: Optional[bool] = None) -> bool:
     """Whether the kernel path runs these shapes: interpreter mode (CPU
-    tests) takes anything; a real TPU needs lane-aligned ``d_head`` and
-    a sublane-aligned block so Mosaic can tile the K/V blocks."""
-    if not _HAS_PALLAS:
-        return False
+    tests) takes anything; a real TPU needs a lane-aligned ``d_head``
+    (heads and block rows ride whole in every block, so neither is
+    constrained)."""
+    del block_size  # whole blocks per program: any size tiles
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if interpret:
         return True
-    return d_head % 128 == 0 and block_size % 8 == 0
+    return d_head % 128 == 0
 
 
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, bs: int, scale: float):
-    """Grid (slot, head, logical_block): one [1, d] query row against one
-    [bs, d] K/V block (resolved physical by the index maps). Softmax
-    state (acc/m/l) persists in scratch across the block axis; blocks
-    entirely past the slot's position — and every block of an inactive
-    (position < 0) slot — skip all compute, and the normalized output is
-    written at the last block step (zeros for a fully-masked row, via
-    the safe divide)."""
+    """Grid (slot, logical_block): the slot's [H, d] query rows against
+    one [bs, H, d] K/V block (resolved physical by the index maps).
+    Everything stays in the [H, d] tile layout — scores are a lane
+    reduction kept as [bs, H, 1], probabilities broadcast back over the
+    lanes — so no in-kernel transpose is needed. Softmax state (acc/m/l)
+    persists in scratch across the block axis; blocks entirely past the
+    slot's position — and every block of an inactive (position < 0) slot
+    — skip all compute, and the normalized output is written at the last
+    block step (zeros for a fully-masked row, via the safe divide)."""
     s = pl.program_id(0)
-    b = pl.program_id(2)
-    n_b = pl.num_programs(2)
+    b = pl.program_id(1)
+    n_b = pl.num_programs(1)
 
     @pl.when(b == 0)
     def _init():
@@ -77,27 +77,23 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when((pos >= 0) & (b * bs <= pos))
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale            # [1, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # [bs, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [1, bs]
-        kpos = b * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        q = q_ref[0].astype(jnp.float32) * scale            # [H, d]
+        k = k_ref[0].astype(jnp.float32)                    # [bs, H, d]
+        v = v_ref[0].astype(jnp.float32)
+        sc = jnp.sum(q[None] * k, axis=-1, keepdims=True)   # [bs, H, 1]
+        kpos = b * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
         sc = jnp.where(kpos <= pos, sc, -1e30)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(sc))
-        p = jnp.exp(sc - m_new)                             # [1, bs]
+        m_prev = m_ref[:]                                   # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
+        p = jnp.exp(sc - m_new[None])                       # [bs, H, 1]
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[0, 0] = l_ref[0, 0] * alpha + jnp.sum(p)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [1, d]
-        m_ref[0, 0] = m_new
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.sum(p * v, axis=0)  # [H, d]
+        m_ref[:] = m_new
 
     @pl.when(b == n_b - 1)
     def _finish():
-        l = l_ref[0, 0]
+        l = l_ref[:]
         safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[:] / safe).astype(o_ref.dtype)
 
@@ -108,28 +104,29 @@ def _paged_call(q, k_pool, v_pool, block_tables, positions,
     S, H, d = q.shape
     bs = k_pool.shape[1]
     nb = block_tables.shape[1]
+    kv_spec = pl.BlockSpec((1, bs, H, d),
+                           lambda s, b, tbl, pos: (tbl[s, b], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, H, nb),
+        grid=(S, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda s, h, b, tbl, pos: (s, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, h, b, tbl, pos: (tbl[s, b], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, h, b, tbl, pos: (tbl[s, b], 0, h, 0)),
+            pl.BlockSpec((1, H, d), lambda s, b, tbl, pos: (s, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda s, h, b, tbl, pos: (s, h, 0)),
+        out_specs=pl.BlockSpec((1, H, d), lambda s, b, tbl, pos: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),        # acc
-            pltpu.SMEM((1, 1), jnp.float32),        # running max
-            pltpu.SMEM((1, 1), jnp.float32),        # running sum
+            pltpu.VMEM((H, d), jnp.float32),        # acc
+            pltpu.VMEM((H, 1), jnp.float32),        # running max
+            pltpu.VMEM((H, 1), jnp.float32),        # running sum
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, bs=bs, scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, positions, q, k_pool, v_pool)
 
@@ -155,10 +152,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, positions, *,
     :func:`paged_attention_reference`.
     """
     S, H, d = q.shape
-    if not _HAS_PALLAS:
-        raise RuntimeError(
-            "paged_decode_attention needs jax.experimental.pallas; use "
-            "the pure-lax fallback (kernel=False) on this build")
     if sm_scale is None:
         sm_scale = float(d) ** -0.5
     if interpret is None:
@@ -166,9 +159,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, positions, *,
     if not paged_attention_supported(d, k_pool.shape[1],
                                      interpret=interpret):
         raise ValueError(
-            f"paged_decode_attention needs d_head%128==0 and "
-            f"block_size%8==0 on TPU; got d_head={d}, "
-            f"block_size={k_pool.shape[1]} (use the lax gather fallback)")
+            f"paged_decode_attention needs d_head%128==0 on TPU; got "
+            f"d_head={d} (use the lax gather path, kernel=False)")
     return _paged_call(q, k_pool, v_pool,
                        jnp.asarray(block_tables, jnp.int32),
                        jnp.asarray(positions, jnp.int32),

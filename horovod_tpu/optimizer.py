@@ -52,6 +52,7 @@ from .ops.fusion import (ZeroPlan, fused_allgather_params, fused_allreduce,
 from .runtime import AXIS
 from .ops.sparse import IndexedSlices, allreduce_indexed_slices
 from .utils import config as _config
+from .utils.compat import is_tracer
 
 
 def _is_sparse_leaf(x) -> bool:
@@ -263,10 +264,9 @@ def zero_from_canonical(canonical: Any,
 def _axes_bound(names) -> bool:
     """True when every named mesh axis is bound in the current trace —
     the generalization of ``runtime._in_world_trace`` to hybrid meshes."""
-    from .utils.compat import axis_size as _axsz
     try:
         for n in ((names,) if isinstance(names, str) else tuple(names)):
-            _axsz(n)
+            jax.lax.axis_size(n)
         return True
     except Exception:  # noqa: BLE001 — unbound axis raises NameError-ish
         return False
@@ -360,7 +360,7 @@ def partition_optimizer(optimizer: optax.GradientTransformation,
                          mesh=mesh, scatter_axis=scatter_axis,
                          skip_axes=skip_axes)
         leaves = plan.treedef.flatten_up_to(params)
-        if any(isinstance(l, jax.core.Tracer) for l in leaves):
+        if any(is_tracer(l) for l in leaves):
             raise ValueError(
                 "hybrid ZeRO state must be initialized eagerly (the "
                 "stacked shard layout is assembled host-side from the "
@@ -404,7 +404,7 @@ def partition_optimizer(optimizer: optax.GradientTransformation,
             else:
                 arr = jnp.reshape(flat, (n, s))
                 if (runtime.is_initialized() and n > 1
-                        and not isinstance(arr, jax.core.Tracer)):
+                        and not is_tracer(arr)):
                     # Place the stacked shards split over the world mesh
                     # up front: each device holds 1/N of every
                     # optimizer-state array from step 0.
@@ -427,7 +427,7 @@ def partition_optimizer(optimizer: optax.GradientTransformation,
             shard_shapes = set(plan.shard_shapes())
             rep = NamedSharding(runtime.mesh(), P())
             inner = jax.tree_util.tree_map(
-                lambda l: l if (isinstance(l, jax.core.Tracer)
+                lambda l: l if (is_tracer(l)
                                 or tuple(np.shape(l)) in shard_shapes)
                 else jax.device_put(l, rep), inner)
         return ZeroShardedState(inner=inner, plan=plan)
@@ -453,8 +453,7 @@ def partition_optimizer(optimizer: optax.GradientTransformation,
                 "mesh=, param_specs=)), or use the env-world plane "
                 "which drives the exchange from the host")
         if _axes_bound(axis):
-            from .utils.compat import axis_size
-            world = int(axis_size(axis))
+            world = int(jax.lax.axis_size(axis))
             if world != plan.nshards:
                 raise ValueError(
                     f"optimizer state was partitioned for a world of "
@@ -530,7 +529,7 @@ def _hybrid_allreduce_optimizer(optimizer, *, mesh, param_specs, skip_axes,
         specs = _specs_for(params)
 
         def _place(leaf, spec):
-            if isinstance(leaf, jax.core.Tracer):
+            if is_tracer(leaf):
                 return leaf
             return jax.device_put(jnp.asarray(leaf),
                                   NamedSharding(mesh, spec))

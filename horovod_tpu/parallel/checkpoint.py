@@ -612,6 +612,14 @@ def restore_for_inference(directory: str, step: Optional[int] = None, *,
         raise CheckpointCorruptError(
             path, f"unreadable checkpoint metadata: "
                   f"{type(e).__name__}: {e}") from e
+    # orbax's metadata() returns a StepMetadata whose item_metadata.tree
+    # is the stored structure; it swallows a read failure into
+    # item_metadata=None (after logging), which here is corruption.
+    if meta.item_metadata is None:
+        raise CheckpointCorruptError(
+            path, "unreadable checkpoint metadata (orbax found no item "
+                  "metadata it could read)")
+    meta = meta.item_metadata.tree
     if "params" not in meta:
         raise ValueError(
             f"{path} has no 'params' subtree — not a checkpoint this "

@@ -125,8 +125,20 @@ def init(devices: Optional[Sequence[jax.Device]] = None,
             controller_rank = process_index
             # 1 process = 1 chip (README.md:62-64): the local mesh is this
             # rank's own device; cross-rank exchange rides the host plane.
+            # tpurun pins exactly one chip to each rank (its
+            # TPU_VISIBLE_CHIPS environment): a rank that sees several
+            # would share them with its siblings, so that is an error,
+            # not something to index into. Virtual CPU devices (--cpu
+            # worlds under a forced host device count) are
+            # interchangeable stand-ins and any one will do.
             local = jax.local_devices()
-            own = local[_config.launcher_local_rank(default=0) % len(local)]
+            if len(local) != 1 and local[0].platform != "cpu":
+                raise RuntimeError(
+                    f"env-world rank {process_index} sees {len(local)} "
+                    f"local {local[0].platform} devices; one process "
+                    f"drives one chip — launch through tpurun, which "
+                    f"gives each rank its own chip")
+            own = local[0]
             mesh = Mesh(np.array([own]), (AXIS,))
         else:
             # Controller rank: global index of the first device owned by
@@ -224,8 +236,7 @@ def _maybe_init_jax_distributed() -> None:
         return
     # NB: do NOT probe jax.process_count() here — it would initialize the
     # backend single-process and make distributed init impossible.
-    from .utils.compat import jax_distributed_is_initialized
-    if jax_distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return
     try:
         jax.distributed.initialize(coordinator_address=addr,

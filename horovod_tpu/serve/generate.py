@@ -147,9 +147,11 @@ class GenerationConfig:
     the contiguous footprint (``max_slots · ceil(max_len/block_size) +
     1``). ``prefix_reuse`` (paged) shares full block-aligned prompt
     prefixes copy-on-write across streams. ``paged_kernel`` gathers
-    decode attention through the Pallas paged kernel where supported
-    (``ops.pallas_paged_attention``); off = the pure-lax gather
-    fallback, the bit-identity reference, everywhere-green path.
+    decode attention through the Pallas paged kernel
+    (``ops.pallas_paged_attention``) and the engine refuses at
+    construction where that kernel cannot run (a real TPU needs
+    ``d_head % 128 == 0``); off = the pure-lax gather path, the
+    bit-identity reference.
 
     ``chunked_prefill`` (paged + prefix_reuse) switches EVERY admission
     to :func:`~horovod_tpu.parallel.kv_blocks.paged_chunked_prefill`: a
@@ -545,9 +547,16 @@ class GenerationEngine(ReadinessMixin):
             self._tables = np.full((s, max_blocks), TRASH_BLOCK, np.int32)
             self._slot_blocks: List[List[int]] = [[] for _ in range(s)]
             d_head = model_cfg.d_model // model_cfg.n_heads
-            self._use_kernel = bool(
-                config.paged_kernel
-                and paged_attention_supported(d_head, config.block_size))
+            self._use_kernel = bool(config.paged_kernel)
+            if self._use_kernel and not paged_attention_supported(
+                    d_head, config.block_size):
+                # Asked for and cannot run: refuse — never serve the
+                # gather path under the kernel's name.
+                raise ValueError(
+                    f"paged_kernel=True cannot run on backend "
+                    f"{jax.default_backend()!r} at d_head={d_head} (the "
+                    f"Pallas paged decode kernel needs d_head % 128 == "
+                    f"0); set paged_kernel=False for the gather path")
         else:
             self._cache = init_kv_cache(model_cfg, s, config.max_len)
             self._blocks = None

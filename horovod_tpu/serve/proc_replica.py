@@ -71,6 +71,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from ..exceptions import (DeadlineExceededError, PreemptedError,
                           ReplicaTimeoutError, ServerClosedError,
                           ServerOverloadedError, WorkerFailureError)
+from ..utils.chips import chips_on_host, one_chip_env
+from ..utils.compat import backend_initialized
 from .generate import GenerationHandle
 
 _DEFAULT = object()     # mirrors generate.submit's eos_id sentinel
@@ -124,6 +126,7 @@ class ProcReplicaClient:
         self.name = name
         self.serve_name = name          # router re-stamps on _attach
         self._proc = proc
+        self.chip: Optional[int] = None   # set by spawn_replica_factory
         self._host = host
         self._port = port
         self._ready_file = ready_file
@@ -641,9 +644,39 @@ def spawn_replica_factory(spec: Dict[str, Any], *,
     Each spawned child inherits the parent environment — fault specs
     (``HVD_FAULT_SPEC``) reach the child loop — and gets a PER-REPLICA
     flight-recorder dump dir (``$HVD_FLIGHTREC_DIR/<name>``) so two
-    children's rank-0 post-mortems never collide."""
+    children's rank-0 post-mortems never collide.
+
+    One process per chip: unless the environment holds the children to
+    the CPU (``JAX_PLATFORMS=cpu``), each child is given one chip of its
+    own (the lowest no live replica of this factory holds), a replica
+    beyond the host's chip count is refused, and so is spawning from a
+    parent whose own jax backend is already up — that parent holds the
+    chip and the child would fail or hang on its lock."""
     base = dict(spec)
     kw = dict(client_kwargs or {})
+
+    def _child_env(name: str) -> Tuple[Dict[str, str], Optional[int]]:
+        """(environment, chip index or None for a CPU child)."""
+        env = dict(os.environ)
+        if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+            return env, None
+        if backend_initialized():
+            raise RuntimeError(
+                f"replica {name!r} needs a chip of its own, but this "
+                f"process has already initialised a jax backend and holds "
+                f"the chip; spawn subprocess replicas from a parent that "
+                f"stays off the backend")
+        held = {c.chip: n for n, c in factory.clients.items()
+                if c.chip is not None and c._proc.poll() is None}
+        n_chips = chips_on_host()
+        free = sorted(set(range(n_chips)) - set(held))
+        if not free:
+            raise RuntimeError(
+                f"replica {name!r} needs a chip of its own and all "
+                f"{n_chips} on this host are held by live replicas "
+                f"{sorted(held.values())}")
+        env.update(one_chip_env(free[0]))
+        return env, free[0]
 
     def factory(name: str) -> ProcReplicaClient:
         rd = run_dir or tempfile.mkdtemp(prefix="hvd-proc-")
@@ -659,12 +692,14 @@ def spawn_replica_factory(spec: Dict[str, Any], *,
                "horovod_tpu.serve.proc_replica",
                "--spec", spec_path, "--ready-file", ready_path,
                "--parent-pid", str(os.getpid())]
-        proc = subprocess.Popen(cmd, stdin=subprocess.PIPE)
+        env, chip = _child_env(name)
+        proc = subprocess.Popen(cmd, stdin=subprocess.PIPE, env=env)
         client = ProcReplicaClient(
             name, proc, host=child_spec["host"], ready_file=ready_path,
             ready_timeout_s=ready_timeout_s,
             default_deadline_ms=(child_spec.get("generation")
                                  or {}).get("default_deadline_ms"), **kw)
+        client.chip = chip      # the chip this child holds while alive
         factory.clients[name] = client
         return client
 

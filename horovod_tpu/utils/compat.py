@@ -1,148 +1,23 @@
-"""JAX version compatibility shims.
-
-``HVD_COMPAT_LEVEL`` forces the resolution level so CI can exercise the
-older-API code paths under a current jax (``ci.sh`` runs a leg with
-``HVD_COMPAT_LEVEL=private``; see README "Version matrix"):
-
-* unset/``public`` — prefer the public symbol (current jax);
-* ``private`` — skip the public symbol, resolve the pre-export private
-  path (jax versions where ``all_gather_invariant`` existed but was not
-  yet public);
-* ``plain`` — plain ``all_gather`` (pre-VMA jax, where shard_map's
-  ``out_specs=P()`` did not require the invariant marking; under a
-  current VMA-checking jax this level is expected to fail type checks —
-  it exists for running the suite against an actually-old jax install,
-  not for simulation).
-"""
+"""The jax symbols this framework needs that jax 0.9.0 (the one installed
+version; setup.py pins it) does not export from a stable public module —
+one import site each, so a later jax moves one line."""
 
 from __future__ import annotations
 
-import os
-
 import jax
-from jax import lax
+from jax._src import xla_bridge as _xla_bridge
+# ``all_gather`` whose output is marked replicated (invariant) over the
+# axis, so ``shard_map(..., out_specs=P())`` type-checks under VMA
+# analysis. jax 0.9.0 has no public export of it.
+from jax._src.lax.parallel import all_gather_invariant  # noqa: F401
 
 
-def _resolve_all_gather_invariant():
-    """``all_gather`` whose output is marked replicated (invariant) over the
-    axis, so ``shard_map(..., out_specs=P())`` type-checks under VMA
-    analysis. Public in newer JAX; fall back to the private symbol, then to
-    plain ``all_gather`` (pre-VMA versions don't need the distinction)."""
-    level = os.environ.get("HVD_COMPAT_LEVEL", "public")
-    if level not in ("public", "private", "plain"):
-        raise ValueError(
-            f"HVD_COMPAT_LEVEL must be public|private|plain, got {level!r}")
-    if level == "public":
-        fn = getattr(lax, "all_gather_invariant", None)
-        if fn is not None:
-            return fn
-        level = "private"
-    forced_private = os.environ.get("HVD_COMPAT_LEVEL") == "private"
-    if level == "private":
-        try:
-            from jax._src.lax.parallel import all_gather_invariant
-            return all_gather_invariant
-        except ImportError:
-            if forced_private:
-                # A forced level must not silently degrade to `plain` (the
-                # level documented to fail under VMA): fail with the real
-                # signal — this jax dropped the private symbol.
-                raise ImportError(
-                    "HVD_COMPAT_LEVEL=private: this jax has neither a "
-                    "public nor a private all_gather_invariant; the "
-                    "private-path CI leg no longer applies to it")
-    return lax.all_gather
+def is_tracer(x) -> bool:
+    """True inside a trace (jit / shard_map / grad) for a traced value."""
+    return isinstance(x, jax.core.Tracer)
 
 
-all_gather_invariant = _resolve_all_gather_invariant()
-
-
-def _resolve_shard_map():
-    """``jax.shard_map`` moved to the top level in newer JAX; on older
-    versions it lives at ``jax.experimental.shard_map.shard_map``. The
-    whole framework (and its test suite) calls the top-level spelling, so
-    besides returning the callable we GRAFT it onto the ``jax`` module
-    when absent — this module is imported by ``horovod_tpu/__init__``, so
-    any code running after ``import horovod_tpu`` sees a working
-    ``jax.shard_map`` on every supported jax."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    import functools
-
-    from jax.experimental.shard_map import shard_map as experimental_fn
-
-    @functools.wraps(experimental_fn)
-    def _compat_shard_map(f, *args, **kwargs):
-        # New-jax spelling of the check knob maps onto the old one…
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # …and the framework is written against the newer VMA replication
-        # checker (all_gather_invariant etc.); the old experimental
-        # checker rejects those specs, so disable it on the graft path —
-        # correctness is covered by the VMA leg on current jax.
-        kwargs.setdefault("check_rep", False)
-        return experimental_fn(f, *args, **kwargs)
-
-    jax.shard_map = _compat_shard_map
-    return _compat_shard_map
-
-
-shard_map = _resolve_shard_map()
-
-
-def _resolve_axis_size():
-    """``lax.axis_size`` (newer jax) — on older versions the same value
-    comes from ``jax.core.axis_frame(name)``, which returns the mapped
-    axis size as a plain int. Grafted onto ``jax.lax`` when absent, for
-    the same reason as the ``shard_map`` graft above."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn
-    import jax.core as _core
-
-    def _compat_axis_size(axis_name):
-        if isinstance(axis_name, (tuple, list)):
-            n = 1
-            for a in axis_name:
-                n *= _core.axis_frame(a)
-            return n
-        return _core.axis_frame(axis_name)
-
-    lax.axis_size = _compat_axis_size
-    return _compat_axis_size
-
-
-axis_size = _resolve_axis_size()
-
-
-def _graft_pallas_compiler_params() -> None:
-    """Newer jax renamed ``pltpu.TPUCompilerParams`` →
-    ``pltpu.CompilerParams``; the kernels call the new spelling. Graft it
-    when absent (same policy as the ``jax.shard_map`` graft above).
-    Pallas is optional on exotic builds, so resolution failures just
-    leave the kernels' own ``_HAS_PALLAS`` guard to handle it."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception:  # pragma: no cover — no pallas in this build
-        return
-    if (not hasattr(pltpu, "CompilerParams")
-            and hasattr(pltpu, "TPUCompilerParams")):
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-
-_graft_pallas_compiler_params()
-
-
-def jax_distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` (newer jax) with a fallback to
-    the distributed client's global state on versions that predate the
-    public predicate."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None
-    except Exception:  # pragma: no cover — very old/unknown layouts
-        return False
+def backend_initialized() -> bool:
+    """Whether this process has initialised a jax backend (and so holds
+    the chip, if it has one) — asked WITHOUT initialising one."""
+    return _xla_bridge.backends_are_initialized()

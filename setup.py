@@ -32,11 +32,7 @@ setup(
     package_data={"horovod_tpu.coord": ["libhvdcoord.so", "coordinator.cc",
                                         "Makefile"]},
     python_requires=">=3.10",
-    # jax floor: 0.9 is the version every CI leg verifies (this image
-    # ships exactly one jax, so older floors would be untested claims).
-    # The only cross-version API the package touches is
-    # all_gather_invariant, shimmed for three jax generations in
-    # utils/compat.py (README "Version matrix" states the coverage).
+    # jax 0.9 is the one version the code is written for and tested on.
     install_requires=["jax>=0.9", "flax", "optax", "orbax-checkpoint",
                       "numpy"],
     # "digits" real-dataset loader (data.load_dataset) needs sklearn.

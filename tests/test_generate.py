@@ -183,15 +183,17 @@ class TestSamplingAndTermination:
         assert len(streams) > 1     # temperature actually samples
 
     def test_eos_terminates(self, eng):
-        # Greedy from this prompt starts 18, 25, ... (pinned by the
-        # deterministic test above): make the second token the EOS.
-        ref = eng.generate([1, 2, 3], timeout=60, max_new_tokens=4)
-        eos = ref["tokens"][1]
-        r = eng.generate([1, 2, 3], timeout=60, max_new_tokens=4,
-                         eos_id=eos)
+        # EOS = the first greedy token (past index 0) that has not occurred
+        # earlier in the stream, so the test pins termination AT that
+        # index, not one numeric stream.
+        ref = eng.generate([1, 2, 3], timeout=60, max_new_tokens=12)
+        toks = ref["tokens"]
+        i = next(j for j in range(1, len(toks)) if toks[j] not in toks[:j])
+        r = eng.generate([1, 2, 3], timeout=60, max_new_tokens=12,
+                         eos_id=toks[i])
         assert r["finish_reason"] == "eos"
-        assert r["tokens"] == ref["tokens"][:2]
-        assert r["n_tokens"] == 2
+        assert r["tokens"] == toks[:i + 1]
+        assert r["n_tokens"] == i + 1
 
     def test_max_tokens_and_cache_capacity_clamp(self, eng):
         r = eng.generate([1] * 14, timeout=60, max_new_tokens=50)

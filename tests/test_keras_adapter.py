@@ -86,7 +86,7 @@ class TestDistributedOptimizer:
         env.pop("HVD_SIZE", None)
         r = subprocess.run(
             [sys.executable, "-m", "horovod_tpu.launcher", "-np", "2",
-             sys.executable, worker],
+             "--cpu", sys.executable, worker],
             env=env, capture_output=True, text=True, timeout=400)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "rank 0: KERAS_FIT_OK" in r.stdout, r.stdout
