@@ -124,8 +124,7 @@ _END = _Sentinel()
 
 
 def prefetch_to_device(batches: Iterable, size: int = 2,
-                       sharding: Optional[Any] = None,
-                       timeline: Optional[Any] = None) -> Iterator:
+                       sharding: Optional[Any] = None) -> Iterator:
     """Iterate ``batches`` with a background thread staying ``size`` batches
     ahead. Exceptions in the source iterator re-raise at the consuming
     ``next()`` call. Abandoning the iterator early (a ``break``, a
@@ -139,45 +138,43 @@ def prefetch_to_device(batches: Iterable, size: int = 2,
     is what makes the prefetch depth actually overlap H2D for sharded
     meshes — without it the source must yield already-placed batches, and
     a source built on a default single-device ``device_put`` serializes
-    the transfer into the consuming ``next()``. Each placement is recorded
-    as an ``H2D`` timeline phase (``timeline`` defaults to the runtime's
-    writer) so a trace can attribute input-bound vs compute-bound steps.
+    the transfer into the consuming ``next()``.
+
+    The worker thread records two program spans per batch, always (no
+    timeline needed; :mod:`horovod_tpu.utils.timeline`): ``input.source``
+    around the source iterator's ``next`` and ``H2D`` around the placement
+    and its completion, both with ``batch=<sequence number>``. The
+    consuming ``next()`` stamps the same ``batch=`` (and ``queue_depth=``,
+    the batches that were ready when it asked) on the span it is called
+    inside, so the three join. ``hvd_input_queue_depth`` and
+    ``hvd_h2d_bytes_total`` carry the same to ``/metrics``.
     """
     if size < 1:
         raise ValueError(f"prefetch size must be >= 1, got {size}")
-    return _prefetch_gen(batches, size, sharding, timeline)
-
-
-# Timeline-row pool: concurrent streams (train + eval) need DISTINCT rows
-# so B/E events don't interleave, but sequential streams (one per epoch)
-# reuse freed ids — otherwise a long run grows one single-use Chrome-trace
-# pseudo-process (and Timeline dict entry) per epoch without bound.
-_h2d_rows = itertools.count()
-_h2d_free: list = []
-_h2d_lock = threading.Lock()
+    return _prefetch_gen(batches, size, sharding)
 
 
 def _prefetch_gen(batches: Iterable, size: int,
-                  sharding: Optional[Any] = None,
-                  timeline: Optional[Any] = None) -> Iterator:
+                  sharding: Optional[Any] = None) -> Iterator:
     import jax
 
-    from . import runtime
+    from .obs.registry import registry
     from .utils import timeline as _tl
 
     q: "queue.Queue" = queue.Queue(maxsize=size)
     stop = threading.Event()
+    m_depth = registry().gauge(
+        "hvd_input_queue_depth",
+        "Prefetched batches ready when the step loop last asked for one "
+        "(0: the loop is waiting on the input thread)")
+    m_h2d_bytes = registry().counter(
+        "hvd_h2d_bytes_total",
+        "Bytes the prefetch thread placed on the devices")
 
-    if timeline is None and runtime.is_initialized():
-        timeline = runtime.world().timeline
-    with _h2d_lock:
-        row_id = _h2d_free.pop() if _h2d_free else next(_h2d_rows)
-    row = f"input.h2d.{row_id}"
-
-    def _place(b):
+    def _place(b, seq):
         if sharding is None:
             return b
-        with _tl.maybe_op(timeline, row, _tl.H2D):
+        with _tl.span(_tl.H2D, batch=seq):
             if isinstance(sharding, jax.sharding.Sharding):
                 placed = jax.tree_util.tree_map(
                     lambda x: jax.device_put(x, sharding), b)
@@ -189,6 +186,8 @@ def _prefetch_gen(batches: Iterable, size: int,
             # dequeued batch must already be device-resident for the
             # prefetch depth to mean completed transfers.
             jax.block_until_ready(placed)
+        m_h2d_bytes.inc(sum(getattr(x, "nbytes", 0)
+                            for x in jax.tree_util.tree_leaves(placed)))
         return placed
 
     def _put(item) -> bool:
@@ -204,8 +203,15 @@ def _prefetch_gen(batches: Iterable, size: int,
 
     def _fill():
         try:
-            for b in batches:
-                if not _put(_place(b)):
+            source = iter(batches)
+            for seq in itertools.count():
+                with _tl.span("input.source", batch=seq) as sp:
+                    try:
+                        b = next(source)
+                    except StopIteration:
+                        sp.drop()
+                        break
+                if not _put((seq, _place(b, seq))):
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised at consumer
             _put(e)
@@ -217,12 +223,16 @@ def _prefetch_gen(batches: Iterable, size: int,
 
     try:
         while True:
+            depth = q.qsize()
             item = q.get()
             if isinstance(item, _Sentinel):
                 return
             if isinstance(item, BaseException):
                 raise item
-            yield item
+            seq, batch = item
+            m_depth.set(depth)
+            _tl.annotate(batch=seq, queue_depth=depth)
+            yield batch
     finally:
         stop.set()
         # Unblock a worker stuck in put() and drop staged batches.
@@ -232,11 +242,6 @@ def _prefetch_gen(batches: Iterable, size: int,
             except queue.Empty:
                 break
         t.join(timeout=5)
-        if not t.is_alive():
-            # Recycle the timeline row only once the worker can no longer
-            # emit on it (a wedged worker leaks its id — safe, just wider).
-            with _h2d_lock:
-                _h2d_free.append(row_id)
         close = getattr(batches, "close", None)
         if close is not None:
             close()

@@ -550,43 +550,46 @@ def _grouped_allreduce(leaves, treedef, syncs: Sequence[GradSync],
     finite_partial = jnp.ones((), jnp.bool_)
     missing_union: set = set()
     prev = None
-    for bucket in buckets:
-        sync = syncs[bucket[0]]
-        if len(bucket) == 1:
-            operand = leaves[bucket[0]]
-        else:
-            operand = _fuse([leaves[j] for j in bucket])
-        if overlap_on and len(buckets) > 1:
-            operand = _barrier_chain(operand, prev)
-        eff = prescale
-        if sync.denom > 1:
-            inv = 1.0 / sync.denom
-            eff = inv if eff is None else eff * inv
-        if sync.psum:
-            if _wire_applies(operand.dtype, wire):
-                r = _wire_sum(operand, sync.psum, wire, prescale=eff)
+    for k, bucket in enumerate(buckets):
+        # The same per-bucket scope as fused_allreduce's 1-D loop.
+        with jax.named_scope(f"allreduce.bucket{k}"):
+            sync = syncs[bucket[0]]
+            if len(bucket) == 1:
+                operand = leaves[bucket[0]]
             else:
-                r = jax.lax.psum(_prescale_array(operand, eff), sync.psum)
-        else:
-            # Fully sharded across every mesh axis: nothing to exchange,
-            # only the correction scale applies.
-            r = _prescale_array(operand, eff)
-        if overlap_on:
-            prev = r
-        if return_finite and jnp.issubdtype(r.dtype, jnp.inexact):
-            flag = jnp.all(jnp.isfinite(r))
-            missing = all_axes - set(sync.psum)
-            if missing:
-                finite_partial = finite_partial & flag
-                missing_union.update(missing)
+                operand = _fuse([leaves[j] for j in bucket])
+            if overlap_on and len(buckets) > 1:
+                operand = _barrier_chain(operand, prev)
+            eff = prescale
+            if sync.denom > 1:
+                inv = 1.0 / sync.denom
+                eff = inv if eff is None else eff * inv
+            if sync.psum:
+                if _wire_applies(operand.dtype, wire):
+                    r = _wire_sum(operand, sync.psum, wire, prescale=eff)
+                else:
+                    r = jax.lax.psum(_prescale_array(operand, eff),
+                                     sync.psum)
             else:
-                finite_full = finite_full & flag
-        if len(bucket) == 1:
-            reduced[bucket[0]] = r
-        else:
-            members = [leaves[j] for j in bucket]
-            for j, rr in zip(bucket, _unfuse(r, members)):
-                reduced[j] = rr
+                # Fully sharded across every mesh axis: nothing to exchange,
+                # only the correction scale applies.
+                r = _prescale_array(operand, eff)
+            if overlap_on:
+                prev = r
+            if return_finite and jnp.issubdtype(r.dtype, jnp.inexact):
+                flag = jnp.all(jnp.isfinite(r))
+                missing = all_axes - set(sync.psum)
+                if missing:
+                    finite_partial = finite_partial & flag
+                    missing_union.update(missing)
+                else:
+                    finite_full = finite_full & flag
+            if len(bucket) == 1:
+                reduced[bucket[0]] = r
+            else:
+                members = [leaves[j] for j in bucket]
+                for j, rr in zip(bucket, _unfuse(r, members)):
+                    reduced[j] = rr
     out = treedef.unflatten(reduced)
     if not return_finite:
         return out
@@ -727,31 +730,34 @@ def fused_allreduce(tree, average: bool = True,
         buckets = plan_buckets(dense, fusion_threshold)
 
     prev = None
-    for bucket in buckets:
-        if len(bucket) == 1:
-            operand = dense[bucket[0]]
-        else:
-            operand = _fuse([dense[j] for j in bucket])
-        if overlap_on and len(buckets) > 1:
-            operand = _barrier_chain(operand, prev)
-        if _wire_applies(operand.dtype, wire):
-            eff = prescale
-            if op is Op.AVERAGE:
-                inv = 1.0 / int(jax.lax.axis_size(axis_name))
-                eff = inv if eff is None else eff * inv
-            r = _wire_sum(operand, axis_name, wire, prescale=eff)
-        else:
-            r = _reduce_in_trace(
-                _prescale_array(operand, prescale), op, axis_name)
-        if overlap_on:
-            prev = r
-        _check(r)
-        if len(bucket) == 1:
-            reduced[dense_idx[bucket[0]]] = r
-        else:
-            members = [dense[j] for j in bucket]
-            for j, rr in zip(bucket, _unfuse(r, members)):
-                reduced[dense_idx[j]] = rr
+    for k, bucket in enumerate(buckets):
+        # One scope per bucket of the plan: a device trace then says which
+        # bucket a collective (and its fuse/unfuse copies) belongs to.
+        with jax.named_scope(f"allreduce.bucket{k}"):
+            if len(bucket) == 1:
+                operand = dense[bucket[0]]
+            else:
+                operand = _fuse([dense[j] for j in bucket])
+            if overlap_on and len(buckets) > 1:
+                operand = _barrier_chain(operand, prev)
+            if _wire_applies(operand.dtype, wire):
+                eff = prescale
+                if op is Op.AVERAGE:
+                    inv = 1.0 / int(jax.lax.axis_size(axis_name))
+                    eff = inv if eff is None else eff * inv
+                r = _wire_sum(operand, axis_name, wire, prescale=eff)
+            else:
+                r = _reduce_in_trace(
+                    _prescale_array(operand, prescale), op, axis_name)
+            if overlap_on:
+                prev = r
+            _check(r)
+            if len(bucket) == 1:
+                reduced[dense_idx[bucket[0]]] = r
+            else:
+                members = [dense[j] for j in bucket]
+                for j, rr in zip(bucket, _unfuse(r, members)):
+                    reduced[dense_idx[j]] = rr
     out = jax.tree_util.tree_unflatten(treedef, reduced)
     return (out, finite) if return_finite else out
 

@@ -14,6 +14,11 @@ Off-TPU (CPU tests) the kernels run in interpreter mode, bit-matching the
 compiled path's math. `flash_attention` falls back to plain XLA attention
 for shapes the kernel doesn't tile (tiny head_dim or sequences not divisible
 by the block).
+
+Kernel names (``pallas_call(name=)``; a device trace and the compiled HLO
+find the kernels by them, so they are API): ``flash_fwd``, ``flash_bwd``
+(fused dq/dk/dv), and the split backward's ``flash_bwd_dq`` and
+``flash_bwd_dkv`` — the same four for the packed-qkv and the BHTD layouts.
 """
 
 from __future__ import annotations
@@ -482,6 +487,7 @@ def _fwd_pallas(q, k, v, causal: bool, interpret: bool,
         ],
         compiler_params=_grid_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return (out if with_lse else (out, None))
 
@@ -536,6 +542,7 @@ def _flash_core_bwd(causal, interpret, res, do):
                 ("parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=_VMEM_BUDGET_BYTES),
             interpret=interpret,
+            name="flash_bwd",
         )(q, k, v, do, lse, delta)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, bq=bq, bk=bk),
@@ -548,6 +555,7 @@ def _flash_core_bwd(causal, interpret, res, do):
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_grid_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv iterate the OTHER way: one K/V tile accumulated over Q tiles.
@@ -571,6 +579,7 @@ def _flash_core_bwd(causal, interpret, res, do):
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_grid_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -637,6 +646,7 @@ def _fwd_pallas_qkv(qkv, H, D, causal, sm_scale, interpret,
         compiler_params=_grid_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qkv, qkv, qkv)
     return (out if with_lse else (out, None))
 
@@ -690,6 +700,7 @@ def _flash_qkv_core_bwd(H, causal, sm_scale, interpret, res, do):
                 ("parallel", "parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=_VMEM_BUDGET_BYTES),
             interpret=interpret,
+            name="flash_bwd",
         )(qkv, qkv, qkv, do, lse, delta)
         return (d_qkv,)
     dq = pl.pallas_call(
@@ -705,6 +716,7 @@ def _flash_qkv_core_bwd(H, causal, sm_scale, interpret, res, do):
         compiler_params=_grid_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qkv, qkv, qkv, do, lse, delta)
 
     # dk/dv iterate the OTHER way: grid (B, H, kb, qi).
@@ -733,6 +745,7 @@ def _flash_qkv_core_bwd(H, causal, sm_scale, interpret, res, do):
         compiler_params=_grid_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qkv, qkv, qkv, do, lse, delta)
     # Interleave back into the packed head-major (H, 3, D) column layout.
     d_qkv = jnp.stack(

@@ -731,7 +731,10 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
         # batch statistics and owns its remat (cfg.remat) and rng-free
         # forward, so stats/logits ride as None.
         def lf(p):
-            return _loss_fn(p, tokens, labels), (None, None)
+            # The same scope as training._build_value_and_grad's loss:
+            # a device trace splits the step by it.
+            with jax.named_scope("forward"):
+                return _loss_fn(p, tokens, labels), (None, None)
         return jax.value_and_grad(lf, has_aux=True)(params)
 
     core = training.make_train_step(

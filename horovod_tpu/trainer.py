@@ -32,6 +32,15 @@ from .training import TrainState, make_batch_placer, shard_batch
 from .utils import timeline as _timeline
 
 
+@jax.jit
+def _add_metrics(acc, metrics):
+    """The running-metric reducer's one program, shared by every Trainer:
+    a per-instance ``jax.jit(lambda ...)`` would compile again for each new
+    Trainer (one ``xla.compile`` inside a warmed loop's first step)."""
+    return jax.tree_util.tree_map(
+        lambda a, x: a + jnp.asarray(x, jnp.float32), acc, metrics)
+
+
 class Trainer:
     """Host training loop; owns the mutable ``state`` that callbacks adjust."""
 
@@ -59,12 +68,11 @@ class Trainer:
         # Global step counter across epochs — drives the deterministic
         # fault-injection hook (testing/faults.py; no-op in production).
         self._global_step = 0
-        # Device-resident running-metric reducer (built lazily): epoch logs
+        # Device-resident running-metric reducer (_add_metrics): epoch logs
         # come from one (sums, count) accumulator updated per step, not an
         # O(steps) host list of device arrays fetched in a storm at epoch
         # end. The add is a tiny jitted program so the step loop never
         # synchronizes on a metric value.
-        self._metric_add = None
         self._eval_placer: Optional[Callable] = None
         # Bad-step containment (active only when the train step was built
         # with guard_nonfinite and emits the ``bad_step`` metric): a
@@ -97,6 +105,10 @@ class Trainer:
             "hvd_step_seconds",
             "Per-step wall time: input wait + dispatch + host-side work "
             "between consecutive step completions")
+        self._m_input_wait = reg.histogram(
+            "hvd_input_wait_seconds",
+            "What next(batch) blocked the step loop for (the part of "
+            "hvd_step_seconds spent waiting on the input pipeline)")
         self._m_samples = reg.counter(
             "hvd_samples_total",
             "Training examples consumed (leading batch-axis rows seen "
@@ -139,10 +151,7 @@ class Trainer:
                             f"silently broadcast into the epoch mean")
             sums = jax.tree_util.tree_map(
                 lambda x: jnp.zeros((), jnp.float32), metrics)
-        if self._metric_add is None:
-            self._metric_add = jax.jit(lambda acc, m: jax.tree_util.tree_map(
-                lambda a, x: a + jnp.asarray(x, jnp.float32), acc, m))
-        return self._metric_add(sums, metrics)
+        return _add_metrics(sums, metrics)
 
     # -- bad-step containment (guard_nonfinite train steps) ----------------
 
@@ -263,7 +272,6 @@ class Trainer:
                           if runtime.is_initialized() else None)
         # Mesh-tied host-side caches die with the old world.
         self._eval_placer = None
-        self._metric_add = None
         self._bad_add = None
         self._bad_counter = None
         if self.verbose:
@@ -304,59 +312,86 @@ class Trainer:
             resized_early = False
             metric_sums = None
             stream = self._stream(data())
+            batches = iter(stream)
+            batch_idx = 0
             step_t0 = time.perf_counter()
             try:
-                for batch_idx, batch in enumerate(stream):
-                    if self.steps_per_epoch is not None \
-                            and batch_idx >= self.steps_per_epoch:
-                        break
-                    for cb in callbacks:
-                        cb.on_batch_begin(batch_idx)
-                    self.state, metrics = self.train_step(self.state, batch)
-                    # The guard's flag rides the metrics dict but is a
-                    # count, not a mean — pop it before the epoch
-                    # accumulator sees it.
-                    bad_flag = (metrics.pop("bad_step", None)
-                                if isinstance(metrics, dict) else None)
-                    metric_sums = self._accumulate_metrics(metric_sums,
-                                                           metrics)
-                    if bad_flag is not None:
-                        guard_active = True
-                        if self._track_bad_step(bad_flag):
-                            bad_steps += 1
-                    for cb in callbacks:
-                        cb.on_batch_end(batch_idx)
-                    nsteps += 1
-                    # Telemetry: per-step wall time (completion to
-                    # completion — input wait included, it is the
-                    # number an operator acts on), throughput counters,
-                    # and one flight-recorder event naming the step a
-                    # post-mortem will call "last completed".
-                    now = time.perf_counter()
-                    self._m_step_seconds.observe(now - step_t0)
-                    step_t0 = now
-                    self._m_steps.inc()
-                    # Post-increment count: the gauge reads "steps this
-                    # process has completed" (the fleet poller's
-                    # straggler spread keys on it).
-                    self._m_gstep.set(self._global_step + 1)
-                    try:
-                        rows = int(np.shape(
-                            jax.tree_util.tree_leaves(batch)[0])[0])
-                    except (IndexError, TypeError):
-                        rows = 0
-                    if rows:
-                        self._m_samples.inc(rows)
-                    _flightrec.record("step", step=self._global_step,
-                                      epoch=epoch)
-                    _faults.step_hook(self._global_step)
-                    self._global_step += 1
-                    if self.resize is not None and self._maybe_resize():
-                        # World re-formed in place: the rest of this
-                        # epoch's stream is sharded for the old world —
-                        # end the epoch here, resume on the new world.
-                        resized_early = True
-                        break
+                while True:
+                    # One turn of the loop is one ``fit.step`` span; its
+                    # children name where the turn went (next batch, the
+                    # train step call, callbacks) and what is left over is
+                    # the loop's own bookkeeping below.
+                    with _timeline.span("fit.step",
+                                        step=self._global_step) as turn:
+                        with _timeline.span("fit.next_batch") as waited:
+                            try:
+                                batch = next(batches)
+                            except StopIteration:
+                                waited.drop()
+                                turn.drop()
+                                break
+                        self._m_input_wait.observe(
+                            (waited.end_ns - waited.start_ns) * 1e-9)
+                        if self.steps_per_epoch is not None \
+                                and batch_idx >= self.steps_per_epoch:
+                            turn.drop()
+                            break
+                        if callbacks:
+                            with _timeline.span("fit.callbacks"):
+                                for cb in callbacks:
+                                    cb.on_batch_begin(batch_idx)
+                        with _timeline.span("fit.train_step"):
+                            self.state, metrics = self.train_step(
+                                self.state, batch)
+                        # The guard's flag rides the metrics dict but is a
+                        # count, not a mean — pop it before the epoch
+                        # accumulator sees it.
+                        bad_flag = (metrics.pop("bad_step", None)
+                                    if isinstance(metrics, dict) else None)
+                        metric_sums = self._accumulate_metrics(metric_sums,
+                                                               metrics)
+                        if bad_flag is not None:
+                            guard_active = True
+                            if self._track_bad_step(bad_flag):
+                                bad_steps += 1
+                        if callbacks:
+                            with _timeline.span("fit.callbacks"):
+                                for cb in callbacks:
+                                    cb.on_batch_end(batch_idx)
+                        nsteps += 1
+                        # Telemetry: per-step wall time (completion to
+                        # completion — input wait included, it is the
+                        # number an operator acts on), throughput
+                        # counters, and one flight-recorder event naming
+                        # the step a post-mortem will call "last
+                        # completed".
+                        now = time.perf_counter()
+                        self._m_step_seconds.observe(now - step_t0)
+                        step_t0 = now
+                        self._m_steps.inc()
+                        # Post-increment count: the gauge reads "steps
+                        # this process has completed" (the fleet poller's
+                        # straggler spread keys on it).
+                        self._m_gstep.set(self._global_step + 1)
+                        try:
+                            rows = int(np.shape(
+                                jax.tree_util.tree_leaves(batch)[0])[0])
+                        except (IndexError, TypeError):
+                            rows = 0
+                        if rows:
+                            self._m_samples.inc(rows)
+                        _flightrec.record("step", step=self._global_step,
+                                          epoch=epoch)
+                        _faults.step_hook(self._global_step)
+                        self._global_step += 1
+                        batch_idx += 1
+                        if self.resize is not None and self._maybe_resize():
+                            # World re-formed in place: the rest of this
+                            # epoch's stream is sharded for the old world
+                            # — end the epoch here, resume on the new
+                            # world.
+                            resized_early = True
+                            break
             finally:
                 close = getattr(stream, "close", None)
                 if close is not None:

@@ -38,6 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from . import runtime
 from .optimizer import Compression, DistributedOptimizer
 from .runtime import AXIS
+from .utils import timeline as _timeline
 
 
 @jax.tree_util.register_dataclass
@@ -209,13 +210,17 @@ def _build_value_and_grad(model, loss_fn, remat):
         variables = {"params": params}
         if batch_stats is not None:
             variables["batch_stats"] = batch_stats
-        out = model.apply(
-            variables, inputs, train=True,
-            mutable=["batch_stats"] if batch_stats is not None else [],
-            rngs={"dropout": step_rng},
-        )
-        logits, new_vars = out if isinstance(out, tuple) else (out, {})
-        loss = loss_fn(logits, labels)
+        # Every op of the loss carries "forward" in its name, and its
+        # transposes "transpose(jvp(forward))": how a device trace splits
+        # the step (docs/timeline.md).
+        with jax.named_scope("forward"):
+            out = model.apply(
+                variables, inputs, train=True,
+                mutable=["batch_stats"] if batch_stats is not None else [],
+                rngs={"dropout": step_rng},
+            )
+            logits, new_vars = out if isinstance(out, tuple) else (out, {})
+            loss = loss_fn(logits, labels)
         return loss, (logits, new_vars.get("batch_stats"))
 
     if remat:
@@ -580,16 +585,17 @@ def make_train_step(model,
         # DistributedOptimizer performs the fused allreduce over `axis_name`
         # — on the accumulated (microbatch-mean) tree, once per step.
         upd_kwargs = _overlap_kwargs(grads)
-        if guard_nonfinite:
-            finite_out: dict = {}
-            updates, new_opt_state = dist_opt.update(
-                grads, state.opt_state, state.params,
-                finite_out=finite_out, **upd_kwargs)
-            all_finite = finite_out["all_finite"]
-        else:
-            updates, new_opt_state = dist_opt.update(
-                grads, state.opt_state, state.params, **upd_kwargs)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            if guard_nonfinite:
+                finite_out: dict = {}
+                updates, new_opt_state = dist_opt.update(
+                    grads, state.opt_state, state.params,
+                    finite_out=finite_out, **upd_kwargs)
+                all_finite = finite_out["all_finite"]
+            else:
+                updates, new_opt_state = dist_opt.update(
+                    grads, state.opt_state, state.params, **upd_kwargs)
+            new_params = optax.apply_updates(state.params, updates)
         new_stats = new_stats if new_stats is not None else state.batch_stats
         metrics = {"loss": jax.lax.pmean(loss, metric_axes)}
         if extras is not None:
@@ -669,7 +675,8 @@ def make_train_step(model,
             if accum_steps > 1:
                 _check_accum_batch(inputs, accum_steps, n_lead)
             _probe_overlap(state, inputs, labels)
-            return _hy_jitted(state)(state, inputs, labels)
+            with _timeline.span("step.dispatch"):
+                return _hy_jitted(state)(state, inputs, labels)
 
         hybrid_step.lower = lambda state, batch: (
             _probe_overlap(state, *batch)
@@ -728,7 +735,8 @@ def make_train_step(model,
             if accum_steps > 1:
                 _check_accum_batch(inputs, accum_steps, n_shards)
             _probe_overlap(state, inputs, labels)
-            return _zero_jitted(state)(state, inputs, labels)
+            with _timeline.span("step.dispatch"):
+                return _zero_jitted(state)(state, inputs, labels)
 
         step.lower = lambda state, batch: (
             _probe_overlap(state, *batch)
@@ -741,7 +749,8 @@ def make_train_step(model,
         if accum_steps > 1:
             _check_accum_batch(inputs, accum_steps, n_shards)
         _probe_overlap(state, inputs, labels)
-        return jitted(state, inputs, labels)
+        with _timeline.span("step.dispatch"):
+            return jitted(state, inputs, labels)
 
     # AOT handle (jax .lower convention): lets callers inspect the compiled
     # artifact — e.g. count the all-reduce ops to verify fusion bucketing

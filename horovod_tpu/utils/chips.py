@@ -63,16 +63,26 @@ def enable_compile_cache() -> str:
     this checkout agrees on, and return the directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
-    this sets nothing in code. Where it is not, it is set — in the
+    this sets no directory in code. Where it is not, it is set — in the
     ENVIRONMENT, so spawned children inherit it, and before jax reads its
     configuration when called before the first ``import jax`` — to the one
     fixed git-ignored directory inside the checkout.
     """
+    import jax
+    # A cached executable carries the metadata it was compiled with, and a
+    # device trace finds the step's phases by it (the named scopes of
+    # docs/timeline.md). jax's default key strips metadata, so a program
+    # WITH scopes would be served one compiled WITHOUT them (measured: PR
+    # 26, PERF.md section 6). Keep it in the key — in the environment too,
+    # for children. The price: a moved checkout or shifted source lines
+    # compile again.
+    os.environ.setdefault("JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY",
+                          "1")
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path
     os.environ["JAX_COMPILATION_CACHE_DIR"] = COMPILE_CACHE_DIR
-    import jax
     # jax reads the variable when it is imported; cover a caller that
     # imported jax first.
     jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)

@@ -23,18 +23,36 @@ TPU adaptation: negotiation events come from the host coordination plane
 (or are synthesized instantly in single-controller mode where no negotiation
 exists); compute-phase boundaries come from dispatch timestamps — XLA owns
 on-chip scheduling, so fine-grained on-device phases belong to the JAX
-profiler, which this trace is designed to be merged with.
+profiler.
+
+**Program spans** (the one span mechanism of the training path). Whether or
+not a :class:`Timeline` is on, :func:`span` records ``(name, start, end,
+thread, parent, ids)`` into a bounded in-memory ring (:data:`RING_SPANS`),
+stamped with ``time.perf_counter_ns()`` and handed out by :func:`spans` in
+wall-clock Unix nanoseconds — the clock on which a profiler trace's
+``profile_start_time`` stat is given, so a span can be laid on a device
+trace without the profiler's host tracer. A :class:`Timeline` writes the
+same spans as complete (``"ph": "X"``) events from a drain thread of its
+own, and one ``hvd_clock_origin`` metadata event holding the Unix
+nanoseconds of its ``ts`` 0: add it to a ``ts`` and the event sits on the
+profiler trace's clock (``docs/timeline.md``).
 """
 
 from __future__ import annotations
 
 import atexit
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Iterable, Optional
+from collections import deque
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional
+
+import jax
+
+from ..obs.registry import registry as _metrics_registry
 
 
 class _State:
@@ -54,18 +72,187 @@ CKPT_WRITE = "CKPT_WRITE"        # background writer: orbax write + GC
 BAD_STEP = "BAD_STEP"            # guard: non-finite grads, update skipped
 
 
+# ---------------------------------------------------------------------------
+# Program spans: one bounded in-memory recorder, always on.
+# ---------------------------------------------------------------------------
+
+RING_SPANS = 65536   # spans kept; the oldest fall out
+
+
+class Span(NamedTuple):
+    """One closed span as :func:`spans` hands it out. Times are wall-clock
+    Unix nanoseconds; ``parent`` is the ``id`` of the span that was open on
+    the same thread when this one began (0: none)."""
+    id: int
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: int
+    parent: int
+    ids: Dict[str, Any]
+
+
+# perf_counter_ns -> wall clock: one anchor pair for the process. The two
+# reads are ~100 ns apart, far under what a span is read to.
+_ANCHOR_WALL_NS = time.time_ns()
+_ANCHOR_PERF_NS = time.perf_counter_ns()
+
+_ring: deque = deque(maxlen=RING_SPANS)   # append is atomic under the GIL
+_next_id = itertools.count(1).__next__
+_now_ns = time.perf_counter_ns
+_TraceAnnotation = jax.profiler.TraceAnnotation
+_tls = threading.local()
+_thread_names: Dict[int, str] = {}
+# Open Timelines' pending deques: a closed span is appended to each (the
+# Timeline's own thread formats and writes it later).
+_sinks: tuple = ()
+_sinks_lock = threading.Lock()
+
+
+def _stack() -> list:
+    """This thread's stack of open spans (made at its first span)."""
+    try:
+        return _tls.stack
+    except AttributeError:
+        t = threading.current_thread()
+        _tls.tid = t.ident
+        _thread_names[t.ident] = t.name
+        _tls.stack = []
+        return _tls.stack
+
+
+class span:
+    """``with span("fit.step", step=n):`` — record one span of the calling
+    thread. Nothing is formatted or written here: two clock reads, a
+    thread-local stack push/pop and one ring append. ``ids`` carry what
+    joins the spans of one step or batch across threads (``step=``,
+    ``batch=``); :func:`annotate` adds more while the span is open. The
+    span also enters a ``jax.profiler.TraceAnnotation`` of the same name,
+    so a profiler session run WITH the host tracer shows the program's
+    spans in its own viewer."""
+
+    __slots__ = ("name", "ids", "id", "parent", "start_ns", "end_ns",
+                 "to_timelines", "_annotation", "_dropped")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self.ids = ids
+        self.to_timelines = True
+        self._dropped = False
+
+    def __enter__(self) -> "span":
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _stack()
+        self.parent = stack[-1].id if stack else 0
+        self.id = _next_id()
+        stack.append(self)
+        self._annotation = a = _TraceAnnotation(self.name)
+        a.__enter__()
+        self.start_ns = _now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end_ns = end = _now_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        _tls.stack.pop()
+        if not self._dropped:
+            rec = (self.id, self.name, self.start_ns, end, _tls.tid,
+                   self.parent, self.ids)
+            _ring.append(rec)
+            if _sinks and self.to_timelines:
+                for sink in _sinks:
+                    sink.append(rec)
+        return False
+
+    def drop(self) -> None:
+        """Leave no record of this span (the turn that found the stream
+        at its end did no work worth a row)."""
+        self._dropped = True
+
+
+def annotate(**ids) -> None:
+    """Add ``ids`` to the innermost span open on this thread (no-op when
+    none is): how a callee names what its caller's span turned out to hold
+    — the prefetch iterator stamps ``batch=`` and ``queue_depth=`` on the
+    ``fit.next_batch`` span it is called inside."""
+    stack = _stack()
+    if stack:
+        stack[-1].ids.update(ids)
+
+
+def record_span(name: str, start_ns: int, end_ns: int, **ids) -> None:
+    """Record a span measured elsewhere (``perf_counter_ns`` readings): a
+    compile the runtime reported after the fact."""
+    stack = _stack()
+    rec = (_next_id(), name, start_ns, end_ns, _tls.tid,
+           stack[-1].id if stack else 0, ids)
+    _ring.append(rec)
+    for sink in _sinks:
+        sink.append(rec)
+
+
+def _snapshot() -> list:
+    while True:
+        try:
+            return list(_ring)
+        except RuntimeError:      # another thread appended mid-copy
+            continue
+
+
+def spans() -> List[Span]:
+    """The ring's closed spans, oldest first (by end), on the wall clock.
+    ``hvd.shutdown()`` does not clear it: a reader may run after the
+    world is gone."""
+    shift = _ANCHOR_WALL_NS - _ANCHOR_PERF_NS
+    return [Span(i, n, s + shift, e + shift, t, p, ids)
+            for i, n, s, e, t, p, ids in _snapshot()]
+
+
+def thread_names() -> Dict[int, str]:
+    """``Span.thread`` -> the thread's name when it first opened a span."""
+    return dict(_thread_names)
+
+
+# Recompilations, where they happen: the runtime reports each backend
+# compile's duration after the fact; it becomes a counter tick and an
+# ``xla.compile`` span ending now on the compiling thread, so a gap or a
+# slow step can be put down to a compile.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_m_compiles = _metrics_registry().counter(
+    "hvd_compiles_total",
+    "XLA backend compile requests in this process: a program traced for "
+    "a new shape, compiled or loaded from the persistent cache. A repeat "
+    "of a shape already compiled is none")
+
+
+def _on_event_duration(event: str, duration_s: float, **_kw) -> None:
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    _m_compiles.inc()
+    now = time.perf_counter_ns()
+    record_span("xla.compile", now - int(duration_s * 1e9), now)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+
+
 @contextlib.contextmanager
 def maybe_op(tl: Optional["Timeline"], tensor_name: str, op_kind: str):
-    """Scoped :meth:`Timeline.op` that no-ops when ``tl`` is None — the
-    emitters on the training hot path (prefetch thread, checkpoint writer)
-    run with or without a timeline and must not branch at every call site.
-    Each concurrent emitter uses its own ``tensor_name`` row, so the
-    per-row state machine never sees interleaved ops from two threads."""
-    if tl is None:
-        yield None
-        return
-    with tl.op(tensor_name, op_kind):
-        yield tl
+    """A program span named ``op_kind`` and, when ``tl`` is a Timeline,
+    also the scoped B/E :meth:`Timeline.op` on row ``tensor_name`` — the
+    checkpoint and guard phases exist with or without ``HOROVOD_TIMELINE``
+    and their call sites do not branch. Each concurrent emitter uses its
+    own ``tensor_name`` row, so the per-row state machine never sees
+    interleaved ops from two threads."""
+    with span(op_kind) as sp:
+        if tl is None:
+            yield None
+            return
+        sp.to_timelines = False      # written below, as B/E on its row
+        with tl.op(tensor_name, op_kind):
+            yield tl
 
 
 class TimelineStateError(RuntimeError):
@@ -89,14 +276,32 @@ class Timeline:
 
     def __init__(self, path: str):
         self._lock = threading.Lock()
+        self._pid_lock = threading.Lock()
         self._file = open(path, "w")
         self._file.write("[\n")
-        self._start = time.monotonic()
+        # ``ts`` 0 on the recorder's clock, and what it is on the wall
+        # clock: written once so the file can be laid on a profiler trace.
+        self._start_ns = time.perf_counter_ns()
         self._pids: dict[str, int] = {}
         self._states: dict[str, int] = {}
         self._depth: dict[str, int] = {}
-        self._last_flush = self._start
+        self._last_flush = time.monotonic()
         self._closed = False
+        self._emit({"name": "hvd_clock_origin", "ph": "M", "pid": 0,
+                    "args": {"unix_ns": self._start_ns - _ANCHOR_PERF_NS
+                             + _ANCHOR_WALL_NS}})
+        # Program spans closed from now on, written by a thread of this
+        # writer's own at the flush cadence — never by the thread a span
+        # measured.
+        self._pending: deque = deque(maxlen=RING_SPANS)
+        self._span_threads: set = set()
+        self._stop = threading.Event()
+        global _sinks
+        with _sinks_lock:
+            _sinks = _sinks + (self._pending,)
+        self._drainer = threading.Thread(
+            target=self._drain_loop, name="hvd-timeline-spans", daemon=True)
+        self._drainer.start()
         # Crash safety: the ~1 s flush cadence means a killed rank loses
         # the buffered tail of its trace — the very events that explain
         # the death. An atexit close catches normal-but-uncloseed exits;
@@ -107,13 +312,43 @@ class Timeline:
     # -- low-level ---------------------------------------------------------
 
     def _ts_us(self) -> int:
-        return int((time.monotonic() - self._start) * 1e6)
+        """Microseconds since this writer's origin, on the span recorder's
+        clock (``perf_counter_ns``): B/E events and drained spans share
+        one time base, and ``hvd_clock_origin`` ties it to the wall."""
+        return (time.perf_counter_ns() - self._start_ns) // 1000
+
+    def _drain_loop(self) -> None:
+        while not self._stop.wait(self.FLUSH_INTERVAL_SECS):
+            self._drain_spans()
+
+    def _drain_spans(self) -> None:
+        """Write the program spans closed since the last drain as complete
+        events, one Chrome "thread" per recording thread under the
+        ``program spans`` row."""
+        while True:
+            try:
+                _id, name, start, end, tid, _parent, ids = \
+                    self._pending.popleft()
+            except IndexError:
+                return
+            pid = self._pid("program spans")
+            if tid not in self._span_threads:
+                self._span_threads.add(tid)
+                self._emit({"name": "thread_name", "ph": "M", "pid": pid,
+                            "tid": tid,
+                            "args": {"name": _thread_names.get(tid, "")}})
+            ev = {"name": name, "ph": "X", "pid": pid, "tid": tid,
+                  "ts": (start - self._start_ns) // 1000,
+                  "dur": (end - start) // 1000}
+            if ids:
+                ev["args"] = dict(ids)
+            self._emit(ev)
 
     def _emit(self, ev: dict) -> None:
         with self._lock:
             if self._closed:
                 return
-            self._file.write(json.dumps(ev) + ",\n")
+            self._file.write(json.dumps(ev, default=str) + ",\n")
             now = time.monotonic()
             if now - self._last_flush > self.FLUSH_INTERVAL_SECS:
                 self._file.flush()
@@ -124,6 +359,7 @@ class Timeline:
         from error paths (:meth:`abort`) and crash hooks, where "the OS
         probably would have written it" is not good enough — the reader
         is a post-mortem."""
+        self._drain_spans()
         with self._lock:
             if self._closed:
                 return
@@ -137,7 +373,12 @@ class Timeline:
 
     def _pid(self, tensor_name: str) -> int:
         pid = self._pids.get(tensor_name)
-        if pid is None:
+        if pid is not None:
+            return pid
+        with self._pid_lock:     # the span drainer registers a row too
+            pid = self._pids.get(tensor_name)
+            if pid is not None:
+                return pid
             pid = len(self._pids)
             self._pids[tensor_name] = pid
             # Metadata event registering the tensor as a pseudo-process
@@ -297,6 +538,11 @@ class Timeline:
                 self.activity_end(tensor_name)
 
     def close(self) -> None:
+        global _sinks
+        with _sinks_lock:
+            _sinks = tuple(q for q in _sinks if q is not self._pending)
+        self._stop.set()
+        self._drain_spans()
         with self._lock:
             if self._closed:
                 return

@@ -64,7 +64,10 @@ _PROBE = ("import json, os; "
           "ret = enable_compile_cache(); "
           "print(json.dumps({'ret': ret, 'before': before, "
           "'config': jax.config.jax_compilation_cache_dir, "
-          "'env': os.environ.get('JAX_COMPILATION_CACHE_DIR')}))")
+          "'env': os.environ.get('JAX_COMPILATION_CACHE_DIR'), "
+          "'metadata_in_key': [jax.config."
+          "jax_compilation_cache_include_metadata_in_key, os.environ.get("
+          "'JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY')]}))")
 
 
 def _probe(env_dir):
@@ -82,6 +85,9 @@ def test_cache_helper_leaves_a_set_variable_alone(tmp_path):
     want = str(tmp_path / "outside")
     got = _probe(want)
     # jax read the variable itself; the helper set nothing else in code.
+    # An executable is profiled by its metadata (named scopes), so the
+    # key keeps it, for this process and its children.
+    assert got.pop("metadata_in_key") == [True, "1"]
     assert got == {"ret": want, "before": want, "config": want, "env": want}
 
 

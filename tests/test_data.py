@@ -163,9 +163,9 @@ def test_prefetch_sharding_places_on_world_from_worker():
 
 
 def test_prefetch_emits_h2d_timeline_phase(tmp_path):
-    """Each worker-side placement is bracketed by an H2D phase so traces
-    can attribute input-bound vs compute-bound steps (bin/profile_step.py
-    --timeline)."""
+    """Each worker-side placement is an ``H2D`` program span, so a trace
+    can attribute input-bound vs compute-bound steps; an open Timeline
+    writes it as a complete event from its own thread (docs/timeline.md)."""
     import json
     from horovod_tpu import runtime
     from horovod_tpu.utils.timeline import Timeline
@@ -175,15 +175,16 @@ def test_prefetch_emits_h2d_timeline_phase(tmp_path):
     host = [(np.zeros((8, 2), np.float32), np.zeros((8,), np.int32))
             for _ in range(4)]
     list(prefetch_to_device(iter(host), 2,
-                            sharding=runtime.ranked_sharding(),
-                            timeline=tl))
+                            sharding=runtime.ranked_sharding()))
     tl.close()
     events = [e for e in json.load(open(path)) if isinstance(e, dict)]
-    h2d_b = [e for e in events
-             if e.get("ph") == "B" and e.get("name") == "H2D"]
-    ends = [e for e in events if e.get("ph") == "E"]
-    assert len(h2d_b) == 4, h2d_b
-    assert len(ends) == len(h2d_b)
+    h2d = [e for e in events
+           if e.get("ph") == "X" and e.get("name") == "H2D"]
+    assert len(h2d) == 4, h2d
+    assert sorted(e["args"]["batch"] for e in h2d) == [0, 1, 2, 3]
+    assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in h2d)
+    # The worker thread's row is named, and nothing is left half-open.
+    assert not [e for e in events if e.get("ph") in ("B", "E")]
 
 
 def test_prefetch_composes_with_training_loop():

@@ -99,6 +99,35 @@ def test_flash_attention_qkv_fwd_bwd_compiles(one_chip, B, T, dtype):
         _expected_kernels(T, dtype, packed=True)
 
 
+@pytest.mark.parametrize("T,backward", [
+    (2048, ("flash_bwd",)),                           # fused
+    (8192, ("flash_bwd_dq", "flash_bwd_dkv")),        # split
+], ids=["fused", "split"])
+def test_flash_kernels_carry_their_names(one_chip, T, backward):
+    """``pallas_call(name=)`` becomes the compiled instruction's name and a
+    component of its ``op_name`` — what a device trace finds the kernels
+    by (benchmarks/layer_metrics/device_scopes.py)."""
+    qkv = jax.ShapeDtypeStruct((2, T, H * 3 * D), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def loss(x):
+        with jax.named_scope("forward"):
+            return jnp.sum(pa.flash_attention_qkv(
+                x, H, causal=True, interpret=False).astype(jnp.float32))
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.value_and_grad(loss)).lower(qkv).compile() \
+            .as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    names = sorted(ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+                   for ln in kernels)
+    assert names == sorted(("flash_fwd",) + backward)
+    assert any("jvp(forward)/flash_fwd/pallas_call" in ln for ln in kernels)
+    for name in backward:
+        assert any(f"transpose(jvp(forward))/{name}/pallas_call" in ln
+                   for ln in kernels), name
+
+
 @pytest.mark.parametrize("B,T", [(8, 2048), (2, 8192)],
                          ids=["B8-T2048", "B2-T8192"])
 def test_flash_attention_pallas_backend_fwd_bwd_compiles(one_chip, B, T):
