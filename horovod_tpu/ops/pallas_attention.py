@@ -1,14 +1,23 @@
 """Pallas TPU flash attention — the hot-op kernel for the transformer path.
 
-Blockwise causal attention computed entirely in VMEM with an online softmax
+Blockwise attention computed entirely in VMEM with an online softmax
 (running max/sum), so the [T, T] score matrix never touches HBM: per grid
-step a [BQ, D] query tile is streamed against K/V tiles with MXU matmuls
-(f32 accumulation). Differentiable end to end: a custom VJP recomputes the
-probability tiles from (q, k, lse) inside dq/dkv kernels, so the backward
-pass never materializes scores either. Used by the parallel transformer's
-single-shard attention path (``parallel/transformer.py``); the
-sequence-parallel path (:func:`horovod_tpu.parallel.ring.ring_attention`)
+step a [b, D] query tile meets K/V tiles with MXU matmuls (f32
+accumulation). Differentiable end to end: a custom VJP recomputes the
+probability blocks from (q, k, lse) inside the backward kernels, so the
+backward pass never materializes scores either. Used by the parallel
+transformer's single-shard attention path (``parallel/transformer.py``);
+the sequence-parallel path (:func:`horovod_tpu.parallel.ring.ring_attention`)
 keeps its own blockwise accumulation across chips.
+
+Causal calls pay for no masked score beyond the diagonal's own strips: no
+grid step, no DMA and no arithmetic for a tile pair above the diagonal,
+and of a pair on it only the row strips up to the diagonal
+(``_tile_schedule``). Which schedule runs is chosen from the shape by ONE
+gate (``_fits_vmem``): K/V whole in VMEM with an in-kernel loop over k
+tiles where that fits (the training shapes), K/V tiles streamed over a
+grid axis clamped at the diagonal where it does not, and the fused or the
+split backward.
 
 Off-TPU (CPU tests) the kernels run in interpreter mode, bit-matching the
 compiled path's math. `flash_attention` falls back to plain XLA attention
@@ -19,6 +28,9 @@ Kernel names (``pallas_call(name=)``; a device trace and the compiled HLO
 find the kernels by them, so they are API): ``flash_fwd``, ``flash_bwd``
 (fused dq/dk/dv), and the split backward's ``flash_bwd_dq`` and
 ``flash_bwd_dkv`` — the same four for the packed-qkv and the BHTD layouts.
+
+Speeds quoted in this file are of one machine and one day each: PERF.md
+(PR 27) has this PR's kernel-alone table; older figures are history.
 """
 
 from __future__ import annotations
@@ -35,396 +47,389 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_Q = 128    # minimum tile (tilability floor)
 BLOCK_K = 128
-# Preferred tile sizes (swept on a v5e chip; _pick_block shrinks them to
-# fit short sequences).
-_WANT_BQ = 512
-_WANT_BK = 512
+# Preferred (square) tile side: the knee of a v5e sweep over {256..2048}²
+# of the kernels this file had before PR 27 (docs/benchmarks.md; history,
+# that machine is gone). _pick_block shrinks it to fit short sequences.
+_WANT_BLOCK = 512
+# Height of the row strips a DIAGONAL tile pair is cut into. Measured on a
+# v5e (PERF.md PR 27, kernels alone at B8 H16 T2048 D128, forward +
+# backward): 256 -> 4.08 ms, 128 -> 4.16, 512 (the pair whole) -> 4.13.
+_DIAG_SUB = 256
 
 
 def _pick_block(t: int, want: int) -> int:
     """Largest power-of-two block <= ``want`` dividing ``t``. Bigger tiles
-    amortize Mosaic's per-grid-step overhead; 128 is the floor the
-    tilability check guarantees."""
+    amortize Mosaic's per-step overhead; 128 is the floor the tilability
+    check guarantees."""
     b = want
     while b > 128 and t % b:
         b //= 2
     return b
 
 
+def _blocks(T: int):
+    """(tile side, diagonal strip height) for sequence length T."""
+    b = _pick_block(T, _WANT_BLOCK)
+    return b, min(b, _DIAG_SUB)
+
+
 def _grid_params(semantics, vmem_limit_bytes=None):
     """dimension_semantics lets Mosaic pipeline HBM tile copies against
-    compute across grid steps — without it every step stalls on its loads
-    (measured ~4x on the backward at T=2048). ``vmem_limit_bytes`` pins
-    the kernel's scoped-VMEM limit (None = the compiler's default)."""
+    compute across grid steps. ``vmem_limit_bytes`` pins the kernel's
+    scoped-VMEM limit (None = the compiler's default)."""
     return pltpu.CompilerParams(dimension_semantics=semantics,
                                 vmem_limit_bytes=vmem_limit_bytes)
 
 
-def _causal_run(qi, kb, bq, bk):
-    """A (qi, kb) tile pair contributes under the causal mask iff its
-    lowest k position is <= its highest q position."""
-    return kb * bk <= qi * bq + bq - 1
+# ---------------------------------------------------------------------------
+# The causal tile schedule. With square tiles of side b, q tile qi needs
+# the k tiles kb < qi whole and unmasked ("plain" pairs), part of the pair
+# kb == qi ("diagonal") and nothing of kb > qi. A plain pair is one [b, b]
+# block. A diagonal pair is cut into row strips of height ``sub``; strip i
+# is computed against the first (i+1)·sub columns only and masked by one
+# static triangle — the rest of the pair is never built. No pair above
+# the diagonal is fetched, computed or masked.
+# ---------------------------------------------------------------------------
 
 
-def _tile_mask(s, qi, kb, bq, bk):
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(q_pos >= k_pos, s, -1e30)
+def _tile_schedule(b: int, sub: int, diagonal: bool):
+    """The blocks of one [b, b] tile pair that are computed, as
+    ``[(row0, rows, cols, masked), ...]``: rows [row0, row0+rows) against
+    the pair's first ``cols`` columns."""
+    if not diagonal:
+        return [(0, b, b, False)]
+    return [(i * sub, sub, (i + 1) * sub, True) for i in range(b // sub)]
 
 
-# The kernels work in the LOG2 domain: the caller pre-scales q by
-# sm_scale*log2(e) ONCE (a [BH,T,D] pass), so the per-tile [BQ,BK] scale
-# multiply disappears and exp becomes the VPU's native exp2. True scores
-# A = ln2 * s; probabilities exp2(s-m) == exp(A-A_max) are IDENTICAL, and
-# the backward's dq/dk epilogues become *ln2 (ln2 * the caller's c folds
-# back to sm_scale). The kernels are VPU-softmax-bound at D=128 (measured:
-# fwd 41 TF/s vs matmul passes at 157), so per-tile elementwise passes are
-# exactly what to shave.
-_LN2 = 0.6931471805599453
-LOG2E = 1.4426950408889634
+def _dot(a, b, ca: int, cb: int):
+    """MXU matmul contracting a's dim ``ca`` with b's ``cb`` in the input
+    dtype (bf16 passes), f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
-def _scores(q, k, qi, kb, *, causal, bq, bk):
-    """Masked log2-domain score tile [BQ, BK] (q arrives pre-scaled),
-    shared by forward and both backward kernels so the mask math cannot
-    desynchronize. The matmul stays in the input dtype (bf16 MXU passes
-    with f32 accumulation); only diagonal-crossing tiles pay the
-    iota/select mask."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if causal:
-        s = jax.lax.cond(
-            kb * bk + bk > qi * bq,
-            lambda s: _tile_mask(s, qi, kb, bq, bk),
-            lambda s: s, s)
+def _scores(q, k, masked: bool):
+    """Log2-domain score block [rows, cols] (q arrives scaled), shared by
+    the forward and every backward kernel so the mask cannot
+    desynchronize. A masked block is a diagonal strip: its LAST row sees
+    all its columns, so the mask is a static triangle."""
+    s = _dot(q, k, 1, 1)
+    if masked:
+        rows, cols = s.shape
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(row + (cols - rows) >= col, s, -1e30)
     return s
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                 l_ref, *, causal: bool, bq: int, bk: int,
-                 qi_axis: int = 1, kb_axis: int = 2,
-                 q_scale: Optional[float] = None):
-    """Grid (..., qi, kb): one [BQ, D] × [BK, D] tile pair.
+# The kernels work in the LOG2 domain: q tiles are scaled by
+# sm_scale*log2(e) on load (a [b, D] pass), so the per-block [b, b] scale
+# multiply disappears and exp becomes the VPU's native exp2. True scores
+# A = ln2 * s; probabilities exp2(s-m) == exp(A-A_max) are IDENTICAL, and
+# the chain rule through the scale leaves sm_scale on dq and dk.
+LOG2E = 1.4426950408889634
 
-    K/V tiles stream through VMEM (no whole-sequence residency); the
-    online-softmax state (acc/m/l) persists in scratch across the kb axis,
-    and the normalized output plus the row log2-sum-exp2 (saved for the
-    backward pass) are written at the last kb step. Above-diagonal tile
-    pairs skip all compute under causal.
 
-    ``q_scale``: the packed-qkv path ships RAW q tiles and scales them on
-    load (a [BQ,D] pass) instead of pre-scaling the whole tensor; None =
-    q already pre-scaled by the caller (the split-q/k/v path).
-    """
-    qi = pl.program_id(qi_axis)
-    kb = pl.program_id(kb_axis)
-    n_kb = pl.num_programs(kb_axis)
+def _scaled(q_ref, q_scale: float):
+    return (q_ref[0].astype(jnp.float32) * q_scale).astype(q_ref.dtype)
 
-    @pl.when(kb == 0)
-    def _init():
+
+def _lanes(x, width: int):
+    """A lane-replicated [rows, 128] stat widened to ``width`` lanes: whole
+    vregs repeated, no broadcast."""
+    return jnp.tile(x, (1, width // 128))
+
+
+def _softmax_update(q, k, v, masked, acc_ref, m_ref, l_ref, rows):
+    """One online-softmax step of the state rows ``rows`` (a static slice)
+    for the q rows ``q`` against one K/V block. The running max and sum
+    are kept REPLICATED over their 128 lanes: a [rows, 1] column costs as
+    many vregs, and every use of it against a [rows, cols] block would pay
+    a lane broadcast (measured on a v5e, PERF.md PR 27: the forward kernel
+    2.31 -> 1.43 ms against lane-0 stats, strips of 128)."""
+    s = _scores(q, k, masked)
+    m_prev = m_ref[rows, :]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp2(m_prev - m_new)
+    p = jnp.exp2(s - _lanes(m_new, s.shape[1]))
+    l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+    acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, v.shape[1]) + _dot(
+        p.astype(v.dtype), v, 1, 0)
+    m_ref[rows, :] = m_new
+
+
+def _for_each_pair(k_ref, v_ref, *, b: int, causal: bool, resident: bool,
+                   begin, visit, end):
+    """The pairs of q tile ``program_id(2)``, in k order: ``begin()``,
+    ``visit(kb, kv, diagonal)`` for every pair that contributes —
+    ``kv(w)`` hands out the first w rows of K/V tile kb — then ``end()``.
+
+    ``resident``: K and V of the (batch, head) are whole in VMEM (fetched
+    once per head, not once per q tile) and an in-kernel loop walks the k
+    tiles up to the diagonal — no grid step exists for a pair above it.
+    Otherwise K/V tiles stream over a kb grid axis (``program_id(3)``)
+    whose index maps are clamped at the diagonal (``_kv_row``), so the
+    steps above it fetch nothing and do nothing."""
+    qi = pl.program_id(2)
+    if resident:
+        def tile(kb):
+            def kv(w):
+                rows = pl.ds(pl.multiple_of(kb * b, b), w)
+                return k_ref[0, rows, :], v_ref[0, rows, :]
+            return kv
+
+        def plain(kb, carry):
+            visit(kb, tile(kb), False)
+            return carry
+
+        begin()
+        jax.lax.fori_loop(0, qi if causal else k_ref.shape[1] // b,
+                          plain, 0)
+        if causal:
+            visit(qi, tile(qi), True)
+        end()
+        return
+
+    def kv(w):
+        return k_ref[0, :w, :], v_ref[0, :w, :]
+
+    kb = pl.program_id(3)
+    last = qi if causal else pl.num_programs(3) - 1
+    pl.when(kb == 0)(begin)
+    pl.when(kb < last)(lambda: visit(kb, kv, False))
+
+    @pl.when(kb == last)
+    def _last():
+        visit(kb, kv, causal)
+        end()
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, b: int, sub: int,
+                resident: bool, q_scale: float, with_lse: bool):
+    """Forward for one [b, D] q tile, grid (g0, g1, qi[, kb]) (the two
+    schedules: ``_for_each_pair``). The online-softmax state (acc/m/l)
+    lives in scratch; the normalized output and the row log2-sum-exp2
+    (saved for the backward) are written after the last contributing
+    pair."""
+    if with_lse:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        (o_ref, acc_ref, m_ref, l_ref), lse_ref = rest, None
+    q = _scaled(q_ref, q_scale)
+
+    def init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -1e30)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    run = _causal_run(qi, kb, bq, bk) if causal else True
+    def visit(kb, kv, diagonal):
+        for r0, n, w, masked in _tile_schedule(b, sub, diagonal):
+            _softmax_update(q[r0:r0 + n], *kv(w), masked, acc_ref, m_ref,
+                            l_ref, slice(r0, r0 + n))
 
-    @pl.when(run)
-    def _compute():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        if q_scale is not None:
-            q = (q.astype(jnp.float32) * q_scale).astype(q_ref.dtype)
-        s = _scores(q, k, qi, kb, causal=causal, bq=bq, bk=bk)  # [BQ, BK]
-        m_prev = m_ref[:, 0]                             # [BQ]
-        m_blk = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp2(s - m_new[:, None])
-        alpha = jnp.exp2(m_prev - m_new)
-        # Running stats live in lane 0 only (reads are [:, 0]); the full
-        # 128-lane broadcast write was two extra [BQ,128] VPU passes per
-        # tile (~10% of fwd kernel time on v5e). Only the FINAL lse output
-        # below is lane-replicated — that's the wire format the backward's
-        # _row_spec tiles expect. (On-chip numerics + bench validated.)
-        l_ref[:, :1] = (l_ref[:, 0] * alpha
-                        + jnp.sum(p, axis=-1))[:, None]
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new[:, None]
-
-    @pl.when(kb == n_kb - 1)
-    def _finish():
-        l = l_ref[:, 0]
+    def finish():
+        l = l_ref[:]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / _lanes(safe, acc_ref.shape[1])).astype(
+            o_ref.dtype)
         if lse_ref is not None:
             # log2 domain, matching the backward's exp2 recompute.
-            lse = jnp.where(l == 0.0, -1e30, m_ref[:, 0] + jnp.log2(safe))
-            lse_ref[0] = lse[:, None] * jnp.ones_like(lse_ref[0])
+            lse = jnp.where(l == 0.0, -1e30, m_ref[:] + jnp.log2(safe))
+            lse_ref[0] = lse[:, :_STAT_LANES]
+
+    _for_each_pair(k_ref, v_ref, b=b, causal=causal, resident=resident,
+                   begin=init, visit=visit, end=finish)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, causal: bool, bq: int, bk: int,
-               qi_axis: int = 1, kb_axis: int = 2,
-               q_scale: Optional[float] = None,
-               dq_scale: float = _LN2):
-    """Grid (..., qi, kb): accumulate dq over the kb axis.
+def _bwd_visit(qs, q, do, lse, delta, kv, schedule, add_dq, add_dkv):
+    """The backward of one tile pair, block by block of ``schedule``.
 
-    Recomputes the probability tile from (q, k, lse) — the flash-backward
-    trade: [BQ, BK] tiles never leave VMEM.
-    dA = P ∘ (dO·Vᵀ − Δ), Δ = rowsum(dO ∘ O). Split path: q arrives
-    pre-scaled, dq_scale = ln2 (the caller's log2e·sm_scale prescale folds
-    the chain rule back to sm_scale). Packed path: q raw + q_scale set,
-    dq_scale = sm_scale directly.
-    """
-    qi = pl.program_id(qi_axis)
-    kb = pl.program_id(kb_axis)
-    n_kb = pl.num_programs(kb_axis)
+    Recomputes each probability block from (q, k, lse) — the
+    flash-backward trade: score blocks never leave VMEM.
+    dA = P ∘ (dO·Vᵀ − Δ), Δ = rowsum(dO ∘ O); unscaled contributions
+    dA·K go to ``add_dq(rows, ·)``, dAᵀ·Q (raw q) and Pᵀ·dO — for the
+    pair's first ``cols`` K/V rows — to ``add_dkv(cols, ·, ·)``; either
+    may be None (the split kernels)."""
+    for r0, n, w, masked in schedule:
+        r = slice(r0, r0 + n)
+        k, v = kv(w)
+        p = jnp.exp2(_scores(qs[r], k, masked) - lse[r])
+        ds = (p * (_dot(do[r], v, 1, 1) - delta[r])).astype(k.dtype)
+        if add_dq is not None:
+            add_dq(r, _dot(ds, k, 1, 0))
+        if add_dkv is not None:
+            add_dkv(w, _dot(ds, q[r], 0, 0),
+                    _dot(p.astype(do.dtype), do[r], 0, 0))
 
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = _causal_run(qi, kb, bq, bk) if causal else True
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                causal: bool, b: int, sub: int, resident: bool,
+                fused: bool, packed: bool, q_scale: float,
+                grad_scale: float):
+    """Backward for one [b, D] q tile, grid (g0, g1, qi[, kb]), with the
+    forward's two schedules (``_for_each_pair``).
 
-    @pl.when(run)
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        if q_scale is not None:
-            q = (q.astype(jnp.float32) * q_scale).astype(q_ref.dtype)
-        s = _scores(q, k, qi, kb, causal=causal, bq=bq, bk=bk)
-        p = jnp.exp2(s - lse_ref[0][:, :1])              # [BQ, BK]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    ``fused``: dq, dk and dv from ONE visit of each tile pair — s, p, dp
+    and ds are computed once (5 matmuls + 1 exp2 pass per block, against
+    7 + 2 over the split dq and dkv kernels). dq accumulates per q tile
+    in a [b, D] scratch; dk/dv accumulate over the whole (batch, head)
+    visit in full-T [T, D] f32 scratch and flush at its last step. That
+    costs 2·T·D f32 of VMEM, so callers fall back to the split kernels
+    when ``_fits_vmem`` says so. ``packed``: the single output block is
+    head h's column stripe ``[1, T, 3D]`` (q|k|v) of the packed gradient,
+    resident for the whole visit, so the gradient exists in exactly one
+    materialization. Not ``fused``: the split path's dq kernel."""
+    n_out = 1 if (packed or not fused) else 3
+    outs, dq_acc = rest[:n_out], rest[n_out]
+    dk_acc, dv_acc = rest[n_out + 1:] if fused else (None, None)
+    qi = pl.program_id(2)
+    n_qi = pl.num_programs(2)
+    d = q_ref.shape[-1]
+    q, do = q_ref[0], do_ref[0]
+    qs = _scaled(q_ref, q_scale)
+    lse, delta = lse_ref[0][:, :1], delta_ref[0][:, :1]
 
-    @pl.when(kb == n_kb - 1)
-    def _finish():
-        dq_ref[0] = (acc_ref[:] * dq_scale).astype(dq_ref.dtype)
+    def add_dq(r, x):
+        dq_acc[r, :] += x
+
+    def visit(kb, kv, diagonal):
+        def add_dkv(w, dk, dv):
+            rows = pl.ds(pl.multiple_of(kb * b, b), w)
+            dk_acc[rows, :] += dk
+            dv_acc[rows, :] += dv
+        _bwd_visit(qs, q, do, lse, delta, kv,
+                   _tile_schedule(b, sub, diagonal), add_dq,
+                   add_dkv if fused else None)
+
+    def init_kv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def write_q():
+        dq = (dq_acc[:] * grad_scale).astype(outs[0].dtype)
+        if packed and fused:
+            outs[0][0, pl.ds(pl.multiple_of(qi * b, b), b), 0:d] = dq
+        else:
+            outs[0][0] = dq
+
+    def write_kv():
+        dk = (dk_acc[:] * grad_scale).astype(outs[0].dtype)
+        dv = dv_acc[:].astype(outs[0].dtype)
+        if packed:
+            outs[0][0, :, d:2 * d] = dk
+            outs[0][0, :, 2 * d:3 * d] = dv
+        else:
+            outs[1][0] = dk
+            outs[2][0] = dv
+
+    def begin():
+        if fused:
+            pl.when(qi == 0)(init_kv)
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def end():
+        write_q()
+        if fused:
+            pl.when(qi == n_qi - 1)(write_kv)
+
+    _for_each_pair(k_ref, v_ref, b=b, causal=causal, resident=resident,
+                   begin=begin, visit=visit, end=end)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_acc, dv_acc, *, causal: bool,
-                bq: int, bk: int, kb_axis: int = 1, qi_axis: int = 2,
-                q_scale: Optional[float] = None,
-                dk_scale: float = _LN2):
-    """Grid (..., kb, qi): accumulate dk/dv for one K/V tile over all
-    contributing Q tiles. dV = Pᵀ·dO. Split path: dK = ln2 · dAᵀ·Q_scaled
-    (prescaled q makes ln2 the correct chain factor). Packed path: q raw
-    (scaled only for the score recompute), dK = sm_scale · dAᵀ·Q."""
-    kb = pl.program_id(kb_axis)
-    qi = pl.program_id(qi_axis)
-    n_qi = pl.num_programs(qi_axis)
+                dv_ref, dk_acc, dv_acc, *, causal: bool, b: int, sub: int,
+                q_scale: float, grad_scale: float):
+    """Split path, grid (g0, g1, kb, qi): dk/dv of one K/V tile over the
+    q tiles at and below the diagonal (the steps above it — they come
+    FIRST here — fetch nothing: ``_q_row`` clamps)."""
+    kb = pl.program_id(2)
+    qi = pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = _causal_run(qi, kb, bq, bk) if causal else True
+    def add_dkv(w, dk, dv):
+        dk_acc[:w, :] += dk
+        dv_acc[:w, :] += dv
 
-    @pl.when(run)
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        qs = q
-        if q_scale is not None:
-            qs = (q.astype(jnp.float32) * q_scale).astype(q_ref.dtype)
-        s = _scores(qs, k, qi, kb, causal=causal, bq=bq, bk=bk)
-        p = jnp.exp2(s - lse_ref[0][:, :1])              # [BQ, BK]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # Pᵀ·dO [BK, D]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # dAᵀ·Q [BK, D]
+    def visit(diagonal):
+        _bwd_visit(_scaled(q_ref, q_scale), q_ref[0], do_ref[0],
+                   lse_ref[0][:, :1], delta_ref[0][:, :1],
+                   lambda w: (k_ref[0, :w, :], v_ref[0, :w, :]),
+                   _tile_schedule(b, sub, diagonal), None, add_dkv)
 
-    @pl.when(qi == n_qi - 1)
+    if causal:
+        pl.when(qi == kb)(lambda: visit(True))
+        pl.when(qi > kb)(lambda: visit(False))
+    else:
+        visit(False)
+
+    @pl.when(qi == pl.num_programs(3) - 1)
     def _finish():
-        dk_ref[0] = (dk_acc[:] * dk_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                 causal: bool, bq: int, bk: int,
-                 qi_axis: int = 1, kb_axis: int = 2,
-                 q_scale: Optional[float] = None,
-                 grad_scale: float = _LN2):
-    """Fused single-pass backward: dq, dk and dv from ONE visit of each
-    (qi, kb) tile pair.
-
-    The split dq / dkv kernels each recompute the probability tile and the
-    dO·Vᵀ matmul and each stream q/k/v/do from HBM — and the kernels are
-    VPU-softmax-bound (measured fwd 41 vs matmul 157 TF/s), so the second
-    exp2 recompute pass is pure waste. Here one grid (…, qi, kb) computes
-    s/p/dp/ds once per pair: dq accumulates per-qi in a [BQ, D] scratch
-    (written at the kb edge, as before), while dk/dv accumulate into
-    full-T [T, D] f32 VMEM scratch across the whole (qi, kb) space and
-    are flushed once per (batch, head) at the final step. Halves the
-    softmax recompute, the dp matmul and the HBM streaming of the backward
-    (7 matmuls + 2 exp2 passes per pair across two kernels -> 5 + 1).
-    Costs 2·T·D f32 of VMEM (1 MiB per 2048×128) — callers fall back to
-    the split kernels when ``_fused_bwd_fits`` says the residents exceed
-    the per-core VMEM budget.
-    """
-    qi = pl.program_id(qi_axis)
-    kb = pl.program_id(kb_axis)
-    n_qi = pl.num_programs(qi_axis)
-    n_kb = pl.num_programs(kb_axis)
-
-    @pl.when((qi == 0) & (kb == 0))
-    def _init_kv():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    @pl.when(kb == 0)
-    def _init_q():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    run = _causal_run(qi, kb, bq, bk) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        qs = q
-        if q_scale is not None:
-            qs = (q.astype(jnp.float32) * q_scale).astype(q_ref.dtype)
-        s = _scores(qs, k, qi, kb, causal=causal, bq=bq, bk=bk)
-        p = jnp.exp2(s - lse_ref[0][:, :1])              # [BQ, BK]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dsc = ds.astype(k.dtype)
-        dq_acc[:] += jax.lax.dot_general(
-            dsc, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        rows = pl.ds(kb * bk, bk)
-        dv_acc[rows, :] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # Pᵀ·dO
-        dk_acc[rows, :] += jax.lax.dot_general(
-            dsc, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # dSᵀ·Q
-
-    @pl.when(kb == n_kb - 1)
-    def _fin_q():
-        dq_ref[0] = (dq_acc[:] * grad_scale).astype(dq_ref.dtype)
-
-    @pl.when((qi == n_qi - 1) & (kb == n_kb - 1))
-    def _fin_kv():
         dk_ref[0] = (dk_acc[:] * grad_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _dqkv_packed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dqkv_ref, dq_acc, dk_acc, dv_acc, *,
-                        causal: bool, bq: int, bk: int, d: int,
-                        q_scale: float, grad_scale: float):
-    """Packed-path fused backward writing the gradient DIRECTLY in the
-    projection's packed column layout.
-
-    Grid (B, H, qi, kb); the single output block is head h's full packed
-    column stripe ``[1, T, 3D]`` of d_qkv (columns q|k|v), grid-constant
-    over (qi, kb) so it lives in VMEM for the whole (batch, head) visit:
-    dq rows land at each qi edge, dk/dv flush from the full-T accumulators
-    at the end. This removes the stack+reshape interleave the previous
-    backward needed (measured ~0.52 ms/layer of concatenate fusions plus
-    the copies around three [B,T,H*D] intermediates — the gradient now
-    exists in exactly one materialization).
-    """
-    qi = pl.program_id(2)
-    kb = pl.program_id(3)
-    n_qi = pl.num_programs(2)
-    n_kb = pl.num_programs(3)
-
-    @pl.when((qi == 0) & (kb == 0))
-    def _init_kv():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    @pl.when(kb == 0)
-    def _init_q():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    run = _causal_run(qi, kb, bq, bk) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        qs = (q.astype(jnp.float32) * q_scale).astype(q_ref.dtype)
-        s = _scores(qs, k, qi, kb, causal=causal, bq=bq, bk=bk)
-        p = jnp.exp2(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dsc = ds.astype(k.dtype)
-        dq_acc[:] += jax.lax.dot_general(
-            dsc, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        rows = pl.ds(kb * bk, bk)
-        dv_acc[rows, :] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[rows, :] += jax.lax.dot_general(
-            dsc, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(kb == n_kb - 1)
-    def _fin_q():
-        dqkv_ref[0, pl.ds(qi * bq, bq), 0:d] = \
-            (dq_acc[:] * grad_scale).astype(dqkv_ref.dtype)
-
-    @pl.when((qi == n_qi - 1) & (kb == n_kb - 1))
-    def _fin_kv():
-        dqkv_ref[0, :, d:2 * d] = \
-            (dk_acc[:] * grad_scale).astype(dqkv_ref.dtype)
-        dqkv_ref[0, :, 2 * d:3 * d] = dv_acc[:].astype(dqkv_ref.dtype)
-
-
-# Scoped VMEM one fused-backward kernel may claim. 16 MiB is the v5e
-# compiler's own default scoped limit; the fused kernels also pass this
-# number as their ``vmem_limit_bytes``, so the gate below and the compiler
-# hold the same limit (an override moves both). Read once at import so
-# every rank traces the same graph — a trace-time env read could diverge
-# across ranks (the HVD_FUSED_PARTS lesson, ADVICE r5).
+# Scoped VMEM one kernel may claim. 16 MiB is the v5e compiler's own
+# default scoped limit; the kernels with whole-sequence residents also
+# pass this number as their ``vmem_limit_bytes``, so the gate below and
+# the compiler hold the same limit (an override moves both). Read once at
+# import so every rank traces the same graph — a trace-time env read could
+# diverge across ranks (the HVD_FUSED_PARTS lesson, ADVICE r5).
 _VMEM_BUDGET_BYTES = int(os.environ.get("HVD_VMEM_BUDGET_MB", "16")) * 2**20
 
 
-def _fused_bwd_fits(T: int, D: int, itemsize: int, *, bq: int, bk: int,
-                    packed: bool) -> bool:
-    """Whether the fused single-pass backward fits the kernel's scoped
-    VMEM limit — the gate deciding fused vs split dq/dkv kernels.
+def _fits_vmem(T: int, D: int, itemsize: int, *, b: int, bwd: bool,
+               kv_resident: bool, packed: bool = False) -> bool:
+    """Whether a kernel with whole-sequence residents fits its scoped VMEM
+    limit — the ONE gate that picks the schedule from the shape: K/V
+    resident (in-kernel loop) or streamed for the forward (``bwd=False``)
+    and for the fused backward, and fused or split backward.
 
-    The fused kernel's full-T dk/dv accumulators make its footprint grow
-    with sequence length. The sum below is what the v5e compiler
-    allocates, checked against it at H=16 for D in {128, 256}, bf16 and
-    f32, T = 128..16384 (the smallest ``vmem_limit_bytes`` that compiles
-    sits within the margin of this sum; ``tests/test_tpu_compile.py``
-    keeps the near-boundary shapes compiling):
+    The sum is what the v5e compiler allocates, checked against it at
+    H=16 for D in {128, 256}, bf16 and f32, T = 128..16384 (the smallest
+    ``vmem_limit_bytes`` that compiles sits within the margin of this
+    sum; ``tests/test_tpu_compile.py`` keeps the near-boundary shapes
+    compiling):
 
-    * scratch: dq_acc [bq, D] + dk/dv accumulators 2×[T, D], all f32;
-    * output block(s) in the input dtype, DOUBLE-buffered like every
-      pipelined operand even though they are grid-constant over a
-      (batch, head) visit: packed [T, 3D] vs split dq [bq, D] + full-T
-      dk/dv 2×[T, D];
-    * streamed input tiles (q/do [bq, D], k/v [bk, D]) and the two f32
-      stat tiles, which occupy a full 128-lane tile in VMEM whatever
-      ``_STAT_LANES`` says — all double-buffered;
-    * margin: three [bq, bk] f32 intermediates (scores/probabilities, dp,
-      ds) the compiler keeps on its stack.
+    * scratch, f32: forward acc [b, D] + two stat tiles; fused backward
+      dq_acc [b, D] + the dk/dv accumulators 2×[T, D];
+    * pipelined operands, DOUBLE-buffered even where they are constant
+      over a (batch, head) visit: the q (and do) tile, the f32 stat tiles
+      (a full 128-lane tile in VMEM whatever ``_STAT_LANES`` says), K and
+      V — whole [T, D] when resident, one tile each when streamed — and
+      the outputs: forward o tile + lse; backward packed [T, 3D], else
+      the dq tile + full-T dk/dv;
+    * stack: the [b, b] f32 intermediates (scores/probabilities, dp, ds)
+      the compiler keeps there; the backward's [b, D] f32 products fit in
+      their slack at D = 128 and need room of their own beyond it.
     """
-    scratch = 4 * (bq * D + 2 * T * D)
-    out = (T * 3 * D if packed else (bq + 2 * T) * D) * itemsize
-    tiles = ((2 * bq + 2 * bk) * D * itemsize
-             + 2 * bq * 128 * 4)
-    margin = 3 * bq * bk * 4
-    return scratch + 2 * out + 2 * tiles + margin <= _VMEM_BUDGET_BYTES
+    tile, full = b * D * itemsize, T * D * itemsize
+    stat, stack = b * 128 * 4, 3 * b * b * 4
+    kv = 2 * (full if kv_resident else tile)
+    if bwd:
+        scratch = 4 * (b * D + 2 * T * D)
+        piped = (2 * tile + 2 * stat + kv
+                 + (3 * full if packed else tile + 2 * full))
+        stack += 6 * b * (D - 128) * 4
+    else:
+        scratch = 4 * b * D + 2 * stat
+        piped = 2 * tile + stat + kv
+    return scratch + 2 * piped + stack <= _VMEM_BUDGET_BYTES
+
+
+def _bwd_plan(T: int, D: int, itemsize: int, *, b: int, packed: bool) -> str:
+    """Which backward a shape gets: the fused kernel with K/V ``resident``,
+    the fused kernel with K/V ``streamed``, or the ``split`` dq and dkv
+    kernels (long sequences)."""
+    fits = functools.partial(_fits_vmem, T, D, itemsize, b=b, bwd=True,
+                             packed=packed)
+    if fits(kv_resident=True):
+        return "resident"
+    return "streamed" if fits(kv_resident=False) else "split"
 
 
 # Lane width of the per-row stat tensors (lse, delta) on the wire between
@@ -436,322 +441,265 @@ def _fused_bwd_fits(T: int, D: int, itemsize: int, *, bq: int, bk: int,
 _STAT_LANES = 8
 
 
-def _row_spec(block_rows, which):
-    """BlockSpec for per-row stats [BH, T, _STAT_LANES]; kernels read
-    column 0 only."""
-    return pl.BlockSpec((1, block_rows, _STAT_LANES), which)
+# ---------------------------------------------------------------------------
+# Two array layouts, one set of kernels. Every kernel's grid starts
+# (g0, g1): the packed layout consumes the fused QKV projection output
+# [B, T, H*3*D] (HEAD-major columns, i.e. reshape [B, T, H, 3, D])
+# DIRECTLY through BlockSpec index maps with (g0, g1) = (batch, head) — no
+# [B,T,H,D] <-> [BH,T,D] transposes on either side of the kernels — and
+# returns the attention output as [B, T, H*D], exactly what the output
+# projection consumes. The [BH, T, D] layout runs the same kernels with
+# (g0, g1) = (batch·head, 0). Stats are [B*H, T, _STAT_LANES] in both.
+# ---------------------------------------------------------------------------
 
 
-def _fwd_pallas(q, k, v, causal: bool, interpret: bool,
-                with_lse: bool = True):
-    """q/k/v: [BH, T, D], q PRE-SCALED by sm_scale*log2e ->
-    (o [BH, T, D], lse2 [BH, T, _STAT_LANES] f32 | None).
+class _Layout:
+    """Block index maps of one array layout. ``H`` None: q/k/v/o are
+    separate [BH, T, D] arrays; else packed columns for H heads."""
 
-    ``with_lse=False`` (the no-grad primal) drops the lse output — Mosaic
-    can't dead-code-eliminate an output buffer, and at long T the f32 lse
-    write outweighs the bf16 output itself."""
-    BH, T, D = q.shape
-    bq = _pick_block(T, _WANT_BQ)
-    bk = _pick_block(T, _WANT_BK)
-    grid = (BH, T // bq, T // bk)
-    base = functools.partial(_attn_kernel, causal=causal, bq=bq, bk=bk)
-    if with_lse:
-        kernel = base
-        out_specs = [
-            pl.BlockSpec((1, bq, D), lambda bh, qi, kb: (bh, qi, 0)),
-            _row_spec(bq, lambda bh, qi, kb: (bh, qi, 0)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, _STAT_LANES), jnp.float32),
-        ]
+    def __init__(self, H: Optional[int], D: int):
+        self.H, self.D = H, D
+
+    def grid(self, lead: int):
+        return (lead, 1) if self.H is None else (lead, self.H)
+
+    def spec(self, kind: str, rows: int, row):
+        """BlockSpec of ``rows`` rows of operand ``kind`` (q, k, v: the
+        inputs; o: an output-shaped [.., H*D] array; stat); ``row`` maps
+        the grid's trailing indices to the row-block index."""
+        H = self.H
+        if kind == "stat":
+            return pl.BlockSpec(
+                (1, rows, _STAT_LANES),
+                lambda g0, g1, *ij: (g0 if H is None else g0 * H + g1,
+                                     row(*ij), 0))
+        col = {"q": 0, "k": 1, "v": 2}.get(kind)
+        return pl.BlockSpec(
+            (1, rows, self.D),
+            lambda g0, g1, *ij: (
+                g0, row(*ij),
+                0 if H is None else (g1 if col is None else g1 * 3 + col)))
+
+
+def _qi_row(qi, *kb):
+    """Row-block map of the q-side tiles on grid (.., qi[, kb])."""
+    return qi
+
+
+def _kv_row(causal):
+    """Row-block map of a streamed K/V tile on grid (.., qi, kb): clamped
+    at the diagonal, so a step above it re-names the tile already in VMEM
+    and nothing is fetched."""
+    return (lambda qi, kb: jnp.minimum(kb, qi)) if causal \
+        else (lambda qi, kb: kb)
+
+
+def _q_row(causal):
+    """The same for the q-side tiles of the dkv kernel's grid (.., kb, qi),
+    where the dead steps come first."""
+    return (lambda kb, qi: jnp.maximum(qi, kb)) if causal \
+        else (lambda kb, qi: qi)
+
+
+def _layout_of(q, H: Optional[int]):
+    """(_Layout, T) of the q array (or of the packed array standing in for
+    q, k and v alike)."""
+    D = q.shape[-1] if H is None else q.shape[-1] // (3 * H)
+    return _Layout(H, D), q.shape[1]
+
+
+# The forward and the backward are jitted by themselves: a model calls them
+# once per layer with the same shapes, and jit's cache then traces and
+# lowers each kernel body ONCE per program instead of once per layer — the
+# unrolled diagonal strips make the bodies several times longer to trace
+# than one tile pair's (PERF.md PR 27: the LM cell's set-up).
+@functools.partial(jax.jit, static_argnames=(
+    "H", "causal", "q_scale", "interpret", "with_lse"))
+def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
+         interpret: bool, with_lse: bool = True):
+    """-> (o, lse2 [B*H, T, _STAT_LANES] f32 | None). ``with_lse=False``
+    (the no-grad primal) drops the lse output — Mosaic can't
+    dead-code-eliminate an output buffer, and at long T the f32 lse write
+    outweighs the bf16 output itself."""
+    lay, T = _layout_of(q, H)
+    D = lay.D
+    b, sub = _blocks(T)
+    lead = q.shape[0]
+    n_heads = lead if lay.H is None else lead * lay.H
+    resident = _fits_vmem(T, D, q.dtype.itemsize, b=b, bwd=False,
+                          kv_resident=True)
+    if resident:
+        grid = lay.grid(lead) + (T // b,)
+        kv_specs = [lay.spec(x, T, lambda qi: 0) for x in "kv"]
+        semantics = ("parallel",) * 3
     else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
-            base(q_ref, k_ref, v_ref, o_ref, None, acc_ref, m_ref, l_ref)
-        out_specs = pl.BlockSpec((1, bq, D), lambda bh, qi, kb: (bh, qi, 0))
-        out_shape = jax.ShapeDtypeStruct((BH, T, D), q.dtype)
+        grid = lay.grid(lead) + (T // b, T // b)
+        kv_specs = [lay.spec(x, b, _kv_row(causal)) for x in "kv"]
+        semantics = ("parallel",) * 3 + ("arbitrary",)
+    o_cols = D if lay.H is None else lay.H * D
+    out_specs = [lay.spec("o", b, _qi_row)]
+    out_shape = [jax.ShapeDtypeStruct(q.shape[:2] + (o_cols,), q.dtype)]
+    if with_lse:
+        out_specs.append(lay.spec("stat", b, _qi_row))
+        out_shape.append(jax.ShapeDtypeStruct((n_heads, T, _STAT_LANES),
+                                              jnp.float32))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, causal=causal, b=b, sub=sub,
+                          resident=resident, q_scale=q_scale,
+                          with_lse=with_lse),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, qi, kb: (bh, kb, 0)),
-        ],
+        in_specs=[lay.spec("q", b, _qi_row)] + kv_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),            # acc
-            pltpu.VMEM((bq, 128), jnp.float32),          # running max
-            pltpu.VMEM((bq, 128), jnp.float32),          # running sum
+            pltpu.VMEM((b, D), jnp.float32),            # acc
+            pltpu.VMEM((b, 128), jnp.float32),          # running max
+            pltpu.VMEM((b, 128), jnp.float32),          # running sum
         ],
-        compiler_params=_grid_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_grid_params(
+            semantics, _VMEM_BUDGET_BYTES if resident else None),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
-    return (out if with_lse else (out, None))
+    return out if with_lse else (out[0], None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_core(q, k, v, causal: bool, interpret: bool):
-    """q arrives pre-scaled by sm_scale*log2e (see _flash_bhtd); the VJP
-    therefore returns dq in the SCALED domain and jax's chain rule through
-    the caller's multiply restores the true dq."""
-    o, _ = _fwd_pallas(q, k, v, causal, interpret, with_lse=False)
-    return o
+@functools.partial(jax.jit, static_argnames=(
+    "H", "causal", "q_scale", "grad_scale", "interpret"))
+def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
+         q_scale: float, grad_scale: float, interpret: bool):
+    """Gradients of the three inputs: one packed [B, T, H*3*D] array from
+    the fused kernel in the packed layout, else (dq, dk, dv) shaped like
+    ``o``, by the kernels ``_bwd_plan`` picks."""
+    lay, T = _layout_of(q, H)
+    D, packed = lay.D, H is not None
+    b, sub = _blocks(T)
+    lead, n_t = q.shape[0], T // b
+    # Δ_i = Σ_d dO ∘ O — cheap elementwise reduction, XLA fuses it;
+    # widened to _STAT_LANES like lse so the kernels read [b, 8] tiles.
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if packed:
+        delta = prod.reshape(lead, T, lay.H, D).sum(-1).transpose(0, 2, 1)
+    else:
+        delta = prod.sum(-1)
+    delta = jnp.broadcast_to(delta.reshape(-1, T, 1),
+                             (lse.shape[0], T, _STAT_LANES))
+    plan = _bwd_plan(T, D, q.dtype.itemsize, b=b, packed=packed)
+    resident, fused = plan == "resident", plan != "split"
+    kernel = functools.partial(
+        _bwd_kernel, causal=causal, b=b, sub=sub, resident=resident,
+        fused=fused, packed=packed, q_scale=q_scale, grad_scale=grad_scale)
+    q_side = [lay.spec("q", b, _qi_row)]
+    tail = [lay.spec("o", b, _qi_row), lay.spec("stat", b, _qi_row),
+            lay.spec("stat", b, _qi_row)]
+    if resident:
+        grid = lay.grid(lead) + (n_t,)
+        kv_specs = [lay.spec(x, T, lambda qi: 0) for x in "kv"]
+        semantics = ("parallel", "parallel", "arbitrary")
+    else:
+        grid = lay.grid(lead) + (n_t, n_t)
+        kv_specs = [lay.spec(x, b, _kv_row(causal)) for x in "kv"]
+        semantics = ("parallel", "parallel",
+                     "arbitrary" if fused else "parallel", "arbitrary")
+    o_like = jax.ShapeDtypeStruct(o.shape, q.dtype)
+    dq_acc = pltpu.VMEM((b, D), jnp.float32)
+    if fused:
+        if packed:
+            out_specs = pl.BlockSpec((1, T, 3 * D),
+                                     lambda g0, g1, *ij: (g0, 0, g1))
+            out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        else:
+            whole = lay.spec("o", T, lambda *ij: 0)
+            out_specs = [lay.spec("o", b, _qi_row), whole, whole]
+            out_shape = [o_like] * 3
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=q_side + kv_specs + tail,
+            out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=[dq_acc, pltpu.VMEM((T, D), jnp.float32),
+                            pltpu.VMEM((T, D), jnp.float32)],
+            compiler_params=_grid_params(semantics, _VMEM_BUDGET_BYTES),
+            interpret=interpret, name="flash_bwd",
+        )(q, k, v, do, lse, delta)
+    dq = pl.pallas_call(
+        kernel, grid=grid, in_specs=q_side + kv_specs + tail,
+        out_specs=lay.spec("o", b, _qi_row), out_shape=o_like,
+        scratch_shapes=[dq_acc],
+        compiler_params=_grid_params(semantics),
+        interpret=interpret, name="flash_bwd_dq",
+    )(q, k, v, do, lse, delta)
+    # dk/dv iterate the OTHER way: grid (.., kb, qi), one K/V tile
+    # accumulated over q tiles.
+    krow = lambda kb, qi: kb                                 # noqa: E731
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, causal=causal, b=b, sub=sub,
+                          q_scale=q_scale, grad_scale=grad_scale),
+        grid=lay.grid(lead) + (n_t, n_t),
+        in_specs=[lay.spec("q", b, _q_row(causal)),
+                  lay.spec("k", b, krow), lay.spec("v", b, krow),
+                  lay.spec("o", b, _q_row(causal)),
+                  lay.spec("stat", b, _q_row(causal)),
+                  lay.spec("stat", b, _q_row(causal))],
+        out_specs=[lay.spec("o", b, krow)] * 2,
+        out_shape=[o_like] * 2,
+        scratch_shapes=[pltpu.VMEM((b, D), jnp.float32),
+                        pltpu.VMEM((b, D), jnp.float32)],
+        compiler_params=_grid_params(
+            ("parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="flash_bwd_dkv",
+    )(q, k, v, do, lse, delta)
+    if not packed:
+        return dq, dk, dv
+    # Interleave back into the packed head-major (H, 3, D) column layout.
+    return jnp.stack([g.reshape(lead, T, lay.H, D) for g in (dq, dk, dv)],
+                     axis=3).reshape(q.shape)
 
 
-def _flash_core_fwd(q, k, v, causal, interpret):
-    o, lse = _fwd_pallas(q, k, v, causal, interpret)
-    # lse is already the narrow [BH, T, _STAT_LANES] wire format; keep it
-    # whole in the residuals (slicing to one lane and re-broadcasting in
-    # backward would cost two device copies to save 7 f32 lanes).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_core(q, k, v, causal: bool, sm_scale: float, interpret: bool):
+    """q/k/v: [BH, T, D] -> o [BH, T, D]."""
+    return _flash_core_fwd(q, k, v, causal, sm_scale, interpret,
+                           with_lse=False)[0]
+
+
+def _flash_core_fwd(q, k, v, causal, sm_scale, interpret, with_lse=True):
+    o, lse = _fwd(q, k, v, H=None, causal=causal, q_scale=sm_scale * LOG2E,
+                  interpret=interpret, with_lse=with_lse)
+    # lse stays in the narrow [BH, T, _STAT_LANES] wire format in the
+    # residuals (slicing to one lane and re-broadcasting in backward would
+    # cost two device copies to save 7 f32 lanes).
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(causal, interpret, res, do):
+def _flash_core_bwd(causal, sm_scale, interpret, res, do):
     q, k, v, o, lse = res
-    BH, T, D = q.shape
-    bq = _pick_block(T, _WANT_BQ)
-    bk = _pick_block(T, _WANT_BK)
-    # Δ_i = Σ_d dO ∘ O — cheap elementwise reduction, XLA fuses it;
-    # widened to _STAT_LANES like lse so the kernels read [BQ, 8] tiles.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)              # [BH, T, 1]
-    delta = jnp.broadcast_to(delta, (BH, T, _STAT_LANES))
-    qkv_spec_q = pl.BlockSpec((1, bq, D), lambda bh, qi, kb: (bh, qi, 0))
-    qkv_spec_k = pl.BlockSpec((1, bk, D), lambda bh, qi, kb: (bh, kb, 0))
-    if _fused_bwd_fits(T, D, q.dtype.itemsize, bq=bq, bk=bk, packed=False):
-        full = pl.BlockSpec((1, T, D), lambda bh, qi, kb: (bh, 0, 0))
-        return pl.pallas_call(
-            functools.partial(_dqkv_kernel, causal=causal, bq=bq, bk=bk),
-            grid=(BH, T // bq, T // bk),
-            in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k, qkv_spec_q,
-                      _row_spec(bq, lambda bh, qi, kb: (bh, qi, 0)),
-                      _row_spec(bq, lambda bh, qi, kb: (bh, qi, 0))],
-            out_specs=[qkv_spec_q, full, full],
-            out_shape=[
-                jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-                jax.ShapeDtypeStruct((BH, T, D), k.dtype),
-                jax.ShapeDtypeStruct((BH, T, D), v.dtype),
-            ],
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32)],
-            compiler_params=_grid_params(
-                ("parallel", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=_VMEM_BUDGET_BYTES),
-            interpret=interpret,
-            name="flash_bwd",
-        )(q, k, v, do, lse, delta)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, bq=bq, bk=bk),
-        grid=(BH, T // bq, T // bk),
-        in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k, qkv_spec_q,
-                  _row_spec(bq, lambda bh, qi, kb: (bh, qi, 0)),
-                  _row_spec(bq, lambda bh, qi, kb: (bh, qi, 0))],
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi, kb: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_grid_params(("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(q, k, v, do, lse, delta)
-
-    # dk/dv iterate the OTHER way: one K/V tile accumulated over Q tiles.
-    kv_q = pl.BlockSpec((1, bq, D), lambda bh, kb, qi: (bh, qi, 0))
-    kv_k = pl.BlockSpec((1, bk, D), lambda bh, kb, qi: (bh, kb, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, bq=bq, bk=bk),
-        grid=(BH, T // bk, T // bq),
-        in_specs=[kv_q, kv_k, kv_k, kv_q,
-                  _row_spec(bq, lambda bh, kb, qi: (bh, qi, 0)),
-                  _row_spec(bq, lambda bh, kb, qi: (bh, qi, 0))],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, kb, qi: (bh, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=_grid_params(("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return _bwd(q, k, v, o, lse, do, H=None, causal=causal,
+                q_scale=sm_scale * LOG2E, grad_scale=sm_scale,
+                interpret=interpret)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-# ---------------------------------------------------------------------------
-# Packed-qkv path: consume the fused QKV projection output [B, T, H*3*D]
-# (HEAD-major columns, i.e. reshape [B, T, H, 3, D]) DIRECTLY via BlockSpec
-# index maps — no [B,T,H,D] -> [BH,T,D] transposes on either side of the
-# kernels (measured ~11 ms/step of layout copies at the LM bench config).
-# The attention output comes back as [B, T, H*D], exactly what the output
-# projection consumes. q is scaled inside the kernels (a [BQ,D] pass).
-# ---------------------------------------------------------------------------
-
-
-def _qkv_specs(H, D, bq, bk):
-    """BlockSpecs into the packed [B, T, H*3*D] array for grid
-    (B, H, qi, kb): column block (h*3 + kind) is head h's q/k/v slice."""
-    q = pl.BlockSpec((1, bq, D), lambda b, h, qi, kb: (b, qi, h * 3 + 0))
-    k = pl.BlockSpec((1, bk, D), lambda b, h, qi, kb: (b, kb, h * 3 + 1))
-    v = pl.BlockSpec((1, bk, D), lambda b, h, qi, kb: (b, kb, h * 3 + 2))
-    return q, k, v
-
-
-def _fwd_pallas_qkv(qkv, H, D, causal, sm_scale, interpret,
-                    with_lse=True):
-    B, T, _ = qkv.shape
-    bq = _pick_block(T, _WANT_BQ)
-    bk = _pick_block(T, _WANT_BK)
-    grid = (B, H, T // bq, T // bk)
-    c = sm_scale * LOG2E
-    base = functools.partial(_attn_kernel, causal=causal, bq=bq, bk=bk,
-                             qi_axis=2, kb_axis=3, q_scale=c)
-    sq, sk, sv = _qkv_specs(H, D, bq, bk)
-    o_spec = pl.BlockSpec((1, bq, D), lambda b, h, qi, kb: (b, qi, h))
-    # Stats shaped [B*H, T, S]: index maps may do arithmetic on grid ids.
-    stat_spec = pl.BlockSpec((1, bq, _STAT_LANES),
-                             lambda b, h, qi, kb: (b * H + h, qi, 0))
-    if with_lse:
-        kernel = base
-        out_specs = [o_spec, stat_spec]
-        out_shape = [
-            jax.ShapeDtypeStruct((B, T, H * D), qkv.dtype),
-            jax.ShapeDtypeStruct((B * H, T, _STAT_LANES), jnp.float32),
-        ]
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
-            base(q_ref, k_ref, v_ref, o_ref, None, acc_ref, m_ref, l_ref)
-        out_specs = o_spec
-        out_shape = jax.ShapeDtypeStruct((B, T, H * D), qkv.dtype)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[sq, sk, sv],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-        ],
-        compiler_params=_grid_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_fwd",
-    )(qkv, qkv, qkv)
-    return (out if with_lse else (out, None))
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
 def _flash_qkv_core(qkv, H: int, causal: bool, sm_scale: float,
                     interpret: bool):
-    D = qkv.shape[-1] // (3 * H)
-    o, _ = _fwd_pallas_qkv(qkv, H, D, causal, sm_scale, interpret,
-                           with_lse=False)
-    return o
+    """qkv: packed [B, T, H*3*D] -> o [B, T, H*D]."""
+    return _flash_qkv_core_fwd(qkv, H, causal, sm_scale, interpret,
+                               with_lse=False)[0]
 
 
-def _flash_qkv_core_fwd(qkv, H, causal, sm_scale, interpret):
-    D = qkv.shape[-1] // (3 * H)
-    o, lse = _fwd_pallas_qkv(qkv, H, D, causal, sm_scale, interpret)
+def _flash_qkv_core_fwd(qkv, H, causal, sm_scale, interpret, with_lse=True):
+    o, lse = _fwd(qkv, qkv, qkv, H=H, causal=causal,
+                  q_scale=sm_scale * LOG2E, interpret=interpret,
+                  with_lse=with_lse)
     return o, (qkv, o, lse)
 
 
 def _flash_qkv_core_bwd(H, causal, sm_scale, interpret, res, do):
     qkv, o, lse = res
-    B, T, _ = qkv.shape
-    D = qkv.shape[-1] // (3 * H)
-    bq = _pick_block(T, _WANT_BQ)
-    bk = _pick_block(T, _WANT_BK)
-    c = sm_scale * LOG2E
-    delta = jnp.sum(
-        (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-            B, T, H, D),
-        axis=-1)                                        # [B, T, H]
-    delta = jnp.broadcast_to(
-        delta.transpose(0, 2, 1).reshape(B * H, T, 1),
-        (B * H, T, _STAT_LANES))
-    sq, sk, sv = _qkv_specs(H, D, bq, bk)
-    do_q = pl.BlockSpec((1, bq, D), lambda b, h, qi, kb: (b, qi, h))
-    stat_q = pl.BlockSpec((1, bq, _STAT_LANES),
-                          lambda b, h, qi, kb: (b * H + h, qi, 0))
-    if _fused_bwd_fits(T, D, qkv.dtype.itemsize, bq=bq, bk=bk, packed=True):
-        packed = pl.BlockSpec((1, T, 3 * D), lambda b, h, qi, kb: (b, 0, h))
-        d_qkv = pl.pallas_call(
-            functools.partial(_dqkv_packed_kernel, causal=causal, bq=bq,
-                              bk=bk, d=D, q_scale=c, grad_scale=sm_scale),
-            grid=(B, H, T // bq, T // bk),
-            in_specs=[sq, sk, sv, do_q, stat_q, stat_q],
-            out_specs=packed,
-            out_shape=jax.ShapeDtypeStruct((B, T, H * 3 * D), qkv.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32)],
-            compiler_params=_grid_params(
-                ("parallel", "parallel", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=_VMEM_BUDGET_BYTES),
-            interpret=interpret,
-            name="flash_bwd",
-        )(qkv, qkv, qkv, do, lse, delta)
-        return (d_qkv,)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, bq=bq, bk=bk,
-                          qi_axis=2, kb_axis=3, q_scale=c,
-                          dq_scale=sm_scale),
-        grid=(B, H, T // bq, T // bk),
-        in_specs=[sq, sk, sv, do_q, stat_q, stat_q],
-        out_specs=pl.BlockSpec((1, bq, D),
-                               lambda b, h, qi, kb: (b, qi, h)),
-        out_shape=jax.ShapeDtypeStruct((B, T, H * D), qkv.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_grid_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qkv, qkv, qkv, do, lse, delta)
-
-    # dk/dv iterate the OTHER way: grid (B, H, kb, qi).
-    kv_sq = pl.BlockSpec((1, bq, D), lambda b, h, kb, qi: (b, qi, h * 3))
-    kv_sk = pl.BlockSpec((1, bk, D),
-                         lambda b, h, kb, qi: (b, kb, h * 3 + 1))
-    kv_sv = pl.BlockSpec((1, bk, D),
-                         lambda b, h, kb, qi: (b, kb, h * 3 + 2))
-    kv_do = pl.BlockSpec((1, bq, D), lambda b, h, kb, qi: (b, qi, h))
-    kv_stat = pl.BlockSpec((1, bq, _STAT_LANES),
-                           lambda b, h, kb, qi: (b * H + h, qi, 0))
-    kv_out = pl.BlockSpec((1, bk, D), lambda b, h, kb, qi: (b, kb, h))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, bq=bq, bk=bk,
-                          kb_axis=2, qi_axis=3, q_scale=c,
-                          dk_scale=sm_scale),
-        grid=(B, H, T // bk, T // bq),
-        in_specs=[kv_sq, kv_sk, kv_sv, kv_do, kv_stat, kv_stat],
-        out_specs=[kv_out, kv_out],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, H * D), qkv.dtype),
-            jax.ShapeDtypeStruct((B, T, H * D), qkv.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=_grid_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(qkv, qkv, qkv, do, lse, delta)
-    # Interleave back into the packed head-major (H, 3, D) column layout.
-    d_qkv = jnp.stack(
-        [g.reshape(B, T, H, D) for g in (dq, dk, dv)],
-        axis=3).reshape(B, T, H * 3 * D)
-    return (d_qkv,)
+    return (_bwd(qkv, qkv, qkv, o, lse, do, H=H, causal=causal,
+                 q_scale=sm_scale * LOG2E, grad_scale=sm_scale,
+                 interpret=interpret),)
 
 
 _flash_qkv_core.defvjp(_flash_qkv_core_fwd, _flash_qkv_core_bwd)
@@ -794,18 +742,15 @@ def flash_attention_qkv(qkv, n_heads: int, *, causal: bool = False,
                                              "interpret"))
 def _flash_bhtd(q, k, v, causal: bool, sm_scale: float, interpret: bool):
     """q/k/v: [BH, T, D] -> [BH, T, D]. Differentiable (custom VJP with
-    Pallas dq/dkv kernels — the score matrix never touches HBM in either
-    direction). q is pre-scaled here (one cheap [BH,T,D] pass) so the
-    kernels run scale-free in the log2 domain; jax's chain rule through
-    this multiply restores the true dq from the kernel's scaled-domain
-    output."""
-    q = (q.astype(jnp.float32) * (sm_scale * LOG2E)).astype(q.dtype)
-    return _flash_core(q, k, v, causal, interpret)
+    Pallas backward kernels — the score matrix never touches HBM in
+    either direction)."""
+    return _flash_core(q, k, v, causal, sm_scale, interpret)
 
 
 # Above roughly this many bytes of [B, H, T, T] f32 scores, the dense XLA
 # path risks HBM exhaustion and the blockwise kernel wins by never
-# materializing them. Measured on a v5e chip (B=1 H=8 D=128, causal,
+# materializing them. Measured on a v5e chip before PR 6 (history; the
+# kernels have since been rewritten — B=1 H=8 D=128, causal,
 # bf16): XLA is FASTER wherever the dense scores fit (8k: 19 vs 24 ms;
 # 16k: 52 vs 69 ms) and the kernel is within ~1.3x; at 32k (34 GB of
 # scores > 16 GB HBM) only the kernel runs (232 ms). So "auto" switches

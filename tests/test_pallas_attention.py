@@ -62,26 +62,104 @@ def test_flash_grad_bf16_runs():
     assert np.isfinite(np.asarray(g, dtype=np.float32)).all()
 
 
-def test_split_backward_fallback_matches_dense(monkeypatch):
-    """The long-context split dq/dkv kernels (taken when _fused_bwd_fits
-    says the fused backward's full-T VMEM accumulators exceed the
-    per-core budget) must stay grad-correct."""
+def _force_plan(monkeypatch, pa, plan):
+    """Pin the VMEM gate to one schedule: ``resident`` (K/V whole in VMEM,
+    in-kernel loop, fused backward), ``streamed`` (K/V tiles over a kb
+    grid axis, fused backward) or ``split`` (streamed, dq + dkv kernels).
+    The [B,T,H,D] entry is jitted, so traces made under another plan are
+    dropped first."""
+    def fits(T, D, itemsize, *, b, bwd, kv_resident, packed=False):
+        if plan == "resident":
+            return kv_resident
+        return plan == "streamed" and bwd and not kv_resident
+    monkeypatch.setattr(pa, "_fits_vmem", fits)
+    jax.clear_caches()
+
+
+def _dense_packed(pa, qkv, H, causal):
+    B, T, cols = qkv.shape
+    D = cols // (3 * H)
+    r = qkv.reshape(B, T, H, 3, D)
+    return pa._xla_attention(r[..., 0, :], r[..., 1, :], r[..., 2, :],
+                             causal, D ** -0.5).reshape(B, T, H * D)
+
+
+# (T, preferred tile, diagonal strip): patched down so that every T has a
+# pair above the diagonal (never visited), plain pairs and diagonal pairs,
+# and both strip heights occur (128: whole-tile and 2 strips; 256: 2 strips).
+_SCHEDULE_SHAPES = [(256, 128, 128), (512, 256, 128), (1024, 512, 256)]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("T,want,sub", _SCHEDULE_SHAPES,
+                         ids=[f"T{t}-b{w}-s{u}" for t, w, u in
+                              _SCHEDULE_SHAPES])
+@pytest.mark.parametrize("plan", ["resident", "streamed", "split"])
+@pytest.mark.parametrize("entry", ["packed", "bthd"])
+def test_tile_schedule_matches_dense(monkeypatch, entry, plan, T, want, sub,
+                                     causal):
+    """Output and gradients of every schedule the gate can pick, through
+    both entry points, against dense attention at the module's standing
+    tolerances."""
     import horovod_tpu.ops.pallas_attention as pa
-    monkeypatch.setattr(pa, "_VMEM_BUDGET_BYTES", 0)
-    B, T, H, D = 1, 256, 2, 128
-    rng = np.random.RandomState(7)
-    q, k, v = (jnp.asarray(rng.randn(B, T, H, D), jnp.float32) * 0.5
-               for _ in range(3))
-    cot = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-    got = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, backend="pallas", interpret=True) * cot),
-        argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda q, k, v: jnp.sum(
-        _xla_attention(q, k, v, True, D ** -0.5) * cot),
-        argnums=(0, 1, 2))(q, k, v)
-    for g, w, name in zip(got, want, "qkv"):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=2e-3, atol=2e-4, err_msg=name)
+    monkeypatch.setattr(pa, "_WANT_BLOCK", want)
+    monkeypatch.setattr(pa, "_DIAG_SUB", sub)
+    _force_plan(monkeypatch, pa, plan)
+    B, H, D = 1, 2, 128
+    rng = np.random.RandomState(T + want)
+    qkv = jnp.asarray(rng.randn(B, T, H * 3 * D), jnp.float32) * 0.5
+    cot = jnp.asarray(rng.randn(B, T, H * D), jnp.float32)
+
+    if entry == "packed":
+        def kern(x):
+            return pa.flash_attention_qkv(x, H, causal=causal,
+                                          interpret=True)
+    else:
+        def kern(x):
+            r = x.reshape(B, T, H, 3, D)
+            return pa.flash_attention(
+                r[..., 0, :], r[..., 1, :], r[..., 2, :], causal=causal,
+                backend="pallas", interpret=True).reshape(B, T, H * D)
+
+    def dense(x):
+        return _dense_packed(pa, x, H, causal)
+
+    np.testing.assert_allclose(np.asarray(kern(qkv)), np.asarray(dense(qkv)),
+                               rtol=2e-4, atol=2e-5)
+    got = jax.grad(lambda x: jnp.sum(kern(x) * cot))(qkv)
+    want_g = jax.grad(lambda x: jnp.sum(dense(x) * cot))(qkv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want_g),
+                               rtol=2e-3, atol=2e-4)
+    jax.clear_caches()      # leave no trace made under the pinned plan
+
+
+@pytest.mark.parametrize("b,sub", [(128, 128), (256, 128), (512, 128),
+                                   (512, 256), (512, 512)])
+def test_tile_schedule_covers_lower_triangle_once(b, sub):
+    """The schedule is a function of shapes: over a sequence of 4 tiles,
+    the plain pairs (kb < qi) and the diagonal pairs' strips compute every
+    score on or under the diagonal exactly once; what they compute above
+    it lies inside a masked strip, and only there."""
+    import horovod_tpu.ops.pallas_attention as pa
+    n = 4
+    T = n * b
+    seen = np.zeros((T, T), np.int32)
+    masked = np.zeros((T, T), bool)
+    for qi in range(n):
+        for kb in range(qi + 1):
+            for r0, rows, cols, m in pa._tile_schedule(b, sub, kb == qi):
+                blk = (slice(qi * b + r0, qi * b + r0 + rows),
+                       slice(kb * b, kb * b + cols))
+                seen[blk] += 1
+                masked[blk] |= m
+    lower = np.tril(np.ones((T, T), bool))
+    assert (seen[lower] == 1).all()
+    assert (seen[~lower] <= 1).all()
+    assert masked[~lower & (seen > 0)].all()
+    # the share of T² that is computed: 1/2 + the strips' overhang
+    assert seen.sum() == (T * T - n * b * b) // 2 + n * sum(
+        rows * cols for _, rows, cols, _ in pa._tile_schedule(b, sub, True))
+    assert pa._tile_schedule(b, sub, False) == [(0, b, b, False)]
 
 
 def test_fallback_on_untiled_shapes():

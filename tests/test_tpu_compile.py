@@ -63,40 +63,53 @@ def _n_kernels(fn, *shapes):
             "tpu_custom_call")
 
 
-def _expected_kernels(T, dtype, packed):
-    """Forward + fused backward = 2 kernels; forward + split dq/dkv = 3.
-    Which one is the GATE's decision — a shape the gate admits and the
-    compiler refuses fails the compile, not this count."""
-    bq = pa._pick_block(T, pa._WANT_BQ)
-    fused = pa._fused_bwd_fits(T, D, jnp.dtype(dtype).itemsize, bq=bq,
-                               bk=bq, packed=packed)
-    return 2 if fused else 3
+def _bwd_plan(T, dtype, packed, d=D):
+    """The backward the GATE picks (resident | streamed | split). A shape
+    the gate admits and the compiler refuses fails the compile, not this
+    function."""
+    return pa._bwd_plan(T, d, jnp.dtype(dtype).itemsize,
+                        b=pa._pick_block(T, pa._WANT_BLOCK), packed=packed)
 
 
-# (B, T, dtype): the bench shape; the largest bf16 shape the gate still
-# fuses; the two shapes the compiler refused the fused kernel for before
-# the gate was corrected (T=8192 bf16, T=4096 f32); a long split shape.
+def _expected_kernels(T, dtype, packed, d=D):
+    """Forward + fused backward = 2 kernels; forward + split dq/dkv = 3."""
+    return 3 if _bwd_plan(T, dtype, packed, d) == "split" else 2
+
+
+# (B, T, dtype, d_head): the bench shape; then, for each dtype and head
+# size, the shapes on both sides of the gate's two boundaries (resident |
+# streamed | split backward; resident | streamed forward) — among them the
+# shapes the compiler refused the fused kernel for before the gate was
+# corrected (T=8192 bf16, T=4096 f32) and a long split shape.
 _QKV_CASES = [
-    (8, 2048, jnp.bfloat16),
-    (2, 4096, jnp.bfloat16),
-    (2, 8192, jnp.bfloat16),
-    (1, 16384, jnp.bfloat16),
-    (2, 4096, jnp.float32),
+    (8, 2048, jnp.bfloat16, 128),       # resident, resident
+    (2, 4096, jnp.bfloat16, 128),       # resident forward, streamed fused
+    (2, 8192, jnp.bfloat16, 128),       # resident forward, split
+    (1, 16384, jnp.bfloat16, 128),      # streamed forward, split
+    (2, 1024, jnp.float32, 128),        # resident, resident
+    (2, 2048, jnp.float32, 128),        # resident forward, streamed fused
+    (2, 4096, jnp.float32, 128),        # resident forward, split
+    (1, 8192, jnp.float32, 128),        # streamed forward, split
+    (2, 1024, jnp.bfloat16, 256),       # resident, resident
+    (2, 2048, jnp.bfloat16, 256),       # resident forward, split
+    (1, 8192, jnp.bfloat16, 256),       # streamed forward, split
+    (2, 1024, jnp.float32, 256),        # resident forward, split
 ]
 
 
 @pytest.mark.parametrize(
-    "B,T,dtype", _QKV_CASES,
-    ids=[f"B{b}-T{t}-{jnp.dtype(d).name}" for b, t, d in _QKV_CASES])
-def test_flash_attention_qkv_fwd_bwd_compiles(one_chip, B, T, dtype):
-    qkv = jax.ShapeDtypeStruct((B, T, H * 3 * D), dtype, sharding=one_chip)
+    "B,T,dtype,d", _QKV_CASES,
+    ids=[f"B{b}-T{t}-{jnp.dtype(dt).name}-d{d}" for b, t, dt, d in _QKV_CASES])
+def test_flash_attention_qkv_fwd_bwd_compiles(one_chip, B, T, dtype, d):
+    h = H * D // d
+    qkv = jax.ShapeDtypeStruct((B, T, h * 3 * d), dtype, sharding=one_chip)
 
     def loss(x):
         return jnp.sum(pa.flash_attention_qkv(
-            x, H, causal=True, interpret=False).astype(jnp.float32))
+            x, h, causal=True, interpret=False).astype(jnp.float32))
 
     assert _n_kernels(jax.value_and_grad(loss), qkv) == \
-        _expected_kernels(T, dtype, packed=True)
+        _expected_kernels(T, dtype, packed=True, d=d)
 
 
 @pytest.mark.parametrize("T,backward", [
@@ -106,7 +119,9 @@ def test_flash_attention_qkv_fwd_bwd_compiles(one_chip, B, T, dtype):
 def test_flash_kernels_carry_their_names(one_chip, T, backward):
     """``pallas_call(name=)`` becomes the compiled instruction's name and a
     component of its ``op_name`` — what a device trace finds the kernels
-    by (benchmarks/layer_metrics/device_scopes.py)."""
+    by (benchmarks/layer_metrics/device_scopes.py). The forward and the
+    backward are jitted by themselves (one trace for all layers), which
+    adds a ``jit(_fwd)`` / ``jit(_bwd)`` component under the scope."""
     qkv = jax.ShapeDtypeStruct((2, T, H * 3 * D), jnp.bfloat16,
                                sharding=one_chip)
 
@@ -122,10 +137,11 @@ def test_flash_kernels_carry_their_names(one_chip, T, backward):
     names = sorted(ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
                    for ln in kernels)
     assert names == sorted(("flash_fwd",) + backward)
-    assert any("jvp(forward)/flash_fwd/pallas_call" in ln for ln in kernels)
+    assert any("jvp(forward)/jit(_fwd)/flash_fwd/pallas_call" in ln
+               for ln in kernels)
     for name in backward:
-        assert any(f"transpose(jvp(forward))/{name}/pallas_call" in ln
-                   for ln in kernels), name
+        assert any(f"transpose(jvp(forward))/jit(_bwd)/{name}/pallas_call"
+                   in ln for ln in kernels), name
 
 
 @pytest.mark.parametrize("B,T", [(8, 2048), (2, 8192)],
@@ -160,17 +176,46 @@ def test_paged_decode_kernel_compiles(one_chip, dtype):
         sds((S,), jnp.int32)) == 1
 
 
-@pytest.mark.parametrize("T,dtype,packed,fused", [
-    (2048, jnp.bfloat16, True, True),      # the bench shape stays fused
-    (4096, jnp.bfloat16, True, True),
-    (8192, jnp.bfloat16, True, False),     # 25 MiB wanted, 16 MiB granted
-    (8192, jnp.bfloat16, False, False),
-    (4096, jnp.float32, True, False),
-    (4096, jnp.float32, False, False),
+@pytest.mark.parametrize("T,causal", [(2048, True), (8192, True),
+                                      (16384, True), (2048, False)],
+                         ids=["T2048", "T8192", "T16384", "T2048-full"])
+def test_flash_forward_only_compiles(one_chip, T, causal):
+    """The no-grad primal (no lse output) — the serving prefill's call —
+    with K/V resident (to T=8192 in bf16) and streamed (beyond)."""
+    x = jax.ShapeDtypeStruct((1, T, H, D), jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return pa.flash_attention(q, k, v, causal=causal, backend="pallas",
+                                  interpret=False)
+
+    assert _n_kernels(fwd, x, x, x) == 1
+
+
+@pytest.mark.parametrize("T,dtype,packed,plan", [
+    (2048, jnp.bfloat16, True, "resident"),    # the bench shape
+    (4096, jnp.bfloat16, True, "streamed"),    # still fused
+    (8192, jnp.bfloat16, True, "split"),       # 25 MiB wanted, 16 granted
+    (8192, jnp.bfloat16, False, "split"),
+    (4096, jnp.float32, True, "split"),
+    (4096, jnp.float32, False, "split"),
+    (4096, jnp.bfloat16, False, "streamed"),
+    (2048, jnp.float32, True, "streamed"),
+    (1024, jnp.float32, True, "resident"),
 ], ids=lambda v: getattr(v, "__name__", str(v)))
-def test_fused_backward_gate(T, dtype, packed, fused):
+def test_fused_backward_gate(T, dtype, packed, plan):
     """The gate's verdicts at D=128 (no compiler needed): what the v5e
     compiler was measured to accept within its 16 MiB scoped VMEM."""
-    bq = pa._pick_block(T, pa._WANT_BQ)
-    assert pa._fused_bwd_fits(T, D, jnp.dtype(dtype).itemsize, bq=bq, bk=bq,
-                              packed=packed) is fused
+    assert _bwd_plan(T, dtype, packed) == plan
+
+
+@pytest.mark.parametrize("T,dtype,resident", [
+    (8192, jnp.bfloat16, True),
+    (16384, jnp.bfloat16, False),
+    (4096, jnp.float32, True),
+    (8192, jnp.float32, False),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_forward_residency_gate(T, dtype, resident):
+    """The same gate for the forward: K and V whole in VMEM or streamed."""
+    assert pa._fits_vmem(T, D, jnp.dtype(dtype).itemsize,
+                         b=pa._pick_block(T, pa._WANT_BLOCK), bwd=False,
+                         kv_resident=True) is resident
