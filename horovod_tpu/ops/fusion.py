@@ -466,7 +466,8 @@ def plan_grad_sync(specs: Sequence[Any], mesh,
     ``parallel/mesh.grad_sync_by_spec`` exactly — psum over every mesh
     axis the leaf is replicated across (minus ``skip_axes``), averaged by
     the product of those axis sizes, with the extra ``1/tp`` on tp-sharded
-    leaves (the psum-transpose factor) folded into ``denom`` so the whole
+    leaves (the psum-transpose factor) and ``1/ep`` on ep-sharded ones (the
+    exchange's sum over the ranks' batches) folded into ``denom`` so the whole
     correction rides the bucket's one fused prescale multiply."""
     mesh_axes = tuple(mesh.axis_names)
     sizes = dict(mesh.shape)
@@ -482,6 +483,10 @@ def plan_grad_sync(specs: Sequence[Any], mesh,
             denom *= int(sizes[a])
         if "tp" in leaf_axes and "tp" in sizes:
             denom *= int(sizes["tp"])
+        if "ep" in leaf_axes and "ep" in sizes:
+            # Experts' gradients arrive summed over the ep ranks whose
+            # tokens they served (each from its own batch's mean loss).
+            denom *= int(sizes["ep"])
         out.append(GradSync(psum=over, shard=shard, denom=denom))
     return out
 

@@ -159,6 +159,11 @@ def grad_sync_by_spec(grads, specs, mesh_axes, *, skip_axes=(),
                 g = lax.pmean(g, over)
         if "tp" in leaf_axes and "tp" in mesh_axes:
             g = g / lax.axis_size("tp")
+        if "ep" in leaf_axes and "ep" in mesh_axes:
+            # An expert's gradient arrives summed over the ep ranks whose
+            # tokens it served, each rank's from its own batch's mean loss:
+            # the mean over the whole batch is that sum over ep.
+            g = g / lax.axis_size("ep")
         return g
 
     return jax.tree_util.tree_map(sync, specs, grads,

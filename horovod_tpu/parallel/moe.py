@@ -1,88 +1,291 @@
-"""Expert parallelism: top-1 gated MoE with all_to_all dispatch over ``ep``.
+"""The expert layer: a top-k mixture of feed-forward experts of which each
+chip HOLDS some, routes over all, and drops nothing.
 
-Net-new TPU capability (absent from the reference). GShard-style layout:
-one expert per ep rank; each chip's tokens are routed by a learned gate,
-packed into a static-capacity dispatch buffer [S, C, D] (XLA needs static
-shapes — overflow tokens beyond capacity drop, standard MoE behavior),
-exchanged with a single ``all_to_all`` so chip e receives every chip's
-tokens for expert e, transformed by the local expert FFN, and returned by
-the inverse ``all_to_all``; gate probabilities weight the combine.
+Every token's router scores all ``E`` experts and keeps its ``top_k``
+(weights renormalised over the kept ones, or the raw probabilities). A chip
+is told which experts it holds — the ``held`` consecutive experts from
+``first_expert``, whose weights are the leading dimension of ``w_up`` /
+``w_down`` (/ ``w_gate``) — and computes the part of the layer's output
+that THOSE experts give, for every assignment that fell to them: rows are
+grouped by expert and multiplied by grouped matrix products (on the TPU
+the Pallas kernel that ships with jax, ``lax.ragged_dot`` elsewhere),
+whatever the imbalance; there is no capacity and no
+dropped token. What experts held elsewhere would add is added elsewhere:
 
-The expert plane is a first-class mesh axis, not a side channel:
-``create_hybrid_mesh(ep=E)`` names it, expert weights carry ``ep`` in
-their PartitionSpecs (``parallel/transformer.py`` puts ``P('ep', …)`` on
-w1/w2 when ``n_experts`` is set), and their gradients ride the SAME
-spec-grouped collective plan as every other leaf
-(``ops/fusion.plan_grad_sync``: expert grads psum over the axes they are
-replicated across — never ``ep``, each rank owns its expert — while the
-replicated gate syncs over the full mesh). No MoE-specific gradient code
-exists anywhere.
+* no ``axis_name`` (or an axis of size 1): the chip's share is the result.
+  A chip that stands for one member of a larger expert-parallel group
+  (``first_expert``/``held`` a slice of ``E``) runs exactly this, with no
+  exchange and nothing standing in for the absent members. What needs the
+  absent members is left out: their experts' outputs, and the gradient of
+  the routing weights (``moe_ffn``), so the router is not trained there;
+* ``axis_name`` of size > 1: rank r holds experts ``[first_expert + r *
+  held, ... + held)``. Tokens and their routing are all-gathered over the
+  axis, every rank computes its experts' share for all of them, and a
+  reduce-scatter sums the shares back to the tokens' owners.
+
+The expert plane is a mesh axis like any other: expert weights carry ``ep``
+in their PartitionSpecs (``parallel/transformer.py``) and their gradients
+ride the same spec-grouped collective plan as every other leaf
+(``ops/fusion.plan_grad_sync``).
+
+Named scopes (a device trace splits the step by them): ``moe.route`` and
+``moe.experts``.
 """
 
 from __future__ import annotations
-
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.registry import registry as _registry
 
-def moe_ffn(x, gate_w, w1, w2, *, axis_name: str = "ep",
-            capacity_factor: float = 1.25):
-    """Top-1 MoE feed-forward over tokens sharded across ``axis_name``.
+
+@jax.custom_vjp
+def _rows_of_tokens(x, token):
+    """x [M, D] -> x[token] [rows, D]. A token is taken once for each of
+    its assignments among the rows, so the transpose adds; it adds in
+    float32 whatever x's dtype."""
+    return x[token]
+
+
+def _rows_of_tokens_fwd(x, token):
+    return x[token], (token, jnp.zeros((x.shape[0], 0), x.dtype))
+
+
+def _rows_of_tokens_bwd(res, g):
+    token, like = res
+    dx = jnp.zeros((like.shape[0], g.shape[1]), jnp.float32).at[token].add(
+        g.astype(jnp.float32))
+    return dx.astype(like.dtype), None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+def _tile(n: int, most: int) -> int:
+    """The largest multiple of 128 up to ``most`` that divides ``n``, or 0."""
+    return next((t for t in range(most, 0, -128) if n % t == 0), 0)
+
+
+_GMM_ROWS = 512      # rows of a grouped product's tile on the TPU
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _megablox():
+    """The module of jax's Pallas grouped matrix products (the package
+    rebinds its name ``gmm`` to a function)."""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@jax.custom_vjp
+def _grouped_kernel(rows, w, sizes):
+    """rows [m, k] grouped by ``sizes`` x w [groups, k, n] -> [m, n] in
+    rows' dtype, float32 inside: the Pallas grouped matrix product that
+    ships with jax (megablox), tiles chosen per product. It visits only the
+    row tiles that hold a group's rows; rows of no group stay unwritten."""
+    gmm = _megablox()
+    (m, k), n = rows.shape, w.shape[2]
+    return gmm.gmm(rows, w, sizes, rows.dtype,
+                   (_GMM_ROWS, _tile(k, 1024), _tile(n, 1024)),
+                   interpret=not _on_tpu())
+
+
+def _grouped_kernel_fwd(rows, w, sizes):
+    return _grouped_kernel(rows, w, sizes), (rows, w, sizes)
+
+
+def _grouped_kernel_bwd(res, g):
+    gmm = _megablox()
+    rows, w, sizes = res
+    (m, k), n = rows.shape, w.shape[2]
+    d_rows = gmm.gmm(g, w, sizes, rows.dtype,
+                     (_GMM_ROWS, _tile(n, 1024), _tile(k, 1024)),
+                     transpose_rhs=True, interpret=not _on_tpu())
+    d_w = gmm.tgmm(rows.swapaxes(0, 1), g, sizes, w.dtype,
+                   (_GMM_ROWS, _tile(k, 1024), _tile(n, 1024)),
+                   num_actual_groups=w.shape[0], interpret=not _on_tpu())
+    return d_rows, d_w, None
+
+
+_grouped_kernel.defvjp(_grouped_kernel_fwd, _grouped_kernel_bwd)
+
+
+def _grouped(rows, w, sizes, real):
+    """Each group's rows times its expert's matrix; rows of no group come
+    out as zeros. On the TPU where the shapes tile, the Pallas kernel
+    (measured on a v5e, PERF.md PR 28: XLA's own ``ragged_dot`` kernel took
+    4 to 5 ms for [32768, 2048] x [16, 2048, 768] however few rows were
+    real); ``lax.ragged_dot`` otherwise. Either leaves rows of no group
+    unwritten, and whatever lies there must not meet arithmetic: 0 x NaN."""
+    (m, k), n = rows.shape, w.shape[2]
+    if (_on_tpu() and m % _GMM_ROWS == 0 and _tile(k, 1024)
+            and _tile(n, 1024)):
+        out = _grouped_kernel(rows, w, sizes)
+    else:
+        out = lax.ragged_dot(rows, w, sizes)
+    return jnp.where(real, out, 0)
+
+
+def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
+    """The held experts' share of the output for tokens ``x`` [M, D] with
+    routing ``ids`` / ``gates`` [M, k]: (y [M, D], assignments per held
+    expert [held]).
+
+    Assignments are sorted by expert, absent experts last, and worked in
+    chunks of half as many rows again as a balanced router would send here
+    (one chunk when every expert is held): a balanced router's load varies
+    by a tenth and more from batch to batch and layer to layer, and a chunk
+    of exactly that load would be followed by a second, nearly empty one
+    every other step, which costs the same rows. A shorter chunk pays more
+    tiles (one more for each expert's boundary). A chunk gathers and scatters
+    all its rows, a chunk past the last row held here is skipped, and the
+    grouped products visit only the tiles that hold a group's rows: the
+    work follows the load the router sends, and a step takes longer when
+    the held experts are popular."""
+    M, D = x.shape
+    k, held = ids.shape[1], w_up.shape[0]
+    local = ids.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).astype(jnp.int32)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    weight = gates.reshape(-1)[order]
+
+    n_rows = M * k
+    unit = _GMM_ROWS if n_rows % _GMM_ROWS == 0 else 8
+    cap = min(n_rows, max(unit, -(-n_rows * held * 3 // (n_experts * 2)
+                                  // unit) * unit))
+    n_chunks = -(-n_rows // cap)
+    pad = n_chunks * cap - n_rows
+    order = jnp.pad(order, (0, pad)).reshape(n_chunks, cap)
+    weight = jnp.pad(weight, (0, pad)).reshape(n_chunks, cap)
+
+    def work(c, order_c, weight_c):
+        """The chunk's rows through their experts, weighted: [cap, D]
+        float32, zeros where a row is of no group."""
+        lo = c * cap
+        sizes_c = (jnp.clip(ends, lo, lo + cap)
+                   - jnp.clip(ends - sizes, lo, lo + cap))
+        real = (lo + jnp.arange(cap, dtype=jnp.int32) < ends[-1])[:, None]
+        rows = jnp.where(real, _rows_of_tokens(x, order_c // k), 0)
+        up = _grouped(rows, w_up, sizes_c, real)
+        if w_gate is None:
+            h = jax.nn.gelu(up)
+        else:
+            h = jax.nn.silu(_grouped(rows, w_gate, sizes_c, real)) * up
+        out = _grouped(h.astype(x.dtype), w_down, sizes_c, real)
+        return out.astype(jnp.float32) * weight_c[:, None]
+
+    # The backward recomputes a chunk from its indices: what a chunk saves
+    # must not grow with the chunks (x and the weights are the scan's
+    # constants; inside a cond they would be saved once a chunk).
+    @jax.checkpoint
+    def rows_out(c, order_c, weight_c):
+        if n_chunks == 1:
+            return work(c, order_c, weight_c)
+        return lax.cond(c * cap < ends[-1], work,
+                        lambda *_: jnp.zeros((cap, D), jnp.float32),
+                        c, order_c, weight_c)
+
+    def chunk(y, inp):
+        c, order_c, weight_c = inp
+        out = rows_out(c, order_c, weight_c)
+
+        def add(y):
+            return y.at[order_c // k].add(out)
+        if n_chunks == 1:
+            return add(y), None
+        return lax.cond(c * cap < ends[-1], add, lambda y: y, y), None
+    y, _ = lax.scan(chunk, jnp.zeros((M, D), jnp.float32),
+                    (jnp.arange(n_chunks, dtype=jnp.int32), order, weight))
+    return y.astype(x.dtype), sizes
+
+
+def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
+            renormalize: bool = False, first_expert=0, axis_name=None):
+    """The held experts' share of a top-k expert layer.
 
     Args:
-      x: [T_local, D] this chip's tokens.
-      gate_w: [D, E] gate (replicated; E == axis size).
-      w1: [D, F] local expert up-projection; w2: [F, D] down.
-      capacity_factor: per-expert buffer = ceil(T_local/E · factor).
+      x: [N, D] this chip's tokens.
+      router_w: [D, E] router over ALL experts (replicated), float32.
+      w_up: [held, D, F], w_down: [held, F, D]: the experts held here;
+        ``w_gate`` [held, D, F] makes them gated, ``silu(x Wg) * (x Wu)``;
+        without it they are ``gelu(x Wu)``.
+      top_k: experts per token; ``renormalize``: weights are the kept
+        probabilities over their sum, else the probabilities themselves.
+      first_expert: the first expert held here (on an ``axis_name`` of size
+        n: by rank 0 of it; rank r holds the next ``held`` ones).
+      axis_name: the mesh axis the experts are spread over, or None.
 
-    Returns ([T_local, D], aux_loss) — aux_loss is the load-balancing loss
-    (mean over experts of fraction_routed · mean_gate_prob · E²).
+    Returns ``(y [N, D], stats)``: ``stats["aux"]`` is the load-balancing
+    loss (Shazeer et al.: E x sum over experts of the share of assignments
+    x the mean router probability), ``stats["held_load"]`` [held] the
+    assignments that fell to each held expert, ``stats["absent"]`` the
+    assignments of these tokens that fell to experts not held here.
     """
-    if capacity_factor <= 0:
-        raise ValueError(
-            f"capacity_factor must be > 0, got {capacity_factor} — a "
-            f"non-positive capacity would silently drop every token")
-    T, D = x.shape
-    E = lax.axis_size(axis_name)
-    C = max(1, int((T / E) * capacity_factor + 0.999))
+    N, _ = x.shape
+    E, held = router_w.shape[1], w_up.shape[0]
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top, ids = lax.top_k(probs, top_k)
+        gates = top / jnp.sum(top, -1, keepdims=True) if renormalize else top
+        share = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(
+            1.0 / (N * top_k))
+        aux = jnp.sum(share * jnp.mean(probs, axis=0)) * E
+    ranks = 1 if axis_name is None else lax.axis_size(axis_name)
+    spread = ranks > 1
+    if held * ranks < E:
+        # A token's weights are normalised over ALL its experts, so their
+        # gradient needs every kept expert's output, and the absent ones'
+        # are elsewhere. The part that can be computed here only ever says
+        # "the experts held here help, the others do not": it would teach
+        # the router to send everything here. It is left out, as the
+        # absent experts' outputs are: the weights are constants to the
+        # backward pass and the router gets no gradient from this share.
+        gates = lax.stop_gradient(gates)
+    xs, ids_s, gates_s = x, ids, gates
+    if spread:
+        first_expert = first_expert + lax.axis_index(axis_name) * held
+        xs, ids_s, gates_s = (lax.all_gather(a, axis_name, tiled=True)
+                              for a in (x, ids, gates))
+    with jax.named_scope("moe.experts"):
+        y, load = _held_experts(xs, ids_s, gates_s.astype(jnp.float32),
+                                w_gate, w_up, w_down, first_expert, E)
+    if spread:
+        y = lax.psum_scatter(y, axis_name, tiled=True)
+    local = ids - first_expert
+    absent = N * top_k - jnp.sum((local >= 0) & (local < held))
+    return y, {"aux": aux, "held_load": load, "absent": absent}
 
-    logits = x @ gate_w                               # [T, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    expert = jnp.argmax(probs, axis=-1)               # [T]
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
 
-    # Position of each token within its expert's capacity buffer.
-    onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)        # [T, E]
-    pos = jnp.cumsum(onehot, axis=0) * onehot                  # 1-based
-    pos = jnp.sum(pos, axis=-1) - 1                            # [T], -1 pad
-    keep = (pos >= 0) & (pos < C)
+_m_load = _registry().gauge(
+    "hvd_moe_load_max_over_mean",
+    "assignments to the busiest held expert over the mean of the held "
+    "experts, by layer, as last recorded", labels=("layer",))
+_m_held = _registry().gauge(
+    "hvd_moe_held_assignments",
+    "router assignments that fell to the experts held on this chip, by "
+    "layer, as last recorded", labels=("layer",))
+_m_absent = _registry().gauge(
+    "hvd_moe_absent_assignments",
+    "router assignments that fell to experts not held on this chip, by "
+    "layer, as last recorded", labels=("layer",))
 
-    # Pack: dispatch[e, c, :] = token routed to expert e at slot c.
-    dispatch = jnp.zeros((E, C, D), x.dtype)
-    dispatch = dispatch.at[expert, jnp.clip(pos, 0, C - 1)].add(
-        jnp.where(keep[:, None], x, 0))
 
-    # Exchange: chip r sends block e to chip e; receives [E, C, D] where
-    # block s came from chip s.
-    shuffled = lax.all_to_all(dispatch, axis_name, split_axis=0,
-                              concat_axis=0, tiled=True)
-
-    h = jax.nn.gelu(shuffled.reshape(-1, D) @ w1)
-    out = (h @ w2).reshape(E, C, D)
-
-    # Return to senders and unpack.
-    returned = lax.all_to_all(out, axis_name, split_axis=0, concat_axis=0,
-                              tiled=True)
-    combined = returned[expert, jnp.clip(pos, 0, C - 1)]
-    combined = jnp.where(keep[:, None], combined, 0)
-    y = combined * gate[:, None].astype(x.dtype)
-
-    # Load-balance auxiliary loss (Shazeer et al.): encourages uniform
-    # routing; fraction of tokens per expert × mean gate prob per expert.
-    frac = jnp.mean(onehot.astype(jnp.float32), axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = jnp.sum(frac * mean_prob) * E
-    return y, aux
+def record_routing(layer: int, held_load, absent) -> None:
+    """Stamp one layer's routing load (host values, off the dispatch path:
+    from a step's small outputs after it completed)."""
+    load = [float(v) for v in held_load]
+    mean = sum(load) / len(load)
+    _m_load.labels(layer=str(layer)).set(max(load) / mean if mean else 0.0)
+    _m_held.labels(layer=str(layer)).set(sum(load))
+    _m_absent.labels(layer=str(layer)).set(float(absent))

@@ -37,7 +37,8 @@ from ..ops.pallas_attention import flash_attention
 # empirical sync rule the fused GradSync plan is parity-pinned against.
 from .mesh import grad_sync_by_spec  # noqa: F401
 from .pipeline import one_f_one_b
-from .transformer import TransformerConfig, _rms_norm, dense_nll
+from .transformer import (TransformerConfig, _check_dense, _rms_norm,
+                          dense_nll)
 
 
 def _axes(mesh: Mesh):
@@ -133,6 +134,7 @@ def make_pp_transformer_train_step(cfg: TransformerConfig, mesh: Mesh,
       sums M microbatch gradients before the one exchange); there is no
       separate accum_steps knob to double-divide with.
     """
+    _check_dense(cfg, "make_pp_transformer_train_step")
     from ..optimizer import DistributedOptimizer
     from ..utils import config as _config
 

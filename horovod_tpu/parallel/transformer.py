@@ -29,13 +29,43 @@ from .ring import ring_attention
 
 
 @dataclasses.dataclass(frozen=True)
+class Indexer:
+    """The lightning indexer of sparse attention (``ops/sparse_attention``):
+    ``n_heads`` index heads of ``d_head`` over ONE index key head; each
+    query row attends to the ``topk`` keys of largest index score."""
+    n_heads: int
+    d_head: int
+    topk: int
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 1024
     d_model: int = 128
     n_heads: int = 8
     n_layers: int = 2
-    d_ff: int = 512
-    n_experts: int = 0          # 0 = dense MLP; >0 = MoE over the ep axis
+    d_ff: int = 512             # feed-forward width (of one expert, if any)
+    n_experts: int = 0          # 0 = dense MLP; >0 = experts the router sees
+    # -- The block, described per configuration. The defaults are the dense
+    # block this file has always had: one packed wqkv of equal head counts,
+    # no positions, GELU feed-forward, tied head.
+    n_kv_heads: int = 0         # 0 = n_heads; fewer = grouped key/value heads
+    d_head: int = 0             # 0 = d_model // n_heads
+    qk_norm: bool = False       # RMSNorm over each head of q and of k
+    rope_theta: float = 0.0     # 0 = no positions; else RoPE, pairs (i, i+d/2)
+    mlp: str = "gelu"           # "gelu" (w1, w2) | "swiglu" (w_gate, w_up, w_down)
+    tied_head: bool = True      # False: an unembedding of its own ("head")
+    indexer: Optional[Indexer] = None   # sparse attention over selected keys
+    # Experts (n_experts > 0): each token keeps its moe_top_k; the weights
+    # are renormalised over the kept ones or not. experts_held of the
+    # n_experts live in this program, from first_expert on, spread over
+    # the ``ep`` axis if the mesh has one (0 = all of them): one chip's
+    # share of a larger expert-parallel group is experts_held < n_experts
+    # on a mesh without ``ep``.
+    moe_top_k: int = 1
+    moe_renormalize: bool = False
+    experts_held: int = 0
+    first_expert: int = 0
     dtype: Any = jnp.bfloat16
     # "pallas" so TRAINING never materializes [T, T] scores for backward
     # (the flash custom VJP recomputes tiles); untilable shapes still fall
@@ -64,40 +94,84 @@ def _axes(mesh: Mesh):
     return set(mesh.axis_names)
 
 
+def _kv_heads(cfg: TransformerConfig) -> int:
+    return cfg.n_kv_heads or cfg.n_heads
+
+
+def _d_head(cfg: TransformerConfig) -> int:
+    return cfg.d_head or cfg.d_model // cfg.n_heads
+
+
+def _packed_qkv(cfg: TransformerConfig) -> bool:
+    """The dense block's attention: one packed ``wqkv`` of equal head
+    counts and nothing between the projection and the flash kernel."""
+    return not (cfg.n_kv_heads or cfg.d_head or cfg.qk_norm
+                or cfg.rope_theta or cfg.indexer)
+
+
+def _held(cfg: TransformerConfig) -> int:
+    return cfg.experts_held or cfg.n_experts
+
+
 def init_params(rng, cfg: TransformerConfig) -> Dict:
     """Global (unsharded-shape) parameter pytree; place with
     :func:`param_specs` + ``jax.device_put`` before use."""
-    k = jax.random.split(rng, 4 + 6 * cfg.n_layers)
+    # The dense block draws what it always drew from a seed.
+    dense = _packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head
+    k = jax.random.split(rng, (4 + 6 * cfg.n_layers) if dense
+                         else (5 + 12 * cfg.n_layers))
     ki = iter(range(len(k)))
     norm = lambda key, shape, s: (jax.random.normal(k[key], shape) * s)  # noqa: E731
+    d, dh = cfg.d_model, _d_head(cfg)
     params: Dict[str, Any] = {
-        "embed": norm(next(ki), (cfg.vocab, cfg.d_model), 0.02),
-        "lnf": jnp.ones((cfg.d_model,)),
+        # A tied table is the unembedding too: 0.02 keeps its logits
+        # small. An untied one feeds the residual stream alone, whose
+        # branches (fan-in scaled) add outputs of unit size: at 0.02 a
+        # token's own row would be a fiftieth of the stream after the
+        # first block, and every token's hidden state nearly the same.
+        "embed": norm(next(ki), (cfg.vocab, d),
+                      0.02 if cfg.tied_head else 1.0),
+        "lnf": jnp.ones((d,)),
         "layers": [],
     }
+    if not cfg.tied_head:
+        params["head"] = norm(next(ki), (cfg.vocab, d), d ** -0.5)
     for _ in range(cfg.n_layers):
-        layer = {
-            "ln1": jnp.ones((cfg.d_model,)),
-            "wqkv": norm(next(ki), (cfg.d_model, 3 * cfg.d_model),
-                         cfg.d_model ** -0.5),
-            "wo": norm(next(ki), (cfg.d_model, cfg.d_model),
-                       cfg.d_model ** -0.5),
-            "ln2": jnp.ones((cfg.d_model,)),
-        }
-        if cfg.n_experts:
-            layer["gate"] = norm(next(ki), (cfg.d_model, cfg.n_experts),
-                                 cfg.d_model ** -0.5)
-            # Leading expert dim shards over ep (one expert per ep rank).
-            layer["w1"] = norm(next(ki),
-                               (cfg.n_experts, cfg.d_model, cfg.d_ff),
-                               cfg.d_model ** -0.5)
-            layer["w2"] = norm(next(ki),
-                               (cfg.n_experts, cfg.d_ff, cfg.d_model),
-                               cfg.d_ff ** -0.5)
+        layer = {"ln1": jnp.ones((d,)), "ln2": jnp.ones((d,))}
+        if _packed_qkv(cfg):
+            layer["wqkv"] = norm(next(ki), (d, 3 * d), d ** -0.5)
+            layer["wo"] = norm(next(ki), (d, d), d ** -0.5)
         else:
-            layer["w1"] = norm(next(ki), (cfg.d_model, cfg.d_ff),
-                               cfg.d_model ** -0.5)
-            layer["w2"] = norm(next(ki), (cfg.d_ff, cfg.d_model),
+            # Head-major columns, as wqkv: a tp column slice holds whole
+            # heads.
+            nq, nkv = cfg.n_heads * dh, _kv_heads(cfg) * dh
+            layer["wq"] = norm(next(ki), (d, nq), d ** -0.5)
+            layer["wk"] = norm(next(ki), (d, nkv), d ** -0.5)
+            layer["wv"] = norm(next(ki), (d, nkv), d ** -0.5)
+            layer["wo"] = norm(next(ki), (nq, d), nq ** -0.5)
+            if cfg.qk_norm:
+                layer["q_norm"] = jnp.ones((dh,))
+                layer["k_norm"] = jnp.ones((dh,))
+        if cfg.indexer:
+            ix = cfg.indexer
+            layer["idx_wq"] = norm(next(ki), (d, ix.n_heads * ix.d_head),
+                                   d ** -0.5)
+            layer["idx_wk"] = norm(next(ki), (d, ix.d_head), d ** -0.5)
+            layer["idx_ww"] = norm(next(ki), (d, ix.n_heads), d ** -0.5)
+            layer["idx_k_scale"] = jnp.ones((ix.d_head,))
+            layer["idx_k_bias"] = jnp.zeros((ix.d_head,))
+        # Experts: a leading dim of the experts held, sharded over ep.
+        lead = (_held(cfg),) if cfg.n_experts else ()
+        if cfg.n_experts:
+            layer["router"] = norm(next(ki), (d, cfg.n_experts), d ** -0.5)
+        if cfg.mlp == "swiglu":
+            layer["w_gate"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
+            layer["w_up"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
+            layer["w_down"] = norm(next(ki), lead + (cfg.d_ff, d),
+                                   cfg.d_ff ** -0.5)
+        else:
+            layer["w1"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
+            layer["w2"] = norm(next(ki), lead + (cfg.d_ff, d),
                                cfg.d_ff ** -0.5)
         params["layers"].append(layer)
     return params
@@ -109,25 +183,30 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     else replicated (dp/sp replicate params)."""
     tp = "tp" if "tp" in _axes(mesh) else None
     ep = "ep" if "ep" in _axes(mesh) else None
-    specs: Dict[str, Any] = {
-        "embed": P(),
-        "lnf": P(),
-        "layers": [],
-    }
+    col, row = P(None, tp), P(tp, None)   # heads / ff columns shard over tp
+    specs: Dict[str, Any] = {"embed": P(), "lnf": P(), "layers": []}
+    if not cfg.tied_head:
+        specs["head"] = P()
+    up, down = ("w_gate", "w_up"), ("w_down",)
+    if cfg.mlp != "swiglu":
+        up, down = ("w1",), ("w2",)
     for _ in range(cfg.n_layers):
-        layer = {
-            "ln1": P(),
-            "wqkv": P(None, tp),   # column-parallel: heads shard over tp
-            "wo": P(tp, None),     # row-parallel: one psum recombines
-            "ln2": P(),
-        }
-        if cfg.n_experts:
-            layer["gate"] = P()
-            layer["w1"] = P(ep, None, None)
-            layer["w2"] = P(ep, None, None)
+        layer = {"ln1": P(), "ln2": P(), "wo": row}
+        if _packed_qkv(cfg):
+            layer["wqkv"] = col
         else:
-            layer["w1"] = P(None, tp)
-            layer["w2"] = P(tp, None)
+            layer.update(wq=col, wk=col, wv=col)
+            if cfg.qk_norm:
+                layer.update(q_norm=P(), k_norm=P())
+        if cfg.indexer:
+            layer.update({name: P() for name in (
+                "idx_wq", "idx_wk", "idx_ww", "idx_k_scale", "idx_k_bias")})
+        if cfg.n_experts:
+            layer["router"] = P()
+            layer.update({name: P(ep, None, None) for name in up + down})
+        else:
+            layer.update({name: col for name in up})
+            layer.update({name: row for name in down})
         specs["layers"].append(layer)
     return specs
 
@@ -138,24 +217,50 @@ def _rms_norm(x, scale):
     return ((x32 / rms) * scale).astype(x.dtype)
 
 
-def forward_hidden(params, tokens, cfg: TransformerConfig, mesh: Mesh):
-    """Runs INSIDE shard_map: ``tokens`` [B_local, T_local] int32.
-    Returns (final hidden states [B_local, T_local, d_model] — the
-    pre-unembed activations — and the MoE aux loss). The chunked-loss
-    path consumes this directly so the [*, vocab] logits never
-    materialize; :func:`forward` layers the tied unembed on top."""
+def _layer_norm(x, scale, bias):
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
+    return ((x32 - mu) / jnp.sqrt(var + 1e-6) * scale + bias).astype(x.dtype)
+
+
+def _rope(x, theta: float):
+    """Rotate the pairs (i, i + d/2) of the last dim of ``x`` [B, T, ..., d]
+    by position t, base ``theta`` (float32 inside, x's dtype out)."""
+    T, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(d // 2, dtype=jnp.float32) / (d // 2))
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    shape = (1, T) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    a = x[..., :d // 2].astype(jnp.float32)
+    b = x[..., d // 2:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
+                    with_masks: bool = False):
+    """Runs INSIDE shard_map: ``tokens`` [B_local, T_local] int32. Returns
+    the final hidden states [B_local, T_local, d_model] (normed, before
+    the unembedding) and, per layer, a dict of what the block produced
+    beside them: ``aux`` (the experts' balance loss), ``kl_sum`` and ``kl``
+    (the indexer's KL summed over each sequence's rows, [B], which the loss
+    differentiates, and per row, [B, T], which it does not), the routing
+    load ``held_load`` /
+    ``absent``, and with ``with_masks`` the int8 selection ``mask``."""
     axes = _axes(mesh)
     has_tp = "tp" in axes
     has_sp = "sp" in axes
-    has_ep = "ep" in axes
-    n_heads_local = cfg.n_heads // (mesh.shape.get("tp", 1))
-    d_head = cfg.d_model // cfg.n_heads
+    tp_size = mesh.shape.get("tp", 1)
+    n_heads_local = cfg.n_heads // tp_size
+    d_head = _d_head(cfg)
 
-    def _layer_fwd(layer, x):
+    def _attention(layer, h, extras):
         from ..ops.pallas_attention import (flash_attention,
                                             flash_attention_qkv,
                                             qkv_flash_tilable)
-        h = _rms_norm(x, layer["ln1"])
+        if not _packed_qkv(cfg):
+            return _projected_attention(layer, h, extras)
         qkv = h @ layer["wqkv"].astype(cfg.dtype)     # [B, T, 3·D/tp]
         B, T, _ = qkv.shape
         # HEAD-major column layout [D, H, 3, dh]: a tp column-slice holds
@@ -168,69 +273,167 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh: Mesh):
             # directly (head-major columns) and returns [B, T, H·dh] — no
             # [B,T,H,dh] <-> [BH,T,dh] transposes on either side
             # (~11 ms/step of layout copies at the LM bench config).
-            attn = flash_attention_qkv(qkv, n_heads_local,
+            return flash_attention_qkv(qkv, n_heads_local,
                                        causal=True).astype(cfg.dtype)
+        qkv = qkv.reshape(B, T, n_heads_local, 3, d_head)
+        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        if has_sp:
+            attn = ring_attention(q, k, v, axis_name="sp", causal=True)
         else:
-            qkv = qkv.reshape(B, T, n_heads_local, 3, d_head)
-            q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-            if has_sp:
-                attn = ring_attention(q, k, v, axis_name="sp", causal=True)
-            else:
-                # Single-shard attention: the Pallas blockwise kernel by
-                # default (scores never hit HBM in forward OR backward);
-                # untilable shapes fall back to XLA dense inside.
-                attn = flash_attention(q, k, v, causal=True,
-                                       backend=cfg.attn_backend
-                                       ).astype(cfg.dtype)
-            attn = attn.reshape(B, T, n_heads_local * d_head)
-        proj = attn @ layer["wo"].astype(cfg.dtype)
+            # Single-shard attention: the Pallas blockwise kernel by
+            # default (scores never hit HBM in forward OR backward);
+            # untilable shapes fall back to XLA dense inside.
+            attn = flash_attention(q, k, v, causal=True,
+                                   backend=cfg.attn_backend
+                                   ).astype(cfg.dtype)
+        return attn.reshape(B, T, n_heads_local * d_head)
+
+    def _projected_attention(layer, h, extras):
+        """Separate q/k/v projections: grouped key/value heads, per-head
+        RMSNorm, RoPE, and dense or indexer-selected attention."""
+        from ..ops.pallas_attention import flash_attention
+        from ..ops.sparse_attention import dsa_attention
+        B, T, _ = h.shape
+        kv_local = _kv_heads(cfg) // tp_size
+
+        def heads(w, n):
+            return (h @ layer[w].astype(cfg.dtype)).reshape(B, T, n, d_head)
+        q, k = heads("wq", n_heads_local), heads("wk", kv_local)
+        v = heads("wv", kv_local)
+        if cfg.qk_norm:
+            q, k = _rms_norm(q, layer["q_norm"]), _rms_norm(k, layer["k_norm"])
+        if cfg.rope_theta:
+            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+        if cfg.indexer:
+            ix = cfg.indexer
+            hs = lax.stop_gradient(h)
+            with jax.named_scope("attn.indexer"):
+                qi = (hs @ layer["idx_wq"].astype(cfg.dtype)).reshape(
+                    B, T, ix.n_heads, ix.d_head)
+                ki = _layer_norm(hs @ layer["idx_wk"].astype(cfg.dtype),
+                                 layer["idx_k_scale"], layer["idx_k_bias"])
+                if cfg.rope_theta:
+                    qi, ki = _rope(qi, cfg.rope_theta), _rope(ki,
+                                                              cfg.rope_theta)
+                w = (hs @ layer["idx_ww"].astype(cfg.dtype)).astype(
+                    jnp.float32) * (ix.n_heads * ix.d_head) ** -0.5
+            attn, extras["kl_sum"], extras["kl"], mask = dsa_attention(
+                q, k, v, qi, ki, w, topk=ix.topk, backend=cfg.attn_backend)
+            if with_masks:
+                extras["mask"] = mask
+        else:
+            group = n_heads_local // kv_local
+            attn = flash_attention(q, jnp.repeat(k, group, axis=2),
+                                   jnp.repeat(v, group, axis=2), causal=True,
+                                   backend=cfg.attn_backend)
+        return attn.astype(cfg.dtype).reshape(B, T, n_heads_local * d_head)
+
+    def _layer_fwd(layer, x):
+        extras = {}
+        h = _rms_norm(x, layer["ln1"])
+        proj = _attention(layer, h, extras) @ layer["wo"].astype(cfg.dtype)
         if has_tp:
             proj = lax.psum(proj, "tp")               # row-parallel combine
         x = x + proj
-        return _ffn(layer, x, B, T)
-
-    def _ffn(layer, x, B, T):
         h = _rms_norm(x, layer["ln2"])
-        if has_ep and cfg.n_experts:
-            flat = h.reshape(-1, cfg.d_model)
-            y, aux = moe_ffn(flat, layer["gate"].astype(cfg.dtype),
-                             layer["w1"][0].astype(cfg.dtype),
-                             layer["w2"][0].astype(cfg.dtype),
-                             axis_name="ep")
-            x = x + y.reshape(B, T, cfg.d_model)
+        gated = cfg.mlp == "swiglu"
+        w_up, w_down = ("w_up", "w_down") if gated else ("w1", "w2")
+        if cfg.n_experts:
+            B, T, _ = h.shape
+            y, stats = moe_ffn(
+                h.reshape(-1, cfg.d_model), layer["router"],
+                layer[w_up].astype(cfg.dtype), layer[w_down].astype(cfg.dtype),
+                w_gate=layer["w_gate"].astype(cfg.dtype) if gated else None,
+                top_k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
+                first_expert=cfg.first_expert,
+                axis_name="ep" if "ep" in axes else None)
+            extras.update(stats)
+            return x + y.reshape(B, T, cfg.d_model), extras
+        up = h @ layer[w_up].astype(cfg.dtype)
+        if gated:
+            up = jax.nn.silu(h @ layer["w_gate"].astype(cfg.dtype)) * up
         else:
-            aux = jnp.zeros((), jnp.float32)
-            up = jax.nn.gelu(h @ layer["w1"].astype(cfg.dtype))
-            down = up @ layer["w2"].astype(cfg.dtype)
-            if has_tp:
-                down = lax.psum(down, "tp")
-            x = x + down
-        return x, aux
+            up = jax.nn.gelu(up)
+        down = up @ layer[w_down].astype(cfg.dtype)
+        if has_tp:
+            down = lax.psum(down, "tp")
+        return x + down, extras
 
     if cfg.remat:
         _layer_fwd = jax.checkpoint(
             _layer_fwd, policy=jax.checkpoint_policies.dots_saveable)
 
     x = params["embed"][tokens].astype(cfg.dtype)     # [B, T, D]
-    aux_total = jnp.zeros((), jnp.float32)
+    per_layer = []
     for layer in params["layers"]:
-        x, aux = _layer_fwd(layer, x)
-        aux_total = aux_total + aux
+        x, extras = _layer_fwd(layer, x)
+        per_layer.append(extras)
+    return _rms_norm(x, params["lnf"]), per_layer
 
-    x = _rms_norm(x, params["lnf"])
-    return x, aux_total
+
+def _aux_total(per_layer):
+    return sum((e["aux"] for e in per_layer if "aux" in e),
+               jnp.zeros((), jnp.float32))
+
+
+def forward_hidden(params, tokens, cfg: TransformerConfig, mesh: Mesh):
+    """Runs INSIDE shard_map: ``tokens`` [B_local, T_local] int32.
+    Returns (final hidden states [B_local, T_local, d_model] — the
+    pre-unembed activations — and the MoE aux loss). The chunked-loss
+    path consumes this directly so the [*, vocab] logits never
+    materialize; :func:`forward` layers the unembedding on top."""
+    x, per_layer = _forward_layers(params, tokens, cfg, mesh)
+    return x, _aux_total(per_layer)
+
+
+def _unembedding(params, cfg: TransformerConfig):
+    """The [vocab, d_model] matrix the logits are taken against: the
+    embedding itself (tied) or the head of its own."""
+    return params["embed"] if cfg.tied_head else params["head"]
+
+
+def _logits(x, params, cfg: TransformerConfig):
+    # bf16 MXU pass with f32 accumulation when unembed_dtype is bf16;
+    # logits are f32 either way for a stable softmax.
+    return jnp.matmul(x.astype(cfg.unembed_dtype),
+                      _unembedding(params, cfg).T.astype(cfg.unembed_dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _over_mesh(fn, cfg: TransformerConfig, mesh: Mesh):
+    """``fn(params, tokens)``, written for the inside of a shard_map, as a
+    function of global arrays: parameters by :func:`param_specs`, tokens
+    replicated (every device computes every sequence; the sequence axis
+    over ``sp``), outputs likewise. A Mosaic kernel cannot be partitioned
+    by the compiler, so on several devices the forward must be mapped.
+    ``fn`` returns (an array over the sequence, a scalar)."""
+    if mesh.size == 1:
+        return fn
+    seq = P(None, "sp" if "sp" in _axes(mesh) else None)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(param_specs(cfg, mesh), seq),
+                         out_specs=(seq, P()), check_vma=False)
 
 
 def forward(params, tokens, cfg: TransformerConfig, mesh: Mesh):
-    """Full forward: hidden states through the tied unembed.
-    Returns (logits [B_local, T_local, vocab], moe_aux_loss)."""
-    x, aux_total = forward_hidden(params, tokens, cfg, mesh)
-    # Tied head: bf16 MXU pass with f32 accumulation when unembed_dtype is
-    # bf16; logits are f32 either way for a stable softmax.
-    logits = jnp.matmul(x.astype(cfg.unembed_dtype),
-                        params["embed"].T.astype(cfg.unembed_dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, aux_total
+    """Full forward of GLOBAL arrays, for an evaluation beside the step:
+    hidden states through the unembedding. Returns (logits [B, T, vocab],
+    moe_aux_loss); on a mesh of several devices every device computes every
+    sequence. Inside a shard_map, :func:`forward_hidden` is the function."""
+    def local(p, t):
+        x, aux_total = forward_hidden(p, t, cfg, mesh)
+        return _logits(x, p, cfg), aux_total
+    return _over_mesh(local, cfg, mesh)(params, tokens)
+
+
+def forward_with_stats(params, tokens, cfg: TransformerConfig, mesh: Mesh):
+    """The training forward with what each block produced beside the
+    hidden states: (logits, per-layer dicts as :func:`_forward_layers`
+    gives them, selections included). For checks and counters, off the
+    step's path."""
+    x, per_layer = _forward_layers(params, tokens, cfg, mesh,
+                                   with_masks=True)
+    return _logits(x, params, cfg), per_layer
 
 
 def dense_nll(logits, labels):
@@ -332,6 +535,12 @@ def _check_dense(cfg: TransformerConfig, what: str):
             f"{what} supports dense FFNs only (cfg.n_experts="
             f"{cfg.n_experts}); the MoE dispatch has no incremental-decode "
             f"path yet")
+    if not (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head):
+        raise NotImplementedError(
+            f"{what} runs the dense block only (packed wqkv, GELU, tied "
+            f"head): grouped key/value heads, q/k norm, RoPE, a gated "
+            f"feed-forward, an untied head and sparse attention exist on "
+            f"the training path alone")
 
 
 def init_kv_cache(cfg: TransformerConfig, max_slots: int, max_len: int,
@@ -698,11 +907,20 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     from ..optimizer import DistributedOptimizer
 
     axes = _axes(mesh)
-    if cfg.n_experts and "ep" in axes \
-            and cfg.n_experts != mesh.shape["ep"]:
+    ep = mesh.shape.get("ep", 1)
+    if cfg.n_experts and (_held(cfg) % ep or cfg.first_expert + _held(cfg)
+                          > cfg.n_experts):
         raise ValueError(
-            f"n_experts={cfg.n_experts} must equal the ep mesh axis size "
-            f"{mesh.shape['ep']} (one expert per ep rank)")
+            f"the {_held(cfg)} experts held (of n_experts={cfg.n_experts}, "
+            f"from {cfg.first_expert}) must divide over the ep mesh axis "
+            f"of size {ep} and lie among the router's")
+    if not _packed_qkv(cfg) and "sp" in axes:
+        raise NotImplementedError(
+            "ring attention over sp takes the dense block only (no RoPE "
+            "offset, no grouped heads, no indexer)")
+    if cfg.indexer and "tp" in axes:
+        raise NotImplementedError(
+            "sparse attention's selection and KL are over all heads: no tp")
     # Batch dim shards over dp AND ep (GShard layout: ep ranks carry
     # distinct tokens; experts see everyone's via the all_to_all); sequence
     # dim over sp.
@@ -717,13 +935,18 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
         fusion_threshold=fusion_threshold, mesh=mesh, param_specs=specs)
 
     def _loss_fn(params, tokens, labels):
+        x, per_layer = _forward_layers(params, tokens, cfg, mesh)
         if cfg.loss_chunk:
-            x, aux = forward_hidden(params, tokens, cfg, mesh)
-            nll = chunked_nll(x, params["embed"], labels, cfg)
+            nll = chunked_nll(x, _unembedding(params, cfg), labels, cfg)
         else:
-            logits, aux = forward(params, tokens, cfg, mesh)
-            nll = dense_nll(logits, labels)
-        loss = jnp.mean(nll) + aux_weight * aux
+            nll = dense_nll(_logits(x, params, cfg), labels)
+        loss = jnp.mean(nll) + aux_weight * _aux_total(per_layer)
+        if cfg.indexer:
+            # Mean over layers and rows of the indexer's KL: it trains the
+            # indexer alone, the NLL everything else (ops/sparse_attention).
+            loss = loss + jnp.sum(
+                jnp.stack([e["kl_sum"] for e in per_layer])
+            ) / (len(per_layer) * tokens.size)
         return loss
 
     def _vag(params, batch_stats, tokens, labels, rng):

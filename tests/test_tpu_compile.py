@@ -219,3 +219,37 @@ def test_forward_residency_gate(T, dtype, resident):
     assert pa._fits_vmem(T, D, jnp.dtype(dtype).itemsize,
                          b=pa._pick_block(T, pa._WANT_BLOCK), bwd=False,
                          kv_resident=True) is resident
+
+
+@pytest.mark.parametrize("B,T,Hq,Hkv", [
+    (2, 8192, 32, 4),      # the Keye cell: 8 query heads a key/value head
+    (1, 1024, 4, 4),       # one head a group: stat lanes padded
+], ids=["keye_8k", "group_of_one"])
+def test_sparse_attention_kernels_compile_and_carry_their_names(
+        one_chip, monkeypatch, B, T, Hq, Hkv):
+    """``dsa_fwd``, ``dsa_bwd_dq`` and ``dsa_bwd_dkv``
+    (ops/pallas_sparse_attention.py) at the published head layout: an
+    int8 selection tile beside bf16 q/k/v, lane slices of a group's
+    heads, [tq, 8] stat blocks — what interpret mode cannot refuse."""
+    from horovod_tpu.ops import pallas_sparse_attention as ps
+    monkeypatch.setattr(ps, "_interpret", lambda: False)
+    jax.clear_caches()
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def loss(q, k, v, mask):
+        with jax.named_scope("attn.sparse"):
+            o, _ = ps.attend(q, k, v, mask)
+        return jnp.sum(o.astype(jnp.float32))
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(B, T, Hq, D), shape(B, T, Hkv, D), shape(B, T, Hkv, D),
+            shape(B, T, T, dtype=jnp.int8)).compile().as_text()
+    jax.clear_caches()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    names = sorted(ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+                   for ln in kernels)
+    assert names == ["dsa_bwd_dkv", "dsa_bwd_dq", "dsa_fwd"]
+    assert any("attn.sparse" in ln and "dsa_fwd" in ln for ln in kernels)
