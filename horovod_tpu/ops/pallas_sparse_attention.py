@@ -4,18 +4,28 @@ and the XLA forms): flash attention's online softmax, on the causal tile
 schedule, with the selection read as an int8 ``[tq, tk]`` tile that all the
 heads of a group share.
 
-One grid step works one (batch, key/value head, q tile, k tile) and loops
-over the group's query heads inside: q arrives as the projection's output
+One visit works one (batch, key/value head, q tile, k tile) and loops over
+the group's query heads inside: q arrives as the projection's output
 ``[B, T, Hq*d]`` (head-major columns), so a block of ``G*d`` columns is one
 key/value head's group and each head a lane-aligned slice of it; nothing is
 transposed on either side. K/V tiles above the diagonal are neither
-fetched (their block index is clamped) nor computed. A tile that holds no
-selected pair is computed like any other: at top-2048 of 8192 keys nearly
-every tile holds some.
+fetched nor computed. A tile that holds no selected pair is computed like
+any other: at top-2048 of 8192 keys nearly every tile holds some.
+
+The forward and the split backward stream K/V tiles over a fourth grid
+axis whose block index is clamped at the diagonal. The backward is chosen
+from the shape by ``_bwd_plan``: ``fused`` — one kernel on grid (b, g, qi)
+with K, V and the float32 dk/dv accumulators of the whole sequence in VMEM
+and an in-kernel loop over the k tiles, dq, dk and dv from one visit of
+each pair — wherever those residents fit ``_VMEM_LIMIT`` (bf16, d 128, a
+group of 8: to T 15360), and ``split`` — a dq kernel and a dkv kernel that
+each rebuild the probabilities — beyond.
+``hvd_dsa_bwd_plan_total{plan=}`` counts the traces of each.
 
 Kernel names (``pallas_call(name=)``; a device trace and the compiled HLO
-find the kernels by them, so they are API): ``dsa_fwd``, ``dsa_bwd_dq``,
-``dsa_bwd_dkv``, and the indexer's loss ``dsa_kl`` (below).
+find the kernels by them, so they are API): ``dsa_fwd``; ``dsa_bwd``
+(fused) or ``dsa_bwd_dq`` and ``dsa_bwd_dkv`` (split); and the indexer's
+loss ``dsa_kl`` (below).
 """
 
 from __future__ import annotations
@@ -32,6 +42,11 @@ from .pallas_attention import _dot, _grid_params, _lanes
 _TQ, _TK = 256, 512
 _STAT_LANES = 8          # lse / delta: one lane per head of the group
 _VMEM_LIMIT = 64 * 2 ** 20
+# Width of the strips the fused backward cuts the DIAGONAL k tile into (a
+# q tile sees the first strip of its diagonal tile, or both). Measured on
+# a v5e (PERF.md PR 29, the kernel alone at the Keye shape): 256 -> 15.38
+# ms a layer, 512 (the tile whole) -> 15.63.
+_DIAG = 256
 
 
 def tilable(T: int, d: int) -> bool:
@@ -166,6 +181,69 @@ def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
+                      delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
+                      dv_acc, qs_ref, lse_rep, delta_rep, *, G: int, d: int,
+                      scale: float):
+    """dq, dk and dv from ONE visit of each (q tile, k tile) pair, grid
+    (b, g, qi): K and V of the (batch, key/value head) are whole in VMEM
+    (fetched once a head, not once a q tile), an in-kernel loop walks the
+    k tiles up to the diagonal and none beyond, and per head s, p, dp and
+    ds are computed once and spent three ways: 5 matmuls and 1 exp pass a
+    head and pair, against 7 and 2 over ``_dq_kernel`` + ``_dkv_kernel``.
+    dq accumulates per q tile; dk and dv over the whole visit in [T, d]
+    float32 scratch, zeroed at the first q tile and written at the last.
+    The diagonal k tile is visited in strips of ``_DIAG`` columns, and of
+    those only the ones a row of the q tile can see."""
+    qi, nq = pl.program_id(2), pl.num_programs(2)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    # Once a q tile: q scaled as the forward scales it, and the stats
+    # replicated over their lanes (a [tq, 1] column would pay a lane
+    # broadcast against every [tq, tk] block).
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+    for h in range(G):
+        qs_ref[:, h * d:(h + 1) * d] = _scaled_head(q_ref, h, d, scale)
+        lse_rep[h] = jnp.broadcast_to(_col(lse_ref, h), (_TQ, 128))
+        delta_rep[h] = jnp.broadcast_to(_col(delta_ref, h), (_TQ, 128))
+
+    def visit(start, width: int):
+        """The q tile against the key rows [start, start + width): the
+        selection is widened once and shared by the group's heads."""
+        rows = pl.ds(pl.multiple_of(start, width), width)
+        k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+        sel = mask_ref[0, :, rows].astype(jnp.int32) != 0
+        for h in range(G):
+            cols = slice(h * d, (h + 1) * d)
+            q, do = qs_ref[:, cols], do_ref[0, :, cols]
+            p = jnp.where(sel, jnp.exp(_dot(q, k, 1, 1)
+                                       - _lanes(lse_rep[h], width)), 0.0)
+            ds = (p * (_dot(do, v, 1, 1) - _lanes(delta_rep[h], width))
+                  ).astype(k.dtype)
+            dq_acc[:, cols] += _dot(ds, k, 1, 0)
+            dv_acc[rows, :] += _dot(p.astype(do.dtype), do, 0, 0)
+            dk_acc[rows, :] += _dot(ds, q, 0, 0)
+
+    def below(kb, carry):
+        visit(kb * _TK, _TK)
+        return carry
+    last = _last_k(qi)
+    jax.lax.fori_loop(0, last, below, 0)
+    for j in range(_TK // _DIAG):
+        start = last * _TK + j * _DIAG
+        pl.when(start < (qi + 1) * _TQ)(functools.partial(visit, start, _DIAG))
+    dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+    @pl.when(qi == nq - 1)
+    def _():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -224,6 +302,45 @@ def _fwd(q, k, v, mask, *, Hkv: int):
     )(q, k, v, mask)
 
 
+def _bwd_plan(T: int, d: int, G: int, itemsize: int) -> str:
+    """Which backward a shape gets: ``fused`` (``dsa_bwd``) where its
+    whole-sequence residents fit ``_VMEM_LIMIT``, ``split``
+    (``dsa_bwd_dq`` + ``dsa_bwd_dkv``, tiles only) beyond.
+
+    The sum bounds what the v5e compiler allocates from above: the
+    smallest ``vmem_limit_bytes`` that compiles sat 2.2-2.7 MiB under it
+    at the Keye shape (T 8192, d 128, G 8, bf16: 35.7 against 38.0 MiB)
+    and at T 12288 and 16384, and further under it elsewhere (bf16 and
+    float32, G 1 to 8, T 1024 to 17408; ``tests/test_tpu_compile.py``
+    keeps both sides of the boundary compiling):
+
+    * scratch: the float32 accumulators dk/dv 2x[T, d] and dq [tq, G*d],
+      q scaled [tq, G*d], and the two stats lane-replicated [G, tq, 128];
+    * pipelined operands, DOUBLE-buffered even where they are constant
+      over a (batch, head) visit: K, V and the dk, dv outputs [T, d], the
+      q, dO and dq blocks [tq, G*d], the q tile's int8 selection rows
+      [tq, T] and two stat blocks (a full 128-lane tile in VMEM);
+    * stack: the [tq, tk] float32 intermediates (s/p, dp, ds and their
+      casts) and the selection widened to 32 bits.
+    """
+    block = _TQ * G * d
+    scratch = 4 * (2 * T * d + block) + itemsize * block \
+        + 2 * G * _TQ * 128 * 4
+    piped = itemsize * (4 * T * d + 3 * block) + _TQ * T \
+        + 2 * _TQ * 128 * 4
+    stack = 6 * _TQ * _TK * 4
+    return "fused" if scratch + 2 * piped + stack <= _VMEM_LIMIT else "split"
+
+
+def _plan_counter():
+    from ..obs.registry import registry
+    return registry().counter(
+        "hvd_dsa_bwd_plan_total",
+        "traces of the sparse-attention backward, by the kernels its shape "
+        "got: fused (dsa_bwd) or split (dsa_bwd_dq + dsa_bwd_dkv)",
+        labels=("plan",))
+
+
 @functools.partial(jax.jit, static_argnames=("Hkv",))
 def _bwd(q, k, v, mask, o, lse, do, *, Hkv: int):
     B, T, _ = q.shape
@@ -236,6 +353,30 @@ def _bwd(q, k, v, mask, o, lse, do, *, Hkv: int):
                     ((0, 0),) * 3 + ((0, _STAT_LANES - G),))
     args = (q, k, v, mask, do, lse, delta)
     scale = d ** -0.5
+    plan = _bwd_plan(T, d, G, q.dtype.itemsize)
+    _plan_counter().labels(plan=plan).inc()
+    kv_like = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+               jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if plan == "fused":
+        q_side = pl.BlockSpec((1, _TQ, G * d), lambda b, g, qi: (b, qi, g))
+        whole = pl.BlockSpec((1, T, d), lambda b, g, qi: (b, 0, g))
+        stat = pl.BlockSpec((1, 1, _TQ, _STAT_LANES),
+                            lambda b, g, qi: (b, g, qi, 0))
+        return _call(
+            functools.partial(_bwd_fused_kernel, G=G, d=d, scale=scale),
+            "dsa_bwd", (B, Hkv, T // _TQ),
+            in_specs=[q_side, whole, whole,
+                      pl.BlockSpec((1, _TQ, T), lambda b, g, qi: (b, qi, 0)),
+                      q_side, stat, stat],
+            out_specs=[q_side, whole, whole],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] + kv_like,
+            scratch_shapes=[pltpu.VMEM((_TQ, G * d), jnp.float32),
+                            pltpu.VMEM((T, d), jnp.float32),
+                            pltpu.VMEM((T, d), jnp.float32),
+                            pltpu.VMEM((_TQ, G * d), q.dtype),
+                            pltpu.VMEM((G, _TQ, 128), jnp.float32),
+                            pltpu.VMEM((G, _TQ, 128), jnp.float32)],
+        )(*args)
 
     def ins(sp):
         return [sp["q"], sp["kv"], sp["kv"], sp["mask"], sp["q"],
@@ -253,8 +394,7 @@ def _bwd(q, k, v, mask, o, lse, do, *, Hkv: int):
         functools.partial(_dkv_kernel, G=G, d=d, scale=scale),
         "dsa_bwd_dkv", (B, Hkv, T // _TK, T // _TQ),
         in_specs=ins(sp), out_specs=[sp["kv"], sp["kv"]],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        out_shape=kv_like,
         scratch_shapes=[pltpu.VMEM((_TK, d), jnp.float32),
                         pltpu.VMEM((_TK, d), jnp.float32)],
     )(*args)

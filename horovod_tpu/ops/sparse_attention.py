@@ -22,8 +22,11 @@ by (they are API, like the kernels' names): ``attn.indexer`` (I),
 ``attn.select`` (S), ``attn.sparse`` (o) and ``attn.indexer_loss`` (KL).
 The XLA forms below work a block of query rows at a time, so that no
 ``[heads, T, T]`` tensor exists; on a TPU ``attn.sparse`` runs the Pallas
-kernels ``dsa_fwd``, ``dsa_bwd_dq`` and ``dsa_bwd_dkv`` and
-``attn.indexer_loss`` the kernel ``dsa_kl`` (``pallas_call(name=)``).
+kernels ``dsa_fwd`` and ``dsa_bwd`` (dq, dk and dv from one visit of each
+tile pair; beyond the sequence lengths whose K, V and dk/dv fit the
+kernel's VMEM, the split ``dsa_bwd_dq`` and ``dsa_bwd_dkv``:
+``pallas_sparse_attention._bwd_plan``) and ``attn.indexer_loss`` the
+kernel ``dsa_kl`` (``pallas_call(name=)``).
 """
 
 from __future__ import annotations

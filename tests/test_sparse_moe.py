@@ -242,11 +242,26 @@ def test_ep2_equals_ep1_holding_both_halves():
         np.testing.assert_allclose(a, b, atol=2e-6, err_msg=str(path))
 
 
-def test_sparse_kernels_match_the_xla_form():
-    """``dsa_fwd`` / ``dsa_bwd_dq`` / ``dsa_bwd_dkv`` in interpret mode
-    against the row-block XLA form, values and gradients, with rows that
-    select nothing in their first tiles."""
-    B, T, Hq, Hkv, d = 1, 1024, 4, 2, 128
+def _force_plan(monkeypatch, plan):
+    """Pin the backward's plan (``fused``: ``dsa_bwd``; ``split``:
+    ``dsa_bwd_dq`` + ``dsa_bwd_dkv``). ``_bwd`` is jitted, so traces made
+    under another plan are dropped first."""
+    monkeypatch.setattr(kernels, "_bwd_plan", lambda T, d, G, itemsize: plan)
+    jax.clear_caches()
+
+
+def _plan_count(plan):
+    return kernels._plan_counter().labels(plan=plan).value
+
+
+@pytest.mark.parametrize("Hkv", [2, 4], ids=["group_of_2", "group_of_1"])
+@pytest.mark.parametrize("plan", ["fused", "split"])
+def test_sparse_kernels_match_the_xla_form(monkeypatch, plan, Hkv):
+    """``dsa_fwd`` and both backward plans in interpret mode against the
+    row-block XLA form: values, log-sum-exp and the three gradients, over
+    three k tiles, with rows that select nothing in their first tiles."""
+    _force_plan(monkeypatch, plan)
+    B, T, Hq, d = 1, 1536, 4, 128
     rng = np.random.default_rng(8)
     q, k, v = (jnp.asarray(rng.normal(size=(B, T, h, d)), jnp.float32)
                for h in (Hq, Hkv, Hkv))
@@ -254,7 +269,12 @@ def test_sparse_kernels_match_the_xla_form():
     ki = jnp.asarray(rng.normal(size=(B, T, 8)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(B, T, 2)), jnp.float32)
     mask, _ = sa.select_topk(qi, ki, w, 100)
-    assert kernels.tilable(T, d)
+    # Rows of the last k tile that keep themselves and what they chose in
+    # that tile only: two k tiles pass before their first selected key.
+    late = jnp.arange(1100, 1300)
+    mask = mask.at[:, 1100:1300, :1024].set(0).at[:, late, late].set(1)
+    assert kernels.tilable(T, d) and T // kernels._TK >= 3
+    before = _plan_count(plan)
 
     def run(fn):
         def loss(q, k, v):
@@ -268,6 +288,25 @@ def test_sparse_kernels_match_the_xla_form():
     np.testing.assert_allclose(lse2, lse1, atol=1e-5)
     for a, b in zip(g2, g1):
         np.testing.assert_allclose(a, b, atol=1e-4)
+    # One tick a trace of the backward, under the label of its plan.
+    assert _plan_count(plan) == before + 1
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("T,d,G,itemsize,plan", [
+    (8192, 128, 8, 2, "fused"),        # the Keye cell
+    (1024, 128, 1, 4, "fused"),        # the tests' float32 shapes
+    (15360, 128, 8, 2, "fused"),       # the last T that fits at G 8, bf16
+    (15872, 128, 8, 2, "split"),
+    (8192, 128, 8, 4, "fused"),
+    (12288, 128, 8, 4, "split"),       # float32 doubles the residents
+    (32768, 128, 1, 2, "split"),
+])
+def test_backward_plan_follows_the_shape(T, d, G, itemsize, plan):
+    """The plan is a function of (T, d, G, itemsize) alone: ``fused``
+    while the whole-sequence residents fit ``_VMEM_LIMIT``, ``split``
+    beyond (tests/test_tpu_compile.py holds the sum to the compiler)."""
+    assert kernels._bwd_plan(T, d, G, itemsize) == plan
 
 
 def test_dense_step_lowers_to_the_same_collectives_and_matmuls_as_before():

@@ -221,16 +221,23 @@ def test_forward_residency_gate(T, dtype, resident):
                          kv_resident=True) is resident
 
 
-@pytest.mark.parametrize("B,T,Hq,Hkv", [
-    (2, 8192, 32, 4),      # the Keye cell: 8 query heads a key/value head
-    (1, 1024, 4, 4),       # one head a group: stat lanes padded
-], ids=["keye_8k", "group_of_one"])
+_FUSED, _SPLIT = ["dsa_bwd", "dsa_fwd"], ["dsa_bwd_dkv", "dsa_bwd_dq", "dsa_fwd"]
+
+
+@pytest.mark.parametrize("B,T,Hq,Hkv,names", [
+    (2, 8192, 32, 4, _FUSED),    # the Keye cell: 8 query heads a k/v head
+    (1, 1024, 4, 4, _FUSED),     # one head a group: stat lanes padded
+    (2, 15360, 8, 1, _FUSED),    # the plan's last T at a group of 8, bf16
+    (2, 15872, 8, 1, _SPLIT),    # and the first beyond it
+], ids=["keye_8k", "group_of_one", "last_fused", "first_split"])
 def test_sparse_attention_kernels_compile_and_carry_their_names(
-        one_chip, monkeypatch, B, T, Hq, Hkv):
-    """``dsa_fwd``, ``dsa_bwd_dq`` and ``dsa_bwd_dkv``
-    (ops/pallas_sparse_attention.py) at the published head layout: an
-    int8 selection tile beside bf16 q/k/v, lane slices of a group's
-    heads, [tq, 8] stat blocks — what interpret mode cannot refuse."""
+        one_chip, monkeypatch, B, T, Hq, Hkv, names):
+    """``dsa_fwd`` and the backward the plan picks — ``dsa_bwd``, or
+    ``dsa_bwd_dq`` and ``dsa_bwd_dkv`` (ops/pallas_sparse_attention.py) —
+    at the published head layout: an int8 selection beside bf16 q/k/v,
+    lane slices of a group's heads, [tq, 8] stat blocks, and the fused
+    kernel's whole-sequence residents against ``_VMEM_LIMIT`` on both
+    sides of the plan's boundary — what interpret mode cannot refuse."""
     from horovod_tpu.ops import pallas_sparse_attention as ps
     monkeypatch.setattr(ps, "_interpret", lambda: False)
     jax.clear_caches()
@@ -249,7 +256,7 @@ def test_sparse_attention_kernels_compile_and_carry_their_names(
             shape(B, T, T, dtype=jnp.int8)).compile().as_text()
     jax.clear_caches()
     kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    names = sorted(ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+    found = sorted(ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
                    for ln in kernels)
-    assert names == ["dsa_bwd_dkv", "dsa_bwd_dq", "dsa_fwd"]
+    assert found == names
     assert any("attn.sparse" in ln and "dsa_fwd" in ln for ln in kernels)
