@@ -17,9 +17,9 @@ expiries).
 instead (a small transformer LM, mixed prompt lengths): per operating
 point it reports p50/p99 **time-to-first-token**, per-user and aggregate
 tokens/sec, and decode-slot occupancy — and prints one JSON line per
-point (``peak_bytes_per_chip`` from the same ``memory_stats`` probe
-``bench.py`` uses, KV-cache bytes, peak concurrent streams, block-pool
-and prefix-cache gauges) so the fixed-HBM capacity claims are checkable
+point (``peak_bytes_per_chip`` from the device's ``memory_stats``,
+KV-cache bytes, peak concurrent streams, block-pool and prefix-cache
+gauges) so the fixed-HBM capacity claims are checkable
 from the bench row. ``--json FILE`` additionally appends the lines to a
 file (the ci.sh capacity/prefix legs parse it).
 
@@ -98,9 +98,8 @@ def _percentile(xs, q):
 
 def _peak_bytes_per_chip():
     """Per-chip peak HBM bytes from the runtime's allocator stats, or
-    None where the backend keeps none (CPU) — the same probe bench.py
-    records, so the fixed-HBM capacity claim is checkable from the JSON
-    row."""
+    None where the backend keeps none (CPU), so the fixed-HBM capacity
+    claim is checkable from the JSON row."""
     import jax
     try:
         stats = jax.local_devices()[0].memory_stats()

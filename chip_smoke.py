@@ -219,8 +219,8 @@ def _lm_batch(c, mesh):
 
 def _lm_train(c, mesh, steps):
     """Build, AOT-compile and run the LM step; returns the pieces the
-    phases check. The carried state is donated (as bench.py does): an
-    undonated 470M f32 + Adam state would sit in HBM twice."""
+    phases check. The carried state is donated (as the benchmark's LM
+    family does): an undonated 470M f32 + Adam state would sit in HBM twice."""
     from horovod_tpu.parallel.transformer import make_parallel_train_step
     cfg = _lm_cfg(c)
     init_state, step = make_parallel_train_step(

@@ -24,99 +24,9 @@ import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
-from ..ops import pallas_conv
-
 ModuleDef = Any
-
-
-class _FusedConv1x1(nn.Module):
-    """1x1 conv via the fused Pallas kernel (``ops/pallas_conv.py``).
-
-    Owns the SAME variable tree as ``nn.Conv(features, (1,1),
-    use_bias=False)`` — params/{name}/kernel [1,1,Cin,Cout] — so a model
-    built with ``conv_backend="fused"`` is checkpoint- and
-    param-compatible with the stock XLA path (the knob is purely a
-    performance choice).
-
-    Returns ``(y, s1, s2, count)``: the conv output plus its streamed
-    per-channel sum / sum-of-squares and the row count, feeding the
-    consumer :class:`_FoldedBN` without a separate stats pass over y.
-    """
-
-    features: int
-    dtype: Any = jnp.bfloat16
-    kernel_init: Callable = nn.initializers.lecun_normal()
-
-    @nn.compact
-    def __call__(self, x, ab=None, relu_prologue: bool = True):
-        n, h, w, c = x.shape
-        kernel = self.param("kernel", self.kernel_init,
-                            (1, 1, c, self.features), jnp.float32)
-        x2 = x.reshape(-1, c).astype(self.dtype)
-        y, s1, s2 = pallas_conv.fused_linear_bn_act(
-            x2, kernel.reshape(c, self.features), ab, relu=relu_prologue)
-        return (y.reshape(n, h, w, self.features), s1, s2, x2.shape[0])
-
-
-class _FoldedBN(nn.Module):
-    """BatchNorm as a folded per-channel affine ``a*y + b``.
-
-    Owns the SAME variables as ``nn.BatchNorm`` (params scale/bias,
-    batch_stats mean/var — float32, momentum/epsilon semantics matching
-    flax: biased variance, running update ``m*ra + (1-m)*batch``) but
-    instead of materializing the normalized tensor it returns ``(a, b)``
-    with ``a = scale*rsqrt(var+eps)``, ``b = bias - mean*a`` for the
-    consumer to fuse (a Pallas prologue or an XLA elementwise chain).
-
-    Batch statistics come either from a producer kernel's streamed moments
-    (``s1``/``s2``/``count``) or from a raw tensor ``x`` (one XLA
-    reduction pass — used after the 3x3 conv, whose output the fused 1x1
-    consumer reads anyway).
-    """
-
-    use_running_average: bool = False
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-    axis_name: Optional[str] = None
-    scale_init: Callable = nn.initializers.ones
-
-    @nn.compact
-    def __call__(self, s1=None, s2=None, count=None, x=None):
-        if x is not None:
-            xf = x.astype(jnp.float32)
-            axes = tuple(range(x.ndim - 1))
-            mean = jnp.mean(xf, axis=axes)
-            mean2 = jnp.mean(xf * xf, axis=axes)
-        else:
-            mean = s1[0] / count
-            mean2 = s2[0] / count
-        features = mean.shape[-1]
-        scale = self.param("scale", self.scale_init, (features,),
-                           jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (features,),
-                          jnp.float32)
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros((features,), jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones((features,), jnp.float32))
-        if self.use_running_average:
-            mu, var = ra_mean.value, ra_var.value
-        else:
-            if self.axis_name is not None:
-                mean = jax.lax.pmean(mean, self.axis_name)
-                mean2 = jax.lax.pmean(mean2, self.axis_name)
-            mu = mean
-            var = mean2 - mu * mu
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * mu
-                ra_var.value = m * ra_var.value + (1 - m) * var
-        a = scale * jax.lax.rsqrt(var + self.epsilon)
-        b = bias - mu * a
-        return a, b
 
 
 class BasicBlock(nn.Module):
@@ -144,123 +54,16 @@ class BasicBlock(nn.Module):
 
 
 class BottleneckBlock(nn.Module):
-    """ResNet v1 bottleneck (1x1 -> 3x3 -> 1x1 x4), used by ResNet-50.
-
-    ``fused=True`` routes the training-mode 1x1 convs through the fused
-    Pallas conv+BN+ReLU kernel (``ops/pallas_conv.py``) so the stage's
-    activation maps make two HBM transits per conv instead of four —
-    the traffic-reduction lever the measured ResNet-50 roofline identifies
-    (``docs/benchmarks.md``). The fused branch declares the SAME variable
-    tree as the stock branch (explicit ``name=`` scopes), so params and
-    checkpoints are interchangeable between backends; eval mode and
-    non-tilable shapes always use the stock XLA branch.
-    """
+    """ResNet v1 bottleneck (1x1 -> 3x3 -> 1x1 x4), used by ResNet-50."""
 
     filters: int
     strides: Tuple[int, int] = (1, 1)
     conv: ModuleDef = nn.Conv
     norm: ModuleDef = nn.BatchNorm
     act: Callable = nn.relu
-    fused: bool = False
-    # Which of the block's 1x1 convs route through the fused kernel — a
-    # measurement sub-knob (per-conv-site attribution of the pallas
-    # -boundary tax, docs/benchmarks.md r5). A module attribute rather
-    # than a trace-time env read so it participates in jit cache keys
-    # and cannot silently diverge across ranks (ADVICE r5); bench.py
-    # maps the HVD_FUSED_PARTS env sweep onto it at model construction.
-    fused_parts: Tuple[str, ...] = ("reduce", "expand", "shortcut")
-
-    def _fuse_settings(self):
-        """The conv/norm configuration when the fused branch applies, else
-        None (custom conv/norm/act flavors keep the stock semantics)."""
-        conv_kw = getattr(self.conv, "keywords", None)
-        norm_kw = getattr(self.norm, "keywords", None)
-        if (getattr(self.conv, "func", None) is not nn.Conv
-                or getattr(self.norm, "func", None) is not nn.BatchNorm):
-            return None
-        if conv_kw.get("use_bias", True) or self.act is not nn.relu:
-            return None
-        if norm_kw.get("use_running_average", False):
-            return None  # eval: BN folds to a constant affine, XLA fuses it
-        # Overrides the fused modules do not replicate (f32 params,
-        # lecun_normal kernels, fast-variance f32 stats) must fall back to
-        # the stock branch rather than silently diverge from it.
-        if any(k in conv_kw for k in
-               ("param_dtype", "kernel_init", "precision")):
-            return None
-        if any(k in norm_kw for k in
-               ("param_dtype", "scale_init", "bias_init")) \
-                or not norm_kw.get("use_fast_variance", True):
-            return None
-        return dict(dtype=conv_kw.get("dtype", jnp.float32),
-                    momentum=norm_kw.get("momentum", 0.99),
-                    epsilon=norm_kw.get("epsilon", 1e-5),
-                    axis_name=norm_kw.get("axis_name"))
-
-    def _fused_call(self, x, st):
-        parts = self.fused_parts
-        dtype = st["dtype"]
-        bn = functools.partial(
-            _FoldedBN, use_running_average=False, momentum=st["momentum"],
-            epsilon=st["epsilon"], axis_name=st["axis_name"])
-        f = self.filters
-        # 1x1 reduce: raw input in, stats epilogue out.
-        if "reduce" in parts:
-            y, s1, s2, cnt = _FusedConv1x1(f, dtype=dtype,
-                                           name="Conv_0")(x)
-            a1, b1 = bn(name="BatchNorm_0")(s1, s2, cnt)
-        else:
-            y = self.conv(f, (1, 1), name="Conv_0")(x)
-            a1, b1 = bn(name="BatchNorm_0")(x=y)
-        z = nn.relu(a1 * y.astype(jnp.float32) + b1).astype(dtype)
-        # 3x3 (carries the stride): XLA's conv — compute-bound at these
-        # shapes, not worth a hand kernel; its BN stats are one XLA
-        # reduction pass, folded into the next conv's prologue.
-        y = self.conv(f, (3, 3), self.strides, padding="SAME",
-                      name="Conv_1")(z)
-        a2, b2 = bn(name="BatchNorm_1")(x=y)
-        # 1x1 expand: BN+ReLU prologue (never materializes relu(bn(y))),
-        # stats epilogue (never re-reads the 4f-channel output).
-        if "expand" in parts:
-            y, s1, s2, cnt = _FusedConv1x1(4 * f, dtype=dtype,
-                                           name="Conv_2")(
-                y, jnp.stack([a2, b2]))
-            a3, b3 = bn(name="BatchNorm_2",
-                        scale_init=nn.initializers.zeros)(s1, s2, cnt)
-        else:
-            z2 = nn.relu(a2 * y.astype(jnp.float32) + b2).astype(dtype)
-            y = self.conv(4 * f, (1, 1), name="Conv_2")(z2)
-            a3, b3 = bn(name="BatchNorm_2",
-                        scale_init=nn.initializers.zeros)(x=y)
-        if x.shape[-1] != 4 * f or self.strides != (1, 1):
-            if "shortcut" in parts:
-                xs = x[:, ::self.strides[0], ::self.strides[1], :]
-                ys, s1s, s2s, cnts = _FusedConv1x1(
-                    4 * f, dtype=dtype, name="shortcut")(xs)
-                a4, b4 = bn(name="shortcut_bn")(s1s, s2s, cnts)
-            else:
-                ys = self.conv(4 * f, (1, 1), self.strides,
-                               name="shortcut")(x)
-                a4, b4 = bn(name="shortcut_bn")(x=ys)
-            residual = a4 * ys.astype(jnp.float32) + b4
-        else:
-            residual = x.astype(jnp.float32)
-        # Block tail (normalize + residual add + relu): one XLA loop fusion.
-        return nn.relu(a3 * y.astype(jnp.float32) + b3
-                       + residual).astype(dtype)
 
     @nn.compact
     def __call__(self, x):
-        if self.fused:
-            st = self._fuse_settings()
-            n, h, w, _ = x.shape
-            m = n * h * w
-            sh, sw = self.strides
-            ok = (st is not None and pallas_conv.fusable(m)
-                  and pallas_conv.fusable(m // (sh * sw))
-                  and h % sh == 0 and w % sw == 0)
-            if ok:
-                return self._fused_call(x, st)
         residual = x
         y = self.conv(self.filters, (1, 1))(x)
         y = self.norm()(y)
@@ -332,18 +135,6 @@ class ResNet(nn.Module):
     stem_space_to_depth: bool = False
     dtype: Any = jnp.bfloat16
     axis_name: Optional[str] = None
-    # "xla" = stock convs; "fused" = route training-mode 1x1 convs in
-    # bottleneck blocks through the fused Pallas conv+BN+ReLU kernel
-    # (checkpoint-compatible — see BottleneckBlock). ``fused_stages``
-    # selects which stages fuse: the default is the large-spatial-map
-    # stages 0-1 where the 1x1 convs are HBM-bound (measured r5 profile:
-    # fusing the deep compute-bound stages too REGRESSES ~2x — XLA's
-    # MXU-rich conv kernels win there and every pallas boundary costs
-    # layout copies; see docs/benchmarks.md).
-    conv_backend: str = "xla"
-    fused_stages: Sequence[int] = (0, 1)
-    # Per-site fusion selection forwarded to BottleneckBlock (see there).
-    fused_parts: Sequence[str] = ("reduce", "expand", "shortcut")
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -382,15 +173,9 @@ class ResNet(nn.Module):
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                extra = {}
-                if (self.conv_backend == "fused"
-                        and self.block_cls is BottleneckBlock
-                        and i in self.fused_stages):
-                    extra["fused"] = True
-                    extra["fused_parts"] = tuple(self.fused_parts)
                 x = self.block_cls(
                     self.num_filters * 2 ** i, strides=strides,
-                    conv=conv, norm=norm, **extra)(x)
+                    conv=conv, norm=norm)(x)
 
         if self.block_cls is PreActBlock:
             x = norm(name="final_bn")(x)

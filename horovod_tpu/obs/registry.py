@@ -4,7 +4,7 @@ One registry serves BOTH planes of this framework: the training loop
 (per-step wall time, samples/sec, bad-step and recovery counters) and
 the serving engines (request/TTFT latency, block-pool gauges). Until
 now every subsystem grew a one-off signal — chrome-trace timelines,
-JSON ``/stats`` reservoirs, heartbeat liveness, bench.py phase blocks —
+JSON ``/stats`` reservoirs, heartbeat liveness —
 and nothing was scrapeable by a standard collector. The exposition
 format here is Prometheus text format 0.0.4, the lowest common
 denominator every metrics stack ingests, so ``curl :PORT/metrics``

@@ -378,7 +378,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 # pass this number as their ``vmem_limit_bytes``, so the gate below and
 # the compiler hold the same limit (an override moves both). Read once at
 # import so every rank traces the same graph — a trace-time env read could
-# diverge across ranks (the HVD_FUSED_PARTS lesson, ADVICE r5).
+# diverge across ranks, and would not retrace a cached function.
 _VMEM_BUDGET_BYTES = int(os.environ.get("HVD_VMEM_BUDGET_MB", "16")) * 2**20
 
 

@@ -49,11 +49,11 @@ def create_hybrid_mesh(dp: int = 1, tp: int = 1, pp: int = 1, sp: int = 1,
     sizes = {"dp": dp, "pp": pp, "ep": ep, "sp": sp, "tp": tp}
     total = math.prod(sizes.values())
     if total != len(devs):
-        knobs = {"dp": "dp= (bench.py --mesh, examples --dp)",
+        knobs = {"dp": "dp= (create_hybrid_mesh(dp=), examples --dp)",
                  "pp": "pp= (examples --pp)",
                  "ep": "ep= (set n_experts to the ep size)",
                  "sp": "sp= (examples --sp)",
-                 "tp": "tp= (bench.py --tp/--mesh, examples --tp)"}
+                 "tp": "tp= (create_hybrid_mesh(tp=), examples --tp)"}
         detail = ", ".join(f"{a}={sizes[a]} via {knobs[a]}" for a in AXES
                            if sizes[a] != 1) or "all axes at their default 1"
         raise ValueError(

@@ -3,6 +3,9 @@ that actually learns (loss decreases) — the analog of the reference's
 examples-as-integration-tests CI (``.travis.yml:93-108`` runs shrunken
 MNIST/Keras examples end-to-end)."""
 
+import functools
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +14,7 @@ import pytest
 
 import horovod_tpu as hvd
 from horovod_tpu import models, training
+from horovod_tpu.models import resnet
 
 
 class TestModelShapes:
@@ -179,3 +183,147 @@ class TestTrainStep:
         plain = inner.init(state.params)
         assert (jax.tree_util.tree_structure(state.opt_state)
                 == jax.tree_util.tree_structure(plain))
+
+
+# ---------------------------------------------------------------------------
+# The bottleneck block and the ImageNet ResNets: what a checkpoint written by
+# any earlier build, the Goyal et al. recipe and the bf16 training path rely on.
+# ---------------------------------------------------------------------------
+
+def _bottleneck(filters, strides, *, train=True, dtype=jnp.float32):
+    """A block built the way ``ResNet.__call__`` builds its blocks."""
+    conv = functools.partial(nn.Conv, use_bias=False, dtype=dtype)
+    norm = functools.partial(nn.BatchNorm, use_running_average=not train,
+                             momentum=0.9, epsilon=1e-5, dtype=dtype)
+    return resnet.BottleneckBlock(filters, strides=strides, conv=conv,
+                                  norm=norm)
+
+
+# (strides, input channels) at filters=8: the first keeps its shape (4f in,
+# no projection), the second halves the map and projects the shortcut.
+_BLOCK_CASES = [pytest.param((1, 1), 32, id="stride1"),
+                pytest.param((2, 2), 16, id="stride2_projection")]
+
+
+@pytest.mark.parametrize("factory,count", [
+    pytest.param(models.resnet50, 25_557_032, id="resnet50"),
+    pytest.param(models.resnet101, 44_549_160, id="resnet101")])
+def test_imagenet_resnet_parameter_count(factory, count):
+    """He et al. 2015, Table 1 (benchmarks/configs/resnet50_imagenet.json
+    carries the 50-layer number)."""
+    model = factory(num_classes=1000)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 224, 224, 3)), train=False))
+    leaves = jax.tree_util.tree_leaves(shapes["params"])
+    assert sum(int(np.prod(l.shape)) for l in leaves) == count
+    assert all(l.dtype == jnp.float32 for l in leaves)
+
+
+@pytest.mark.parametrize("strides,cin", _BLOCK_CASES)
+def test_bottleneck_variable_tree(strides, cin):
+    """flax's automatic names and the kernels' layouts are the checkpoint
+    format: every saved ResNet-50 restores by them."""
+    f = 8
+    block = _bottleneck(f, strides)
+    v = block.init(jax.random.PRNGKey(0), jnp.zeros((2, 8, 8, cin)))
+    convs = {"Conv_0": (1, 1, cin, f), "Conv_1": (3, 3, f, f),
+             "Conv_2": (1, 1, f, 4 * f)}
+    norms = {"BatchNorm_0": f, "BatchNorm_1": f, "BatchNorm_2": 4 * f}
+    if strides != (1, 1) or cin != 4 * f:
+        convs["shortcut"] = (1, 1, cin, 4 * f)
+        norms["shortcut_bn"] = 4 * f
+    assert set(v) == {"params", "batch_stats"}
+    assert set(v["params"]) == set(convs) | set(norms)
+    assert set(v["batch_stats"]) == set(norms)
+    for name, shape in convs.items():
+        assert set(v["params"][name]) == {"kernel"}
+        assert v["params"][name]["kernel"].shape == shape
+    for name, width in norms.items():
+        assert set(v["params"][name]) == {"scale", "bias"}
+        assert set(v["batch_stats"][name]) == {"mean", "var"}
+        for leaf in (*v["params"][name].values(),
+                     *v["batch_stats"][name].values()):
+            assert leaf.shape == (width,)
+    assert all(l.dtype == jnp.float32 for l in jax.tree_util.tree_leaves(v))
+
+
+@pytest.mark.parametrize("strides,cin", _BLOCK_CASES)
+def test_bottleneck_batch_stats(strides, cin):
+    """Training mode moves the running statistics by (1 - momentum) x the
+    batch's and leaves ``params`` alone; eval mode reads them and mutates
+    nothing."""
+    f = 8
+    x = jnp.asarray(np.random.RandomState(2).randn(4, 8, 8, cin), jnp.float32)
+    block = _bottleneck(f, strides)
+    v = block.init(jax.random.PRNGKey(0), x)
+    # BatchNorm_2's scale starts at zero: set it so the statistics reach
+    # the output.
+    bn2 = v["params"]["BatchNorm_2"]
+    params = {**v["params"],
+              "BatchNorm_2": {**bn2, "scale": jnp.ones_like(bn2["scale"])}}
+    v = {"params": params, "batch_stats": v["batch_stats"]}
+
+    _, upd = block.apply(v, x, mutable=["batch_stats"])
+    assert set(upd) == {"batch_stats"}
+    # Conv_0 is a 1x1 at stride 1: its output is x @ kernel, pixel by pixel.
+    y0 = np.einsum("nhwc,cf->nhwf", np.asarray(x),
+                   np.asarray(params["Conv_0"]["kernel"][0, 0]))
+    got = upd["batch_stats"]["BatchNorm_0"]
+    np.testing.assert_allclose(np.asarray(got["mean"]),
+                               0.1 * y0.mean(axis=(0, 1, 2)),
+                               rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got["var"]),
+                               0.9 + 0.1 * y0.var(axis=(0, 1, 2)),
+                               rtol=1e-4, atol=1e-6)
+    if "shortcut_bn" in upd["batch_stats"]:
+        ys = np.einsum("nhwc,cf->nhwf",
+                       np.asarray(x)[:, ::strides[0], ::strides[1]],
+                       np.asarray(params["shortcut"]["kernel"][0, 0]))
+        np.testing.assert_allclose(
+            np.asarray(upd["batch_stats"]["shortcut_bn"]["mean"]),
+            0.1 * ys.mean(axis=(0, 1, 2)), rtol=1e-4, atol=1e-6)
+
+    eval_block = _bottleneck(f, strides, train=False)
+    trained = {"params": params, "batch_stats": upd["batch_stats"]}
+    first, kept = eval_block.apply(trained, x, mutable=["batch_stats"])
+    for a, b in zip(jax.tree_util.tree_leaves(kept["batch_stats"]),
+                    jax.tree_util.tree_leaves(upd["batch_stats"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(eval_block.apply(trained, x)),
+                                  np.asarray(first))
+    # It reads them: other running statistics, another output.
+    assert not np.allclose(np.asarray(eval_block.apply(v, x)),
+                           np.asarray(first))
+
+
+def test_bottleneck_starts_as_identity():
+    """BatchNorm_2's scale is zero at init (Goyal et al.), so a fresh block
+    that keeps its shape returns relu(x)."""
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 8, 8, 32), jnp.float32)
+    block = _bottleneck(8, (1, 1))
+    v = block.init(jax.random.PRNGKey(0), x)
+    assert not np.asarray(v["params"]["BatchNorm_2"]["scale"]).any()
+    assert np.asarray(v["params"]["BatchNorm_1"]["scale"]).all()
+    out, _ = block.apply(v, x, mutable=["batch_stats"])
+    np.testing.assert_array_equal(np.asarray(out), np.maximum(x, 0))
+
+
+def test_bottleneck_bf16_is_finite_and_keeps_dtype():
+    x = jnp.asarray(np.random.RandomState(5).randn(2, 16, 16, 16),
+                    jnp.bfloat16)
+    block = _bottleneck(8, (1, 1), dtype=jnp.bfloat16)
+    v = block.init(jax.random.PRNGKey(0), x)
+    assert all(l.dtype == jnp.float32 for l in jax.tree_util.tree_leaves(v))
+    out, _ = block.apply(v, x, mutable=["batch_stats"])
+    assert out.dtype == jnp.bfloat16 and out.shape == (2, 16, 16, 32)
+
+    def loss(p):
+        y, _ = block.apply({"params": p, "batch_stats": v["batch_stats"]},
+                           x, mutable=["batch_stats"])
+        return jnp.sum(y.astype(jnp.float32))
+
+    grads = jax.grad(loss)(v["params"])
+    for leaf in jax.tree_util.tree_leaves(grads):
+        assert leaf.dtype == jnp.float32
+        assert np.isfinite(np.asarray(leaf)).all()

@@ -26,9 +26,8 @@ so nobody re-chases them:
   option). At tens-of-MB bucket sizes (the 64 MiB product default on
   real models) the buckets survive as separate ops — verified below.
 
-The wall-clock side of the scaling claim is the committed
-``bench.py --scaling`` artifact (SCALING_cpu8.json) plus the projected
-v5e-64 model in ``docs/benchmarks.md``.
+The wall-clock side of the scaling claim is the ``lm_dp4_4chip`` cell
+against ``lm_step_1chip`` (``PERF.md`` section 2).
 """
 
 import re

@@ -26,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from horovod_tpu.ops import pallas_attention as pa
 from horovod_tpu.ops.pallas_paged_attention import paged_decode_attention
 
-H, D = 16, 128      # the LM's published head layout (bench.py _LM_TPU)
+H, D = 16, 128      # the LM cells' head layout (lm_pythia14b_width)
 
 
 @pytest.fixture(scope="module")
