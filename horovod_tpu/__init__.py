@@ -18,6 +18,10 @@ plane (multi-process) eagerly. See ``runtime.py`` and ``ops/``.
 
 from .version import __version__  # noqa: F401
 
+# Before anything can start the TPU backend: libtpu reads its arguments once.
+from .utils.chips import enable_async_collectives as _enable_async_collectives
+_enable_async_collectives()
+
 from .runtime import (  # noqa: F401
     AXIS,
     init,

@@ -30,6 +30,7 @@ memory and update FLOPs by the world size (``docs/performance.md``).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -516,9 +517,157 @@ def plan_exchange(leaves: Sequence[Any], *, world_size: int,
     return plan_buckets(leaves, fusion_threshold, groups=syncs), syncs
 
 
+def _count_bucket(where: str) -> None:
+    """``hvd_grad_sync_buckets_total{where=}``, at trace time: one tick for
+    each gradient bucket the plan gives a collective, by where the plan
+    issues it: ``backward`` (:func:`reduce_in_backward`) or ``after``."""
+    from ..obs.registry import registry
+    registry().counter(
+        "hvd_grad_sync_buckets_total",
+        "traces of a gradient bucket's collective, by where the plan "
+        "issues it: inside the backward pass or after it",
+        labels=("where",)).labels(where=where).inc()
+
+
+def _reduce_one(operand, sync: GradSync, prescale, wire):
+    """One operand's exchange by its :class:`GradSync`: the ``1/denom``
+    average folded into the fp32 prescale, then one ``psum`` over the
+    sync's axes in the operand's dtype or the wire's. An operand that is
+    sharded over every axis is only scaled."""
+    eff = prescale
+    if sync.denom > 1:
+        inv = 1.0 / sync.denom
+        eff = inv if eff is None else eff * inv
+    if not sync.psum:
+        return _prescale_array(operand, eff)
+    if _wire_applies(operand.dtype, wire):
+        return _wire_sum(operand, sync.psum, wire, prescale=eff)
+    return jax.lax.psum(_prescale_array(operand, eff), sync.psum)
+
+
+# Leaves up to this size share an operand inside a backward bucket (norm
+# scales, biases: the copy is nothing); a larger leaf rides alone, uncopied.
+_SMALL_LEAF_BYTES = 1 << 20
+
+
+def _fusion_groups(syncs: Sequence[GradSync]) -> List[Any]:
+    """The fusion-group key of each leaf: its :class:`GradSync` (frozen and
+    hashable, so the allreduce and ZeRO planes cannot drift on what "same
+    group" means; plan_zero passes the same objects). A leaf with nothing
+    to exchange (sharded over every axis, or reduced in the backward
+    already) is a group of its own: fusing it would be a copy for no
+    collective."""
+    return [s if s.psum else (s, i) for i, s in enumerate(syncs)]
+
+
+def _backward_operands(leaves, syncs: Sequence[GradSync]):
+    """The operands of one backward bucket, as leaf-index groups in issue
+    order."""
+    return plan_buckets(leaves, _SMALL_LEAF_BYTES,
+                        groups=_fusion_groups(syncs))
+
+
+def backward_carry(tree, syncs: Sequence[GradSync]):
+    """The value :func:`reduce_in_backward` threads through the forward,
+    from the lowest layer to the highest: zeros shaped like the last
+    operand a layer's bucket exchanges, which no forward op reads. Its
+    COTANGENT runs through the backward the other way and IS that operand's
+    reduced result: what the next bucket's first collective, and the
+    backward below it, are made to wait for. (A constant scalar would do
+    for jax; the TPU compiler forwards it through the barriers and the
+    chain is gone.) Layers must be uniform: one carry serves them all."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    exchanged = [m for m in _backward_operands(leaves, syncs)
+                 if syncs[m[0]].psum]
+    if not exchanged:
+        raise ValueError("no leaf of the tree is exchanged: nothing to "
+                         "reduce in the backward")
+    last = [leaves[j] for j in exchanged[-1]]
+    shape = last[0].shape if len(last) == 1 \
+        else (sum(int(math.prod(l.shape)) for l in last),)
+    return jnp.zeros(shape, last[0].dtype)
+
+
+def reduce_in_backward(tree, x, carry, syncs: Sequence[GradSync],
+                       bucket: int, *, wire=None):
+    """Identity on ``(tree, x, carry)`` whose BACKWARD is bucket ``bucket``
+    of the gradient exchange.
+
+    The cotangent of ``tree`` (a layer's parameters, so the layer's
+    complete gradient, as soon as the layer's backward has produced it) is
+    reduced there as ``syncs`` say (:func:`plan_grad_sync` of the tree's
+    specs). Each leaf over ``_SMALL_LEAF_BYTES`` is one collective of its
+    own with no concatenation copy; the small ones share one. Every operand
+    is barrier-chained on the result before it, and the first on the
+    carry's cotangent, which is the last result of the bucket before
+    (:func:`backward_carry`; nothing precedes bucket 0). So the whole
+    exchange is ONE chain in backward order: the compiler's combiner cannot
+    merge two links of it into a variadic all-reduce, which this libtpu
+    leaves synchronous (PERF.md section 6, PR 31).
+
+    ``x`` is the activation that enters the layer. The barrier that chains
+    this bucket's first operand on the carry holds ``x``'s cotangent too,
+    and the backward below needs that: so the bucket BEFORE this one must
+    be done before the backward goes on below this layer, not only before
+    the optimizer. Its operands were complete when the backward entered
+    this layer; the layer's backward is the compute the compiler's
+    scheduler runs that collective under (asynchronously where
+    ``utils/chips.enable_async_collectives`` switched that on).
+
+    Returns ``(tree, x, carry)``. The leaves come out of the gradient
+    ALREADY reduced: tell the optimizer (``update(..., presynced=)``)."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    if len(syncs) != len(leaves):
+        raise ValueError(
+            f"syncs must align with the tree: {len(syncs)} GradSync "
+            f"entries for {len(leaves)} leaves")
+    wire = resolve_wire_dtype(wire)
+    operands = _backward_operands(leaves, syncs)
+
+    def _bwd(_, cts):
+        g_leaves, g_x, carried = cts
+        # Nothing precedes bucket 0: its carry is the zero nobody set.
+        prev, tie = (carried, True) if bucket else (None, False)
+        reduced = [None] * len(leaves)
+        with jax.named_scope("optimizer"), \
+                jax.named_scope(f"allreduce.bucket{bucket}"):
+            for members in operands:
+                sync = syncs[members[0]]
+                gs = [g_leaves[j] for j in members]
+                operand = gs[0] if len(gs) == 1 else _fuse(gs)
+                if sync.psum and tie:
+                    # One barrier, not two in a row: the compiler drops the
+                    # unused half of a barrier that feeds a barrier. The
+                    # cotangent goes through it as [rows, features]: as
+                    # [B, T, d] the TPU's layout assignment gave the
+                    # barrier's operand T-minor tiles and carried them into
+                    # every activation of the step (15 ms of 408 on the
+                    # chip, PERF.md section 6, PR 31); merging the leading
+                    # dims is a bitcast and leaves it no such choice.
+                    shape = g_x.shape
+                    operand, g_x, _ = jax.lax.optimization_barrier(
+                        (operand, g_x.reshape(-1, shape[-1]), prev))
+                    g_x, tie = g_x.reshape(shape), False
+                elif sync.psum:
+                    operand = _barrier_chain(operand, prev)
+                r = _reduce_one(operand, sync, None, wire)
+                if sync.psum:
+                    prev = r
+                for j, rr in zip(members,
+                                 [r] if len(gs) == 1 else _unfuse(r, gs)):
+                    reduced[j] = rr
+            _count_bucket("backward")
+        return tuple(reduced), g_x, prev
+
+    tap = jax.custom_vjp(lambda *args: args)
+    tap.defvjp(lambda *args: (args, None), _bwd)
+    leaves, x, carry = tap(tuple(leaves), x, carry)
+    return treedef.unflatten(leaves), x, carry
+
+
 def _grouped_allreduce(leaves, treedef, syncs: Sequence[GradSync],
                        fusion_threshold, prescale, return_finite, wire,
-                       overlap_on: bool, grad_order):
+                       overlap_on: bool, grad_order, first_bucket: int = 0):
     """The N-D (spec-grouped) half of :func:`fused_allreduce`: leaves
     bucket within their :class:`GradSync` group (same psum axes, same
     denominator), each bucket rides ONE ``lax.psum`` over its group's
@@ -532,10 +681,7 @@ def _grouped_allreduce(leaves, treedef, syncs: Sequence[GradSync],
     with one scalar ``pmin`` over the missing axes, the only collective
     the guard adds on the hybrid plane (documented in
     docs/performance.md; the 1-D plane stays at zero extra)."""
-    # GradSync is frozen/hashable — the object IS the fusion-group key,
-    # so the allreduce and ZeRO planes cannot drift on what "same group"
-    # means (plan_zero passes the same objects).
-    groups = list(syncs)
+    groups = _fusion_groups(syncs)
     if overlap_on:
         order = None if grad_order is None \
             else tuple(int(i) for i in grad_order)
@@ -555,30 +701,23 @@ def _grouped_allreduce(leaves, treedef, syncs: Sequence[GradSync],
     finite_partial = jnp.ones((), jnp.bool_)
     missing_union: set = set()
     prev = None
-    for k, bucket in enumerate(buckets):
-        # The same per-bucket scope as fused_allreduce's 1-D loop.
-        with jax.named_scope(f"allreduce.bucket{k}"):
-            sync = syncs[bucket[0]]
+    k = first_bucket - 1
+    for bucket in buckets:
+        sync = syncs[bucket[0]]
+        # The same per-bucket scope as fused_allreduce's 1-D loop, numbered
+        # over the buckets that exchange something.
+        k += bool(sync.psum)
+        with jax.named_scope(f"allreduce.bucket{k}") if sync.psum \
+                else contextlib.nullcontext():
             if len(bucket) == 1:
                 operand = leaves[bucket[0]]
             else:
                 operand = _fuse([leaves[j] for j in bucket])
             if overlap_on and len(buckets) > 1:
                 operand = _barrier_chain(operand, prev)
-            eff = prescale
-            if sync.denom > 1:
-                inv = 1.0 / sync.denom
-                eff = inv if eff is None else eff * inv
+            r = _reduce_one(operand, sync, prescale, wire)
             if sync.psum:
-                if _wire_applies(operand.dtype, wire):
-                    r = _wire_sum(operand, sync.psum, wire, prescale=eff)
-                else:
-                    r = jax.lax.psum(_prescale_array(operand, eff),
-                                     sync.psum)
-            else:
-                # Fully sharded across every mesh axis: nothing to exchange,
-                # only the correction scale applies.
-                r = _prescale_array(operand, eff)
+                _count_bucket("after")
             if overlap_on:
                 prev = r
             if return_finite and jnp.issubdtype(r.dtype, jnp.inexact):
@@ -613,7 +752,8 @@ def fused_allreduce(tree, average: bool = True,
                     wire_dtype=None,
                     overlap: bool = False,
                     grad_order: Optional[Sequence[int]] = None,
-                    reduce_axes: Optional[Sequence[GradSync]] = None):
+                    reduce_axes: Optional[Sequence[GradSync]] = None,
+                    first_bucket: int = 0):
     """Allreduce a pytree with fusion bucketing. Compiled-context only
     (it is the gradient hot path inside the jitted train step).
 
@@ -626,7 +766,9 @@ def fused_allreduce(tree, average: bool = True,
     correction — folds into the bucket's one fused prescale. Requires
     ``average=True`` (the denominators define the averaging semantics) and
     dense leaves (sparse trees stay on the 1-D plane); ``axis_name`` is
-    ignored in this mode.
+    ignored in this mode. ``first_bucket`` numbers this call's bucket
+    scopes from there on (the buckets :func:`reduce_in_backward` issued
+    came first).
 
     Sparse (:class:`~horovod_tpu.ops.sparse.IndexedSlices`) leaves are kept
     whole and routed through the two-allgather sparse path — never flattened
@@ -695,7 +837,7 @@ def fused_allreduce(tree, average: bool = True,
         return _grouped_allreduce(
             leaves, treedef, reduce_axes, fusion_threshold, prescale,
             return_finite, wire, overlap or grad_order is not None,
-            grad_order)
+            grad_order, first_bucket)
     op = Op.AVERAGE if average else Op.SUM
     reduced: List[Optional[jax.Array]] = [None] * len(leaves)
     finite = jnp.ones((), jnp.bool_)

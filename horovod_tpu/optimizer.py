@@ -516,7 +516,9 @@ def _hybrid_allreduce_optimizer(optimizer, *, mesh, param_specs, skip_axes,
     ``reduce_axes=``), the wrapped transformation updates a full replica.
     State leaves mirror the params, so they are committed to the hybrid
     mesh with the SAME PartitionSpecs — tp-sharded weights' momenta shard
-    over tp too."""
+    over tp too. ``update(..., presynced=tree)`` names the leaves a step
+    already reduced inside its backward (the bucket's number, or -1): they
+    are not reduced again."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     prescale = None if accum_steps <= 1 else 1.0 / accum_steps
@@ -541,14 +543,26 @@ def _hybrid_allreduce_optimizer(optimizer, *, mesh, param_specs, skip_axes,
     def update_fn(grads, state, params=None, **extra):
         finite_out = extra.pop("finite_out", None)
         grad_order = extra.pop("grad_order", None)
+        presynced = extra.pop("presynced", None)
         specs = _specs_for(params if params is not None else grads)
         spec_leaves = jax.tree_util.tree_flatten(
             specs, is_leaf=lambda x: isinstance(x, P))[0]
         syncs = plan_grad_sync(spec_leaves, mesh, skip_axes=skip_axes)
+        first_bucket = 0
+        if presynced is not None:
+            # ``presynced``: per leaf, the bucket in which the backward
+            # already reduced it (ops/fusion.reduce_in_backward), or -1.
+            # Such a leaf has nothing left to exchange and no average to
+            # take: it passes through (and the guard still reads it).
+            ks = jax.tree_util.tree_leaves(presynced)
+            syncs = [s if k < 0 else dataclasses.replace(
+                s, psum=(), shard=s.shard + s.psum, denom=1)
+                for s, k in zip(syncs, ks)]
+            first_bucket = max(ks) + 1
         kw = dict(average=True, fusion_threshold=fusion_threshold,
                   prescale=prescale, wire_dtype=wire,
                   overlap=overlap, grad_order=grad_order,
-                  reduce_axes=syncs)
+                  reduce_axes=syncs, first_bucket=first_bucket)
         if finite_out is None:
             grads = fused_allreduce(grads, **kw)
         else:

@@ -239,7 +239,7 @@ def _rope(x, theta: float):
 
 
 def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
-                    with_masks: bool = False):
+                    with_masks: bool = False, grad_sync=None):
     """Runs INSIDE shard_map: ``tokens`` [B_local, T_local] int32. Returns
     the final hidden states [B_local, T_local, d_model] (normed, before
     the unembedding) and, per layer, a dict of what the block produced
@@ -247,7 +247,13 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     (the indexer's KL summed over each sequence's rows, [B], which the loss
     differentiates, and per row, [B, T], which it does not), the routing
     load ``held_load`` /
-    ``absent``, and with ``with_masks`` the int8 selection ``mask``."""
+    ``absent``, and with ``with_masks`` the int8 selection ``mask``.
+
+    ``grad_sync(k, layer, x, carry) -> (layer, x, carry)``, an identity
+    here, is the training step's hook for the gradient exchange
+    (:func:`make_parallel_train_step`): it sees layer ``k``'s parameters
+    and the activations that enter the layer, and threads its carry from
+    the lowest layer to the highest."""
     axes = _axes(mesh)
     has_tp = "tp" in axes
     has_sp = "sp" in axes
@@ -364,8 +370,11 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             _layer_fwd, policy=jax.checkpoint_policies.dots_saveable)
 
     x = params["embed"][tokens].astype(cfg.dtype)     # [B, T, D]
+    carry = None
     per_layer = []
-    for layer in params["layers"]:
+    for k, layer in enumerate(params["layers"]):
+        if grad_sync is not None:
+            layer, x, carry = grad_sync(k, layer, x, carry)
         x, extras = _layer_fwd(layer, x)
         per_layer.append(extras)
     return _rms_norm(x, params["lnf"]), per_layer
@@ -902,8 +911,22 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     ``wire_dtype`` (``"bf16"``/``"fp8"``; see ``docs/performance.md``
     "Overlap & wire formats") runs the data-parallel gradient averages in
     reduced wire precision with fp32 scales and fp32 result accumulation.
+
+    ``overlap``: ``None`` (the default) and ``True`` reduce each layer's
+    gradients INSIDE the backward wherever they cross chips (a sync axis
+    with more than one member, ``zero=False``, ``accum_steps == 1``): one
+    bucket a layer, issued when the layer's backward has produced it and
+    due before the backward goes on below the layer underneath, so the
+    all-reduce runs under that layer's backward
+    (``ops/fusion.reduce_in_backward``); the embedding, the head and the
+    final norm follow after the backward. ``False`` keeps the plan that
+    reduces everything after the backward. Where the in-backward plan
+    does not apply, ``True`` is PR 6's barrier-chained emission after the
+    backward, as on the flax plane.
     """
     from .. import training
+    from ..ops.fusion import (backward_carry, plan_grad_sync,
+                              reduce_in_backward)
     from ..optimizer import DistributedOptimizer
 
     axes = _axes(mesh)
@@ -930,12 +953,39 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
                    "sp" if "sp" in axes else None)
     specs = param_specs(cfg, mesh)
 
+    # Whether the layers' buckets are reduced inside the backward (the
+    # docstring's ``overlap``). With one member on every sync axis nothing
+    # crosses chips and the step is the old one, op for op; ZeRO's
+    # reduce-scatter and microbatch accumulation (one exchange per
+    # accumulated step) keep the plan that reduces after the backward.
+    layer_syncs = plan_grad_sync(
+        jax.tree_util.tree_leaves(
+            specs["layers"][:1], is_leaf=lambda x: isinstance(x, P)), mesh)
+    in_backward = (overlap is not False and not zero and accum_steps == 1
+                   and any(mesh.shape[a] > 1
+                           for s in layer_syncs for a in s.psum))
+    if in_backward:
+        overlap = False     # what is left after the backward needs no order
+
     dist_opt = DistributedOptimizer(
         optimizer, zero=zero, wire_dtype=wire_dtype, overlap=overlap,
         fusion_threshold=fusion_threshold, mesh=mesh, param_specs=specs)
 
+    def _bucket(k):
+        # Buckets are numbered in the order the backward issues them: the
+        # highest layer's first.
+        return cfg.n_layers - 1 - k
+
+    def _grad_sync(k, layer, x, carry):
+        if carry is None:
+            carry = backward_carry(layer, layer_syncs)
+        return reduce_in_backward(layer, x, carry, layer_syncs, _bucket(k),
+                                  wire=dist_opt.update.wire_dtype)
+
     def _loss_fn(params, tokens, labels):
-        x, per_layer = _forward_layers(params, tokens, cfg, mesh)
+        x, per_layer = _forward_layers(
+            params, tokens, cfg, mesh,
+            grad_sync=_grad_sync if in_backward else None)
         if cfg.loss_chunk:
             nll = chunked_nll(x, _unembedding(params, cfg), labels, cfg)
         else:
@@ -959,6 +1009,16 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
             with jax.named_scope("forward"):
                 return _loss_fn(p, tokens, labels), (None, None)
         return jax.value_and_grad(lf, has_aux=True)(params)
+
+    if in_backward:
+        # Which bucket of the backward reduced each leaf, -1 for none: the
+        # optimizer reduces only what is left.
+        _vag.presynced = {
+            **{name: -1 for name in specs if name != "layers"},
+            "layers": [jax.tree_util.tree_map(
+                lambda _, k=k: _bucket(k), layer,
+                is_leaf=lambda x: isinstance(x, P))
+                for k, layer in enumerate(specs["layers"])]}
 
     core = training.make_train_step(
         None, dist_opt, mesh=mesh, param_specs=specs,

@@ -585,6 +585,10 @@ def make_train_step(model,
         # DistributedOptimizer performs the fused allreduce over `axis_name`
         # — on the accumulated (microbatch-mean) tree, once per step.
         upd_kwargs = _overlap_kwargs(grads)
+        if getattr(vag, "presynced", None) is not None:
+            # Leaves the custom value-and-grad reduced inside its backward
+            # (parallel/transformer.py): the optimizer must not again.
+            upd_kwargs["presynced"] = vag.presynced
         with jax.named_scope("optimizer"):
             if guard_nonfinite:
                 finite_out: dict = {}

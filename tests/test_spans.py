@@ -329,6 +329,9 @@ def test_parallel_transformer_step_carries_the_same_scopes():
     tokens = jnp.zeros((4, 16), jnp.int32)
     text = step.lower(params, opt_state, tokens, tokens).as_text(
         debug_info=True)
+    # dp = 2: the layer's bucket is reduced inside the backward and keeps
+    # its scope there; what is left follows under the optimizer's.
     for scope in ('"jvp(forward)/', '"transpose(jvp(forward))/',
-                  '"optimizer/allreduce.bucket0/psum'):
+                  'transpose(jvp(forward))/optimizer/allreduce.bucket0/psum',
+                  '"optimizer/allreduce.bucket1/psum'):
         assert scope in text, scope

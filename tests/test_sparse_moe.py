@@ -309,21 +309,34 @@ def test_backward_plan_follows_the_shape(T, d, G, itemsize, plan):
     assert kernels._bwd_plan(T, d, G, itemsize) == plan
 
 
-def test_dense_step_lowers_to_the_same_collectives_and_matmuls_as_before():
+@pytest.mark.parametrize("overlap", [False, None])
+def test_dense_step_lowers_to_the_same_collectives_and_matmuls_as_before(
+        overlap):
     """The dense block goes through the described block's code and lowers
     to what it lowered to before it (counts taken on the parent commit of
-    PR 28, same configuration): 2 all-reduces, 41 matmuls."""
+    PR 28, same configuration): 41 matmuls, and 2 all-reduces under the plan
+    that reduces after the backward. The default plan (PR 31) reduces each
+    of the 2 layers inside the backward, operand by operand, and leaves the
+    embedding, the final norm and the loss: the same leaves, none twice."""
     cfg = TransformerConfig(vocab=256, d_model=256, n_heads=2, n_layers=2,
                             d_ff=512, dtype=jnp.bfloat16,
                             attn_backend="pallas",
                             unembed_dtype=jnp.bfloat16)
     mesh = create_hybrid_mesh(devices=jax.devices()[:4], dp=4)
     init_state, step = make_parallel_train_step(cfg, mesh,
-                                                optax.adamw(1e-3))
+                                                optax.adamw(1e-3),
+                                                overlap=overlap)
     state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct((8, 256), jnp.int32)
     text = step.lower(*state, tok, tok).as_text()
-    assert len(re.findall("all_reduce", text)) == 2
+    all_reduces = 2
+    if overlap is None:
+        from horovod_tpu.ops import fusion
+        layer = jax.tree_util.tree_leaves(state[0]["layers"][0])
+        sync = fusion.GradSync(("dp",), (), 4)
+        all_reduces = 3 + 2 * len(fusion._backward_operands(
+            layer, [sync] * len(layer)))
+    assert len(re.findall(r"stablehlo\.all_reduce", text)) == all_reduces
     assert len(re.findall("dot_general", text)) == 41
     assert "all_gather" not in text and "all_to_all" not in text
 
