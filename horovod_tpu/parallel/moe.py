@@ -69,6 +69,7 @@ def _tile(n: int, most: int) -> int:
 
 
 _GMM_ROWS = 512      # rows of a grouped product's tile on the TPU
+_SCAN_CHUNKS = 8     # chunks one scan takes, skipped ones included
 
 
 def _on_tpu() -> bool:
@@ -203,8 +204,26 @@ def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
         if n_chunks == 1:
             return add(y), None
         return lax.cond(c * cap < ends[-1], add, lambda y: y, y), None
-    y, _ = lax.scan(chunk, jnp.zeros((M, D), jnp.float32),
-                    (jnp.arange(n_chunks, dtype=jnp.int32), order, weight))
+    def run(y, chunks):
+        return lax.scan(chunk, y, chunks)[0]
+    y = jnp.zeros((M, D), jnp.float32)
+    chunks = (jnp.arange(n_chunks, dtype=jnp.int32), order, weight)
+    # A skipped turn of the scan is not free: its transpose still adds a
+    # zero the size of x and of every weight to their cotangents (8.6 ms a
+    # turn and layer at [16384, 2048] and 16 experts of 512, measured on a
+    # v5e, PERF.md PR 32). Up to _SCAN_CHUNKS chunks one scan takes them
+    # all; a smaller share's turns after the first go under ONE cond,
+    # which a balanced load (two thirds of a chunk) never enters.
+    if n_chunks <= _SCAN_CHUNKS:
+        return run(y, chunks).astype(x.dtype), sizes
+    # The checkpoint goes outside the cond (inside, what the turns close
+    # over would be saved once a turn: PERF.md PR 28).
+    @jax.checkpoint
+    def overflow(rest):
+        return lax.cond(cap < ends[-1], run, lambda y, _: y,
+                        jnp.zeros((M, D), jnp.float32), rest)
+    y = run(y, tuple(a[:1] for a in chunks)) \
+        + overflow(tuple(a[1:] for a in chunks))
     return y.astype(x.dtype), sizes
 
 
@@ -228,7 +247,8 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
     loss (Shazeer et al.: E x sum over experts of the share of assignments
     x the mean router probability), ``stats["held_load"]`` [held] the
     assignments that fell to each held expert, ``stats["absent"]`` the
-    assignments of these tokens that fell to experts not held here.
+    assignments of these tokens that fell to experts not held here,
+    ``stats["ids"]`` [N, top_k] the experts each token kept.
     """
     N, _ = x.shape
     E, held = router_w.shape[1], w_up.shape[0]
@@ -264,7 +284,7 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
         y = lax.psum_scatter(y, axis_name, tiled=True)
     local = ids - first_expert
     absent = N * top_k - jnp.sum((local >= 0) & (local < held))
-    return y, {"aux": aux, "held_load": load, "absent": absent}
+    return y, {"aux": aux, "held_load": load, "absent": absent, "ids": ids}
 
 
 _m_load = _registry().gauge(

@@ -15,7 +15,9 @@ Params are global jax.Arrays placed with `NamedSharding` spec trees
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -39,6 +41,26 @@ class Indexer:
 
 
 @dataclasses.dataclass(frozen=True)
+class GatedDeltaNet:
+    """A Gated DeltaNet linear-attention mixer (``ops/gated_delta.py``):
+    ``n_k_heads`` query/key heads of ``d_k``, each serving
+    ``n_v_heads // n_k_heads`` consecutive value heads of ``d_v``; a causal
+    depthwise convolution of ``conv_width`` over time on q, k and v; the
+    rule in chunks of ``chunk`` rows by ``backend`` (``"auto"``: the Pallas
+    kernels on a TPU, the XLA scan elsewhere)."""
+    n_k_heads: int
+    n_v_heads: int
+    d_k: int
+    d_v: int
+    conv_width: int = 4
+    chunk: int = 64
+    backend: str = "auto"
+
+
+LAYER_KINDS = ("attn", "gdn")
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 1024
     d_model: int = 128
@@ -56,6 +78,20 @@ class TransformerConfig:
     mlp: str = "gelu"           # "gelu" (w1, w2) | "swiglu" (w_gate, w_up, w_down)
     tied_head: bool = True      # False: an unembedding of its own ("head")
     indexer: Optional[Indexer] = None   # sparse attention over selected keys
+    # -- Layers of several kinds. ``layer_pattern`` is one period of kinds,
+    # repeated over the depth: "attn" (the softmax-attention mixer the
+    # fields above describe) or "gdn" (the linear-attention mixer ``gdn``
+    # describes); () = every layer "attn". Whatever its mixer, a layer ends
+    # in the same feed-forward or expert layer.
+    layer_pattern: Tuple[str, ...] = ()
+    gdn: Optional[GatedDeltaNet] = None
+    norm_offset: bool = False   # RMSNorm weights as (1 + w), w from zero
+    rope_fraction: float = 1.0  # RoPE over this leading share of a head
+    attn_gate: bool = False     # wq is doubled per head: q and a sigmoid
+    #                             gate on the attention's output
+    shared_expert_ff: int = 0   # >0: a gated expert of this width every
+    #                             token takes, behind a sigmoid gate, beside
+    #                             the routed ones (never part of a share)
     # Experts (n_experts > 0): each token keeps its moe_top_k; the weights
     # are renormalised over the kept ones or not. experts_held of the
     # n_experts live in this program, from first_expert on, spread over
@@ -106,7 +142,43 @@ def _packed_qkv(cfg: TransformerConfig) -> bool:
     """The dense block's attention: one packed ``wqkv`` of equal head
     counts and nothing between the projection and the flash kernel."""
     return not (cfg.n_kv_heads or cfg.d_head or cfg.qk_norm
-                or cfg.rope_theta or cfg.indexer)
+                or cfg.rope_theta or cfg.indexer or cfg.attn_gate)
+
+
+def layer_kind(cfg: TransformerConfig, i: int) -> str:
+    """The kind of layer ``i`` (from 0): the pattern, repeated."""
+    if not cfg.layer_pattern:
+        return "attn"
+    return cfg.layer_pattern[i % len(cfg.layer_pattern)]
+
+
+def _kinds(cfg: TransformerConfig):
+    return {layer_kind(cfg, i) for i in range(cfg.n_layers)}
+
+
+def _check_pattern(cfg: TransformerConfig):
+    unknown = set(cfg.layer_pattern) - set(LAYER_KINDS)
+    if unknown:
+        raise ValueError(f"layer_pattern names {sorted(unknown)}; the kinds "
+                         f"are {LAYER_KINDS}")
+    if "gdn" in cfg.layer_pattern:
+        g = cfg.gdn
+        if g is None:
+            raise ValueError("layer_pattern has 'gdn' layers and cfg.gdn "
+                             "does not describe them")
+        if g.n_v_heads % g.n_k_heads:
+            raise ValueError(f"gdn: {g.n_v_heads} value heads do not divide "
+                             f"over {g.n_k_heads} key heads")
+    if cfg.shared_expert_ff and not (cfg.n_experts and cfg.mlp == "swiglu"):
+        raise ValueError("shared_expert_ff is a gated expert beside routed "
+                         "ones: it needs n_experts > 0 and mlp='swiglu'")
+
+
+def _extended(cfg: TransformerConfig) -> bool:
+    """Whether the configuration uses what the layer pattern brought (its
+    parameters are drawn from more keys a layer)."""
+    return bool(cfg.layer_pattern or cfg.norm_offset or cfg.shared_expert_ff
+                or cfg.attn_gate)
 
 
 def _held(cfg: TransformerConfig) -> int:
@@ -115,14 +187,21 @@ def _held(cfg: TransformerConfig) -> int:
 
 def init_params(rng, cfg: TransformerConfig) -> Dict:
     """Global (unsharded-shape) parameter pytree; place with
-    :func:`param_specs` + ``jax.device_put`` before use."""
-    # The dense block draws what it always drew from a seed.
-    dense = _packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head
-    k = jax.random.split(rng, (4 + 6 * cfg.n_layers) if dense
-                         else (5 + 12 * cfg.n_layers))
+    :func:`param_specs` + ``jax.device_put`` before use. Layer ``i``'s
+    entry has the leaves of its kind (:func:`layer_kind`)."""
+    _check_pattern(cfg)
+    # The dense block draws what it always drew from a seed, and so does
+    # the described block of one kind.
+    dense = (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head
+             and not _extended(cfg))
+    per_layer = 6 if dense else 16 if _extended(cfg) else 12
+    k = jax.random.split(rng, (4 if dense else 5) + per_layer * cfg.n_layers)
     ki = iter(range(len(k)))
     norm = lambda key, shape, s: (jax.random.normal(k[key], shape) * s)  # noqa: E731
     d, dh = cfg.d_model, _d_head(cfg)
+    # A norm's weight multiplies as it stands (from one) or as 1 + w (from
+    # zero): the same function at birth.
+    gain = jnp.zeros if cfg.norm_offset else jnp.ones
     params: Dict[str, Any] = {
         # A tied table is the unembedding too: 0.02 keeps its logits
         # small. An untied one feeds the residual stream alone, whose
@@ -131,28 +210,45 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         # first block, and every token's hidden state nearly the same.
         "embed": norm(next(ki), (cfg.vocab, d),
                       0.02 if cfg.tied_head else 1.0),
-        "lnf": jnp.ones((d,)),
+        "lnf": gain((d,)),
         "layers": [],
     }
     if not cfg.tied_head:
         params["head"] = norm(next(ki), (cfg.vocab, d), d ** -0.5)
-    for _ in range(cfg.n_layers):
-        layer = {"ln1": jnp.ones((d,)), "ln2": jnp.ones((d,))}
-        if _packed_qkv(cfg):
+    for i in range(cfg.n_layers):
+        layer = {"ln1": gain((d,)), "ln2": gain((d,))}
+        if layer_kind(cfg, i) == "gdn":
+            g = cfg.gdn
+            nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
+            # Columns [q | k | v | z], heads-major inside each; [b | a].
+            layer["gdn_wqkvz"] = norm(next(ki), (d, 2 * nk + 2 * nv),
+                                      d ** -0.5)
+            layer["gdn_wba"] = norm(next(ki), (d, 2 * g.n_v_heads), d ** -0.5)
+            layer["gdn_conv"] = norm(next(ki), (g.conv_width, 2 * nk + nv),
+                                     g.conv_width ** -0.5)
+            # As the published module draws them: A uniform in (0, 16),
+            # kept as its logarithm; the step's bias at one.
+            layer["gdn_a_log"] = jnp.log(jax.random.uniform(
+                k[next(ki)], (g.n_v_heads,), minval=1e-3, maxval=16.0))
+            layer["gdn_dt_bias"] = jnp.ones((g.n_v_heads,))
+            layer["gdn_norm"] = jnp.ones((g.d_v,))      # plain weight
+            layer["gdn_wout"] = norm(next(ki), (nv, d), nv ** -0.5)
+        elif _packed_qkv(cfg):
             layer["wqkv"] = norm(next(ki), (d, 3 * d), d ** -0.5)
             layer["wo"] = norm(next(ki), (d, d), d ** -0.5)
         else:
             # Head-major columns, as wqkv: a tp column slice holds whole
-            # heads.
+            # heads (with attn_gate, each head's q then its gate).
             nq, nkv = cfg.n_heads * dh, _kv_heads(cfg) * dh
-            layer["wq"] = norm(next(ki), (d, nq), d ** -0.5)
+            layer["wq"] = norm(next(ki), (d, nq * (2 if cfg.attn_gate else 1)),
+                               d ** -0.5)
             layer["wk"] = norm(next(ki), (d, nkv), d ** -0.5)
             layer["wv"] = norm(next(ki), (d, nkv), d ** -0.5)
             layer["wo"] = norm(next(ki), (nq, d), nq ** -0.5)
             if cfg.qk_norm:
-                layer["q_norm"] = jnp.ones((dh,))
-                layer["k_norm"] = jnp.ones((dh,))
-        if cfg.indexer:
+                layer["q_norm"] = gain((dh,))
+                layer["k_norm"] = gain((dh,))
+        if cfg.indexer and layer_kind(cfg, i) == "attn":
             ix = cfg.indexer
             layer["idx_wq"] = norm(next(ki), (d, ix.n_heads * ix.d_head),
                                    d ** -0.5)
@@ -173,14 +269,26 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
             layer["w1"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
             layer["w2"] = norm(next(ki), lead + (cfg.d_ff, d),
                                cfg.d_ff ** -0.5)
+        if cfg.shared_expert_ff:
+            f = cfg.shared_expert_ff
+            layer["shared_gate"] = norm(next(ki), (d, f), d ** -0.5)
+            layer["shared_up"] = norm(next(ki), (d, f), d ** -0.5)
+            layer["shared_down"] = norm(next(ki), (f, d), f ** -0.5)
+            layer["shared_w"] = norm(next(ki), (d, 1), d ** -0.5)
         params["layers"].append(layer)
     return params
+
+
+_GDN_LEAVES = ("gdn_wqkvz", "gdn_wba", "gdn_conv", "gdn_a_log", "gdn_dt_bias",
+               "gdn_norm", "gdn_wout")
+_SHARED_LEAVES = ("shared_gate", "shared_up", "shared_down", "shared_w")
 
 
 def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     """PartitionSpec tree matching :func:`init_params`: Megatron column
     (out-dim) / row (in-dim) sharding over tp; experts over ep; everything
-    else replicated (dp/sp replicate params)."""
+    else replicated (dp/sp replicate params; a "gdn" layer's mixer and the
+    shared expert are replicated whole)."""
     tp = "tp" if "tp" in _axes(mesh) else None
     ep = "ep" if "ep" in _axes(mesh) else None
     col, row = P(None, tp), P(tp, None)   # heads / ff columns shard over tp
@@ -190,15 +298,17 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     up, down = ("w_gate", "w_up"), ("w_down",)
     if cfg.mlp != "swiglu":
         up, down = ("w1",), ("w2",)
-    for _ in range(cfg.n_layers):
-        layer = {"ln1": P(), "ln2": P(), "wo": row}
-        if _packed_qkv(cfg):
-            layer["wqkv"] = col
+    for i in range(cfg.n_layers):
+        layer = {"ln1": P(), "ln2": P()}
+        if layer_kind(cfg, i) == "gdn":
+            layer.update({name: P() for name in _GDN_LEAVES})
+        elif _packed_qkv(cfg):
+            layer.update(wqkv=col, wo=row)
         else:
-            layer.update(wq=col, wk=col, wv=col)
+            layer.update(wq=col, wk=col, wv=col, wo=row)
             if cfg.qk_norm:
                 layer.update(q_norm=P(), k_norm=P())
-        if cfg.indexer:
+        if cfg.indexer and layer_kind(cfg, i) == "attn":
             layer.update({name: P() for name in (
                 "idx_wq", "idx_wk", "idx_ww", "idx_k_scale", "idx_k_bias")})
         if cfg.n_experts:
@@ -207,14 +317,18 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
         else:
             layer.update({name: col for name in up})
             layer.update({name: row for name in down})
+        if cfg.shared_expert_ff:
+            layer.update({name: P() for name in _SHARED_LEAVES})
         specs["layers"].append(layer)
     return specs
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, offset: bool = False):
+    """RMSNorm, float32 inside; ``offset``: the weight multiplies as
+    ``1 + scale`` (``TransformerConfig.norm_offset``)."""
     x32 = x.astype(jnp.float32)
     rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
-    return ((x32 / rms) * scale).astype(x.dtype)
+    return ((x32 / rms) * (1.0 + scale if offset else scale)).astype(x.dtype)
 
 
 def _layer_norm(x, scale, bias):
@@ -224,9 +338,15 @@ def _layer_norm(x, scale, bias):
     return ((x32 - mu) / jnp.sqrt(var + 1e-6) * scale + bias).astype(x.dtype)
 
 
-def _rope(x, theta: float):
+def _rope(x, theta: float, fraction: float = 1.0):
     """Rotate the pairs (i, i + d/2) of the last dim of ``x`` [B, T, ..., d]
-    by position t, base ``theta`` (float32 inside, x's dtype out)."""
+    by position t, base ``theta`` (float32 inside, x's dtype out). With
+    ``fraction`` < 1 the leading ``fraction`` of the last dim is rotated so
+    (pairs (i, i + r/2) of its r) and the rest passes through."""
+    if fraction != 1.0:
+        r = int(x.shape[-1] * fraction)
+        return jnp.concatenate([_rope(x[..., :r], theta), x[..., r:]],
+                               axis=-1)
     T, d = x.shape[1], x.shape[-1]
     inv = theta ** (-jnp.arange(d // 2, dtype=jnp.float32) / (d // 2))
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -238,6 +358,54 @@ def _rope(x, theta: float):
                            axis=-1).astype(x.dtype)
 
 
+def _l2_norm(x):
+    """x / |x| over the last dim (float32 inside, eps 1e-6 under the
+    root)."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True)
+                            + 1e-6)).astype(x.dtype)
+
+
+def _causal_conv(x, w):
+    """Depthwise convolution over time, causal: x [B, T, C], w [W, C];
+    ``y_t = sum_j w[j] x_{t - (W - 1) + j}`` with zeros before the start
+    (no bias; no state carried in from another sequence)."""
+    W, T = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
+    y = sum(padded[:, j:j + T].astype(jnp.float32) * w[j].astype(jnp.float32)
+            for j in range(W))
+    return y.astype(x.dtype)
+
+
+def shared_expert(layer, h, dtype):
+    """The gated expert every token takes, behind its sigmoid gate:
+    ``sigmoid(h w_s) (silu(h Wg) * h Wu) Wd``. Every chip of an
+    expert-parallel group computes it for its own tokens: it is no part
+    of a share."""
+    with jax.named_scope("moe.shared"):
+        def dot(a, w):
+            return a @ layer[w].astype(dtype)
+        act = jax.nn.silu(dot(h, "shared_gate")) * dot(h, "shared_up")
+        gate = jax.nn.sigmoid(dot(h, "shared_w").astype(jnp.float32))
+        return (dot(act, "shared_down").astype(jnp.float32)
+                * gate).astype(dtype)
+
+
+def _check_mesh(cfg: TransformerConfig, axes):
+    """Refuse the mesh axes a configuration's layers cannot take."""
+    if "gdn" in _kinds(cfg) and ("sp" in axes or "tp" in axes):
+        raise NotImplementedError(
+            "a 'gdn' layer's state runs over the whole sequence and its "
+            "heads are not sharded: no sp and no tp (dp and ep only)")
+    if not _packed_qkv(cfg) and "sp" in axes:
+        raise NotImplementedError(
+            "ring attention over sp takes the dense block only (no RoPE "
+            "offset, no grouped heads, no indexer, no output gate)")
+    if cfg.indexer and "tp" in axes:
+        raise NotImplementedError(
+            "sparse attention's selection and KL are over all heads: no tp")
+
+
 def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                     with_masks: bool = False, grad_sync=None):
     """Runs INSIDE shard_map: ``tokens`` [B_local, T_local] int32. Returns
@@ -246,8 +414,9 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     beside them: ``aux`` (the experts' balance loss), ``kl_sum`` and ``kl``
     (the indexer's KL summed over each sequence's rows, [B], which the loss
     differentiates, and per row, [B, T], which it does not), the routing
-    load ``held_load`` /
-    ``absent``, and with ``with_masks`` the int8 selection ``mask``.
+    load ``held_load`` / ``absent`` and the experts each token kept
+    ``ids``, and with ``with_masks`` the int8 selection ``mask`` and a
+    "gdn" layer's rule output ``gdn_o`` [B, T, Hv, dv].
 
     ``grad_sync(k, layer, x, carry) -> (layer, x, carry)``, an identity
     here, is the training step's hook for the gradient exchange
@@ -255,11 +424,13 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     and the activations that enter the layer, and threads its carry from
     the lowest layer to the highest."""
     axes = _axes(mesh)
+    _check_mesh(cfg, axes)
     has_tp = "tp" in axes
     has_sp = "sp" in axes
     tp_size = mesh.shape.get("tp", 1)
     n_heads_local = cfg.n_heads // tp_size
     d_head = _d_head(cfg)
+    offset = cfg.norm_offset
 
     def _attention(layer, h, extras):
         from ..ops.pallas_attention import (flash_attention,
@@ -302,14 +473,20 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
         B, T, _ = h.shape
         kv_local = _kv_heads(cfg) // tp_size
 
-        def heads(w, n):
-            return (h @ layer[w].astype(cfg.dtype)).reshape(B, T, n, d_head)
-        q, k = heads("wq", n_heads_local), heads("wk", kv_local)
-        v = heads("wv", kv_local)
+        def heads(w, n, width=d_head):
+            return (h @ layer[w].astype(cfg.dtype)).reshape(B, T, n, width)
+        if cfg.attn_gate:
+            q = heads("wq", n_heads_local, 2 * d_head)
+            q, gate = q[..., :d_head], q[..., d_head:]
+        else:
+            q = heads("wq", n_heads_local)
+        k, v = heads("wk", kv_local), heads("wv", kv_local)
         if cfg.qk_norm:
-            q, k = _rms_norm(q, layer["q_norm"]), _rms_norm(k, layer["k_norm"])
+            q = _rms_norm(q, layer["q_norm"], offset)
+            k = _rms_norm(k, layer["k_norm"], offset)
         if cfg.rope_theta:
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            q = _rope(q, cfg.rope_theta, cfg.rope_fraction)
+            k = _rope(k, cfg.rope_theta, cfg.rope_fraction)
         if cfg.indexer:
             ix = cfg.indexer
             hs = lax.stop_gradient(h)
@@ -332,16 +509,78 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             attn = flash_attention(q, jnp.repeat(k, group, axis=2),
                                    jnp.repeat(v, group, axis=2), causal=True,
                                    backend=cfg.attn_backend)
+        if cfg.attn_gate:
+            attn = attn.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))
         return attn.astype(cfg.dtype).reshape(B, T, n_heads_local * d_head)
 
-    def _layer_fwd(layer, x):
+    def _gdn_mixer(li, layer, h, extras):
+        """The Gated DeltaNet mixer: h [B, T, D] -> [B, T, D]."""
+        from ..ops.gated_delta import gated_delta_rule, record_saved
+        g, f32 = cfg.gdn, jnp.float32
+        B, T, _ = h.shape
+        nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
+        with jax.named_scope("gdn.proj"):
+            qkvz = h @ layer["gdn_wqkvz"].astype(cfg.dtype)
+            ba = (h @ layer["gdn_wba"].astype(cfg.dtype)).astype(f32)
+        # What lies between the projections and the rule, and between the
+        # rule and the output projection, is elementwise over 12288
+        # columns a row: it is recomputed in the backward from the
+        # projections' outputs (taken whole: a slice would be a copy), not
+        # saved (3.4 GB over three layers at 2 x 8192 rows, compiled for a
+        # v5e).
+        @jax.checkpoint
+        def conv_and_gates(qkvz, ba, taps, a_log, dt_bias):
+            with jax.named_scope("gdn.conv"):
+                qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * nk + nv], taps))
+            with jax.named_scope("gdn.scan"):
+                q = qkv[..., :nk].reshape(B, T, g.n_k_heads, g.d_k)
+                k = qkv[..., nk:2 * nk].reshape(B, T, g.n_k_heads, g.d_k)
+                v = qkv[..., 2 * nk:].reshape(B, T, g.n_v_heads, g.d_v)
+                beta = jax.nn.sigmoid(ba[..., :g.n_v_heads])
+                decay = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                    ba[..., g.n_v_heads:] + dt_bias.astype(f32))
+                return (_l2_norm(q) * g.d_k ** -0.5, _l2_norm(k), v, decay,
+                        beta)
+
+        @jax.checkpoint
+        def gated_norm(o, qkvz, scale):
+            z = qkvz[..., 2 * nk + nv:].reshape(B, T, g.n_v_heads, g.d_v)
+            o = _rms_norm(o.astype(f32), scale) * jax.nn.silu(z.astype(f32))
+            return o.astype(cfg.dtype).reshape(B, T, nv)
+
+        q, k, v, decay, beta = conv_and_gates(
+            qkvz, ba, layer["gdn_conv"], layer["gdn_a_log"],
+            layer["gdn_dt_bias"])
+        with jax.named_scope("gdn.scan"):
+            record_saved(li, q.shape, g.n_v_heads, g.d_v,
+                         jnp.dtype(cfg.dtype).itemsize, g.chunk)
+            # Each key head serves n_v_heads / n_k_heads consecutive value
+            # heads (inside the rule).
+            o = gated_delta_rule(q, k, v, decay, beta, chunk=g.chunk,
+                                 backend=g.backend)
+            if with_masks:
+                extras["gdn_o"] = o
+        with jax.named_scope("gdn.out"):
+            return gated_norm(o, qkvz, layer["gdn_norm"]) \
+                @ layer["gdn_wout"].astype(cfg.dtype)
+
+    def _layer_fwd(li, layer, x):
         extras = {}
-        h = _rms_norm(x, layer["ln1"])
-        proj = _attention(layer, h, extras) @ layer["wo"].astype(cfg.dtype)
-        if has_tp:
-            proj = lax.psum(proj, "tp")               # row-parallel combine
+        h = _rms_norm(x, layer["ln1"], offset)
+        if layer_kind(cfg, li) == "gdn":
+            proj = _gdn_mixer(li, layer, h, extras)
+        else:
+            # Among layers of several kinds the softmax layer has a scope
+            # of its own.
+            with (jax.named_scope("attn.full") if cfg.layer_pattern
+                  else contextlib.nullcontext()):
+                proj = _attention(layer, h, extras) \
+                    @ layer["wo"].astype(cfg.dtype)
+            if has_tp:
+                proj = lax.psum(proj, "tp")           # row-parallel combine
         x = x + proj
-        h = _rms_norm(x, layer["ln2"])
+        h = _rms_norm(x, layer["ln2"], offset)
         gated = cfg.mlp == "swiglu"
         w_up, w_down = ("w_up", "w_down") if gated else ("w1", "w2")
         if cfg.n_experts:
@@ -354,7 +593,10 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                 first_expert=cfg.first_expert,
                 axis_name="ep" if "ep" in axes else None)
             extras.update(stats)
-            return x + y.reshape(B, T, cfg.d_model), extras
+            y = y.reshape(B, T, cfg.d_model)
+            if cfg.shared_expert_ff:
+                y = y + shared_expert(layer, h, cfg.dtype)
+            return x + y, extras
         up = h @ layer[w_up].astype(cfg.dtype)
         if gated:
             up = jax.nn.silu(h @ layer["w_gate"].astype(cfg.dtype)) * up
@@ -365,9 +607,12 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             down = lax.psum(down, "tp")
         return x + down, extras
 
-    if cfg.remat:
-        _layer_fwd = jax.checkpoint(
-            _layer_fwd, policy=jax.checkpoint_policies.dots_saveable)
+    def _layer(li):
+        fwd = functools.partial(_layer_fwd, li)
+        if cfg.remat:
+            fwd = jax.checkpoint(
+                fwd, policy=jax.checkpoint_policies.dots_saveable)
+        return fwd
 
     x = params["embed"][tokens].astype(cfg.dtype)     # [B, T, D]
     carry = None
@@ -375,9 +620,9 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     for k, layer in enumerate(params["layers"]):
         if grad_sync is not None:
             layer, x, carry = grad_sync(k, layer, x, carry)
-        x, extras = _layer_fwd(layer, x)
+        x, extras = _layer(k)(layer, x)
         per_layer.append(extras)
-    return _rms_norm(x, params["lnf"]), per_layer
+    return _rms_norm(x, params["lnf"], offset), per_layer
 
 
 def _aux_total(per_layer):
@@ -544,11 +789,14 @@ def _check_dense(cfg: TransformerConfig, what: str):
             f"{what} supports dense FFNs only (cfg.n_experts="
             f"{cfg.n_experts}); the MoE dispatch has no incremental-decode "
             f"path yet")
-    if not (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head):
+    if not (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head) \
+            or _extended(cfg):
         raise NotImplementedError(
             f"{what} runs the dense block only (packed wqkv, GELU, tied "
             f"head): grouped key/value heads, q/k norm, RoPE, a gated "
-            f"feed-forward, an untied head and sparse attention exist on "
+            f"feed-forward, an untied head, sparse attention, layers of "
+            f"several kinds (a 'gdn' layer's state is no key/value cache), "
+            f"(1 + w) norms, an output gate and a shared expert exist on "
             f"the training path alone")
 
 
@@ -918,7 +1166,7 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     bucket a layer, issued when the layer's backward has produced it and
     due before the backward goes on below the layer underneath, so the
     all-reduce runs under that layer's backward
-    (``ops/fusion.reduce_in_backward``); the embedding, the head and the
+    (``ops/fusion.reduce_in_backward``; the layers must be of one kind); the embedding, the head and the
     final norm follow after the backward. ``False`` keeps the plan that
     reduces everything after the backward. Where the in-backward plan
     does not apply, ``True`` is PR 6's barrier-chained emission after the
@@ -937,13 +1185,8 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
             f"the {_held(cfg)} experts held (of n_experts={cfg.n_experts}, "
             f"from {cfg.first_expert}) must divide over the ep mesh axis "
             f"of size {ep} and lie among the router's")
-    if not _packed_qkv(cfg) and "sp" in axes:
-        raise NotImplementedError(
-            "ring attention over sp takes the dense block only (no RoPE "
-            "offset, no grouped heads, no indexer)")
-    if cfg.indexer and "tp" in axes:
-        raise NotImplementedError(
-            "sparse attention's selection and KL are over all heads: no tp")
+    _check_pattern(cfg)
+    _check_mesh(cfg, axes)
     # Batch dim shards over dp AND ep (GShard layout: ep ranks carry
     # distinct tokens; experts see everyone's via the all_to_all); sequence
     # dim over sp.
@@ -958,10 +1201,14 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     # crosses chips and the step is the old one, op for op; ZeRO's
     # reduce-scatter and microbatch accumulation (one exchange per
     # accumulated step) keep the plan that reduces after the backward.
+    # One carry serves every layer's bucket, so the layers must be of one
+    # kind (``ops/fusion.backward_carry``): a model of several kinds keeps
+    # the plan that reduces after the backward.
     layer_syncs = plan_grad_sync(
         jax.tree_util.tree_leaves(
             specs["layers"][:1], is_leaf=lambda x: isinstance(x, P)), mesh)
     in_backward = (overlap is not False and not zero and accum_steps == 1
+                   and len(_kinds(cfg)) == 1
                    and any(mesh.shape[a] > 1
                            for s in layer_syncs for a in s.psum))
     if in_backward:

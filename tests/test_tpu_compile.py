@@ -260,3 +260,38 @@ def test_sparse_attention_kernels_compile_and_carry_their_names(
                    for ln in kernels)
     assert found == names
     assert any("attn.sparse" in ln and "dsa_fwd" in ln for ln in kernels)
+
+
+@pytest.mark.parametrize("B,T,Hk,Hv,dtype", [
+    (2, 8192, 16, 32, jnp.bfloat16),   # the Qwen3-Next cell: 2 x 8192 rows
+    (1, 1024, 2, 4, jnp.float32),      # float32 operands, one head group
+], ids=["qwen3next_8k", "float32"])
+def test_gated_delta_kernels_compile_and_carry_their_names(
+        one_chip, monkeypatch, B, T, Hk, Hv, dtype):
+    """``gdn_fwd`` and ``gdn_bwd`` (ops/pallas_gated_delta.py) at the
+    published head layout (key and value heads of 128, chunks of 64): the
+    [64, 64] and [1, 128] blocks, the [128, 128] float32 state in scratch
+    and eight chunks a grid step against ``_VMEM_LIMIT`` — what interpret
+    mode cannot refuse — inside the chunk-local stage's XLA program."""
+    from horovod_tpu.ops import gated_delta as gd
+    from horovod_tpu.ops import pallas_gated_delta as pgd
+    monkeypatch.setattr(pgd, "_interpret", lambda: False)
+
+    def shape(*s, dtype=dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("gdn.scan"):
+            o = gd.gated_delta_rule(q, k, v, g, beta, backend="pallas")
+        return jnp.sum(o.astype(jnp.float32))
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            shape(B, T, Hk, D), shape(B, T, Hk, D), shape(B, T, Hv, D),
+            shape(B, T, Hv, dtype=jnp.float32),
+            shape(B, T, Hv, dtype=jnp.float32)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    found = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for ln in kernels}
+    assert found == {"gdn_fwd", "gdn_bwd"}
+    assert any("gdn.scan" in ln and "gdn_bwd" in ln for ln in kernels)
