@@ -32,20 +32,26 @@ a head forgets.
 
 Two stages, and a backward of its own (``jax.custom_vjp``):
 
-* the chunk-local stage (``_a_matrix``, the solve, ``_prepare``: A, T, W,
-  U, Aqk for all chunks at once, batched einsums) is plain ``jax.numpy``
-  whichever backend runs. Its backward is jax's own transpose of it,
-  recomputed from q, k, v, g, beta, but for the solve: T is saved and its
-  transpose is ``dA = -T^T dT T^T``, two products in place of the
-  transposes of the ten that made it;
+* the chunk-local stage (A, the solve, W, U, Aqk for every chunk). On the
+  ``"pallas"`` backend, where the chunk length packs into tiles of 128 rows
+  (:func:`_local_kernels`; gauge ``hvd_gdn_local_kernel{layer}``), it is
+  the kernels ``gdn_local_fwd`` / ``gdn_local_bwd``
+  (``ops/pallas_gated_delta.py``): a tile's arrays stay in VMEM from A to
+  W, U and Aqk, a key head is read once for its value heads, and the
+  backward is the stage's transpose written out. Elsewhere it is plain
+  ``jax.numpy`` (``_a_matrix``, ``_unit_lower_inverse``, ``_prepare``:
+  batched einsums, a group of heads at a time) and its backward jax's own
+  transpose of that, which is also what the tests hold the kernels to.
+  Either way the backward recomputes the stage from q, k, v, g, beta, but
+  for the solve: T is saved and its transpose is ``dA = -T^T dT T^T``, two
+  products in place of the transposes of the ten that made it;
 * the state's walk over the chunks is ``lax.scan`` (backend ``"xla"``: runs
-  anywhere) or the Pallas kernels ``gdn_fwd`` / ``gdn_bwd``
-  (``ops/pallas_gated_delta.py``, backend ``"pallas"``: the state stays in
-  VMEM between chunks). Its backward walks the chunks in reverse carrying
-  ``dS``, from the chunk-start states the forward saved: every chunk's, in
-  the activations' dtype, which is how the backward's products take them
-  (32 KB a chunk and head at 128 x 128 in bf16; the CARRIED state is
-  float32 throughout).
+  anywhere) or the Pallas kernels ``gdn_fwd`` / ``gdn_bwd`` (backend
+  ``"pallas"``: the state stays in VMEM between chunks). Its backward walks
+  the chunks in reverse carrying ``dS``, from the chunk-start states the
+  forward saved: every chunk's, in the activations' dtype, which is how the
+  backward's products take them (32 KB a chunk and head at 128 x 128 in
+  bf16; the CARRIED state is float32 throughout).
 
 What the custom VJP keeps for the backward is q, k, v, g, beta, those
 states and T (:func:`saved_bytes`; gauge ``hvd_gdn_saved_state_bytes{layer}``,
@@ -238,12 +244,24 @@ def _per_value_head(q, k, v, g, beta):
             _flat_heads(v), _flat_heads(g), _flat_heads(beta))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule(q, k, v, g, beta, backend):
-    return _rule_fwd(q, k, v, g, beta, backend)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, backend, local):
+    return _rule_fwd(q, k, v, g, beta, backend, local)[0]
 
 
-def _rule_fwd(q, k, v, g, beta, backend):
+def _rule_fwd(q, k, v, g, beta, backend, local):
+    """``local``: the chunk-local stage by the kernels ``gdn_local_fwd`` /
+    ``gdn_local_bwd`` (:func:`_local_kernels`), which read a key head once
+    for its value heads and hold no float32 transient in HBM: no
+    ``_per_value_head``, no ``_by_head_groups``."""
+    if local:
+        from . import pallas_gated_delta as pgd
+        *prepared, t = pgd.local_fwd(q, k, v, g, beta)
+        o, states = pgd.scan_fwd(*prepared)
+        rep = v.shape[2]
+        return _value_heads(o, rep), (q, k, v, g, beta,
+                                      _value_heads(states, rep), t)
+
     def group(*inputs):
         q, k, v, g, beta = _per_value_head(*inputs)
         t = _unit_lower_inverse(_a_matrix(k, g, beta))
@@ -256,10 +274,20 @@ def _rule_fwd(q, k, v, g, beta, backend):
     return o, (q, k, v, g, beta, states, t)
 
 
-def _rule_bwd(backend, res, do):
+def _rule_bwd(backend, local, res, do):
     """From the saved solve t = (I + A)^-1: the rest of the chunk-local
-    stage is recomputed and transposed by jax, the walk goes back over the
-    saved states, and the solve's own transpose is dA = -t^T dt t^T."""
+    stage is recomputed (by the kernel again, or by ``_prepare``), the
+    walk goes back over the saved states, and the stage is transposed: by
+    ``gdn_local_bwd``, or by jax with the solve's own transpose
+    dA = -t^T dt t^T."""
+    do = do.astype(res[0].dtype)
+    if local:
+        from . import pallas_gated_delta as pgd
+        *inputs, states, t = res
+        return pgd.local_bwd(*inputs, t, *pgd.scan_bwd(
+            *pgd.local_fwd(*inputs, t), _flat_heads(states),
+            _flat_heads(do)))
+
     def group(q, k, v, g, beta, states, t, do):
         heads, to_key_heads = jax.vjp(_per_value_head, q, k, v, g, beta)
         C = k.shape[-2]
@@ -274,7 +302,7 @@ def _rule_bwd(backend, res, do):
         d_heads[3] += dg
         d_heads[4] += dbeta
         return to_key_heads(tuple(d_heads))
-    return _by_head_groups(group, *res, do.astype(res[0].dtype))
+    return _by_head_groups(group, *res, do)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -290,16 +318,30 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
+def _local_kernels(backend: str, chunk: int, dtype) -> bool:
+    """Whether the chunk-local stage runs as the kernels: the Pallas
+    backend, chunks the kernels can tile, activations they take."""
+    if backend != "pallas":
+        return False
+    from . import pallas_gated_delta as pgd
+    return (pgd.local_tile(1, chunk) is not None
+            and dtype in (jnp.float32, jnp.bfloat16))
+
+
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
-                     backend: str = "auto"):
+                     backend: str = "auto", layer=None):
     """The rule above for q, k [B, T, Hk, dk] (normalised and scaled by the
     caller), v [B, T, Hv, dv], g (log-decay, <= 0) and beta [B, T, Hv];
     each of the Hk key heads serves Hv / Hk consecutive value heads.
     Returns o [B, T, Hv, dv] in v's dtype. T is padded to a multiple of
     ``chunk`` with rows that write nothing (k = 0, beta = 0, g = 0) and
     whose outputs are dropped. ``backend``: ``"xla"``, ``"pallas"`` (on a
-    CPU: the kernels in interpret mode) or ``"auto"``."""
+    CPU: the kernels in interpret mode) or ``"auto"``. ``layer``: the
+    calling layer's index, for the gauge ``hvd_gdn_local_kernel{layer}``."""
     backend = resolve_backend(backend)
+    local = _local_kernels(backend, chunk, q.dtype)
+    if layer is not None:
+        _m_local.labels(layer=str(layer)).set(int(local))
     B, T, Hk, _ = q.shape
     Hv = v.shape[2]
     if Hv % Hk:
@@ -316,17 +358,19 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
     o = _rule(chunked(q, (Hk,)), chunked(k.astype(q.dtype), (Hk,)),
               chunked(v.astype(q.dtype), per_key),
               chunked(g.astype(jnp.float32), per_key),
-              chunked(beta.astype(jnp.float32), per_key), backend)
+              chunked(beta.astype(jnp.float32), per_key), backend, local)
     o = jnp.moveaxis(o.reshape(B, Hv, n * chunk, -1), 1, 2)
     return o[:, :T].astype(v.dtype)
 
 
 def saved_bytes(q_shape, n_v_heads: int, dv: int, itemsize: int,
                 chunk: int = 64) -> int:
-    """Bytes the rule's custom VJP keeps for the backward of one call, from
-    shapes: q, k ([B, T, Hk, dk]), v and a [dk, dv] state for every chunk
-    and value head in the activations' dtype; g, beta and the solve's
-    [chunk, chunk] result for every chunk and value head in float32."""
+    """Bytes the rule's custom VJP keeps for the backward of one call
+    (what ``_rule_fwd`` returns beside o), from shapes: q, k ([B, T, Hk,
+    dk]), v and a [dk, dv] chunk-start state for every chunk and value head
+    in the activations' dtype; g, beta and the solve T, ``chunk`` float32 a
+    row and value head (T leaves flattened [.., chunk * chunk], or from
+    the kernels packed [.., chunk, 128]: full lanes, the same bytes)."""
     B, T, Hk, dk = q_shape
     rows = B * (T + -T % chunk)
     return (rows * (2 * Hk * dk + n_v_heads * dv) * itemsize
@@ -339,6 +383,11 @@ _m_saved = _registry().gauge(
     "bytes the gated delta rule's custom VJP keeps for the backward of one "
     "layer's call (inputs and chunk-start states), from shapes at trace "
     "time", labels=("layer",))
+_m_local = _registry().gauge(
+    "hvd_gdn_local_kernel",
+    "1 where the layer's chunk-local stage was traced as the kernels "
+    "gdn_local_fwd / gdn_local_bwd, 0 where its shapes sent it to "
+    "jax.numpy", labels=("layer",))
 _m_chunk = _registry().gauge(
     "hvd_gdn_chunk", "chunk length the gated delta rule runs with, as last "
     "traced")

@@ -558,7 +558,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             # Each key head serves n_v_heads / n_k_heads consecutive value
             # heads (inside the rule).
             o = gated_delta_rule(q, k, v, decay, beta, chunk=g.chunk,
-                                 backend=g.backend)
+                                 backend=g.backend, layer=li)
             if with_masks:
                 extras["gdn_o"] = o
         with jax.named_scope("gdn.out"):

@@ -138,6 +138,136 @@ def test_chunked_rule_matches_the_recurrence(backend, chunk, T, fast):
             jnp.abs(b))) + 1e-6, err_msg=name)
 
 
+def stage_inputs(chunk, n, dtype, rep, fast, seed=0, B=1, Hk=2, dk=16,
+                 dv=32):
+    """The rule's inputs as ``_rule`` has them: in chunks, value heads
+    grouped under their key head."""
+    q, k, v, g, beta = rule_inputs(n * chunk, seed, B, Hk, Hk * rep, dk, dv,
+                                   fast)
+
+    def chunked(x, heads):
+        x = jnp.moveaxis(x, 2, 1)
+        return x.reshape(B, *heads, n, chunk, *x.shape[3:])
+    return (chunked(q, (Hk,)).astype(dtype), chunked(k, (Hk,)).astype(dtype),
+            chunked(v, (Hk, rep)).astype(dtype),
+            chunked(g, (Hk, rep)).astype(jnp.float32),
+            chunked(beta, (Hk, rep)).astype(jnp.float32))
+
+
+def stage_oracle(q, k, v, g, beta):
+    """The ``jax.numpy`` stage: what ``_prepare`` returns, and the solve."""
+    heads = gd._per_value_head(q, k, v, g, beta)
+    t = gd._unit_lower_inverse(gd._a_matrix(heads[1], heads[3], heads[4]))
+    return gd._prepare(t, *heads) + (t,)
+
+
+def unpacked(t, chunk):
+    """The kernels' T, a tile's chunks side by side, as [B, Hv, N, C, C]."""
+    B, Hk, rep, tiles, _, R = t.shape
+    t = t.reshape(B, Hk * rep, tiles, chunk, R // chunk, chunk)
+    return jnp.moveaxis(t, 4, 3).reshape(B, Hk * rep, -1, chunk, chunk)
+
+
+def assert_close_by_dtype(got, want, names):
+    """float32 results to float32's rounding, bf16 results to bf16's."""
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        tol = 2e-5 if b.dtype == jnp.float32 else 2e-2
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=tol * np.abs(b).max() + 1e-30,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("fast", [False, True], ids=["slow", "fast"])
+@pytest.mark.parametrize("rep", [1, 2], ids=["rep1", "rep2"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("chunk,n", [(16, 16), (64, 4)], ids=["c16", "c64"])
+def test_local_kernels_match_the_stage_and_its_transpose(chunk, n, dtype, rep,
+                                                         fast):
+    """``gdn_local_fwd`` (interpreted) against ``_a_matrix``, the solve and
+    ``_prepare``, all seven results, with the solve and from a given T;
+    ``gdn_local_bwd`` against jax's own transpose of them, every cotangent,
+    from random cotangents of all six of the walk's inputs. Two tiles (of
+    eight chunks of 16, of two of 64), so a grid step works more than one."""
+    from horovod_tpu.ops import pallas_gated_delta as pgd
+    args = stage_inputs(chunk, n, dtype, rep, fast)
+    want = stage_oracle(*args)
+    *got, t = pgd.local_fwd(*args)
+    assert t.shape[-1] == 128
+    assert_close_by_dtype(got + [unpacked(t, chunk)], want,
+                          "qg kd w u aqk e t".split())
+    for a, b in zip(pgd.local_fwd(*args, t), got):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    cts = [jax.random.normal(key, x.shape).astype(x.dtype) for key, x in zip(
+        jax.random.split(jax.random.PRNGKey(3), 6), want)]
+    _, transpose = jax.vjp(lambda *a: stage_oracle(*a)[:6], *args)
+    assert_close_by_dtype(pgd.local_bwd(*args, t, *cts),
+                          transpose(tuple(cts)), "dq dk dv dg dbeta".split())
+
+
+def test_float32_products_of_the_kernels_are_three_bf16_passes(monkeypatch):
+    """Compiled for a chip, a float32 product of the stage is hi hi + hi lo
+    + lo hi of bf16 parts (``Precision.HIGH``): run here as it would be
+    there, it is within 2^-14 of the product's size and a hundred times
+    nearer than one pass."""
+    from horovod_tpu.ops import pallas_gated_delta as pgd
+    monkeypatch.setattr(pgd, "_interpret", lambda: False)
+    a, b = (jax.random.normal(key, (64, 64), jnp.float32)
+            for key in jax.random.split(jax.random.PRNGKey(0)))
+    want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    hi, lo = pgd._split(a)
+    assert hi.dtype == lo.dtype == jnp.bfloat16
+    three = np.abs(pgd._dot3(pgd._split(a), pgd._split(b), 1, 0) - want).max()
+    one = np.abs(pgd._dot(hi, pgd._split(b)[0], 1, 0) - want).max()
+    assert three < 2 ** -14 * np.abs(want).max() and 100 * three < one
+    # A bf16 operand is exact in its one part: two passes.
+    assert pgd._split(hi) == (hi, None)
+    np.testing.assert_allclose(
+        pgd._dot3(pgd._split(a), (hi, None), 1, 0),
+        np.asarray(a, np.float64) @ np.asarray(hi, np.float64),
+        atol=2 ** -14 * np.abs(want).max())
+
+
+def local_kernel_gauge(layer):
+    return parse_exposition(registry().render())[
+        ("hvd_gdn_local_kernel", (("layer", str(layer)),))]
+
+
+def test_chunks_the_kernels_cannot_tile_fall_to_the_jax_numpy_stage():
+    """Chunks of 24 do not pack into 128 rows: the Pallas backend runs its
+    walk and the ``jax.numpy`` stage, gives the XLA backend's numbers, and
+    the gauge says 0; the published chunk of 64 in bf16 says 1."""
+    from horovod_tpu.ops import pallas_gated_delta as pgd
+    assert pgd.local_tile(4, 24) is None and pgd.local_tile(128, 64) == 2
+    assert pgd.local_tile(3, 64) == 1 and pgd.local_tile(12, 16) == 6
+    args = rule_inputs(96)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+    def value_and_grads(backend, layer):
+        return jax.value_and_grad(lambda *a: jnp.sum(gd.gated_delta_rule(
+            *a, chunk=24, backend=backend, layer=layer) * weight),
+            argnums=(0, 1, 2, 3, 4))(*args)
+    got, want = value_and_grads("pallas", 5), value_and_grads("xla", None)
+    assert local_kernel_gauge(5) == 0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(
+            jnp.abs(b))) + 1e-6)
+    cell = tuple(jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((2, 8192, 16, 128), jnp.bfloat16), ((2, 8192, 16, 128), jnp.bfloat16),
+        ((2, 8192, 32, 128), jnp.bfloat16), ((2, 8192, 32), jnp.float32),
+        ((2, 8192, 32), jnp.float32)))
+    jax.eval_shape(lambda *a: gd.gated_delta_rule(
+        *a, chunk=64, backend="pallas", layer=6), *cell)
+    assert local_kernel_gauge(6) == 1
+    # The XLA backend has no kernels to run it by.
+    jax.eval_shape(lambda *a: gd.gated_delta_rule(
+        *a, chunk=64, backend="xla", layer=6), *cell)
+    assert local_kernel_gauge(6) == 0
+
+
 def test_rule_in_bf16_keeps_its_state_and_decays_in_float32():
     """bf16 operands, float32 inside: close to the float32 recurrence of
     the same (rounded) inputs over 256 rows, where a bf16 state would have

@@ -268,11 +268,16 @@ def test_sparse_attention_kernels_compile_and_carry_their_names(
 ], ids=["qwen3next_8k", "float32"])
 def test_gated_delta_kernels_compile_and_carry_their_names(
         one_chip, monkeypatch, B, T, Hk, Hv, dtype):
-    """``gdn_fwd`` and ``gdn_bwd`` (ops/pallas_gated_delta.py) at the
-    published head layout (key and value heads of 128, chunks of 64): the
-    [64, 64] and [1, 128] blocks, the [128, 128] float32 state in scratch
-    and eight chunks a grid step against ``_VMEM_LIMIT`` — what interpret
-    mode cannot refuse — inside the chunk-local stage's XLA program."""
+    """The rule's four kernels (ops/pallas_gated_delta.py) at the published
+    head layout (key and value heads of 128, chunks of 64). The walk's
+    ``gdn_fwd`` / ``gdn_bwd``: the [64, 64] and [1, 128] blocks, the
+    [128, 128] float32 state in scratch and eight chunks a grid step. The
+    chunk-local stage's ``gdn_local_fwd`` / ``gdn_local_bwd``: tiles of two
+    chunks, block-diagonal [128, 128] float32 arrays, sums over lanes and
+    sublanes, concatenations along both, float32 products as bf16 parts
+    (Mosaic refuses ``Precision.HIGH``), a dozen blocked operands of two
+    value heads against ``_VMEM_LIMIT`` — what interpret mode cannot
+    refuse."""
     from horovod_tpu.ops import gated_delta as gd
     from horovod_tpu.ops import pallas_gated_delta as pgd
     monkeypatch.setattr(pgd, "_interpret", lambda: False)
@@ -293,5 +298,8 @@ def test_gated_delta_kernels_compile_and_carry_their_names(
     kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     found = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
              for ln in kernels}
-    assert found == {"gdn_fwd", "gdn_bwd"}
-    assert any("gdn.scan" in ln and "gdn_bwd" in ln for ln in kernels)
+    assert found == {"gdn_fwd", "gdn_bwd", "gdn_local_fwd", "gdn_local_bwd"}
+    for name in found:
+        assert any("gdn.scan" in ln and name + "." in ln for ln in kernels)
+    # The forward's kernel, and again in the backward from the saved T.
+    assert sum("gdn_local_fwd." in ln.split(" = ")[0] for ln in kernels) == 2
