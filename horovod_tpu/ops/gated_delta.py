@@ -4,7 +4,9 @@ Per sequence and head, with a state ``S`` [dk, dv] from zero and, per row t,
 a query ``q_t`` and a key ``k_t`` [dk], a value ``v_t`` [dv], a log-decay
 ``g_t <= 0`` and a write strength ``beta_t``:
 
-    S   <- exp(g_t) S
+    S   <- exp(g_t) S          one g_t a head (Gated DeltaNet), or
+    S   <- Diag(exp g_t) S     a [dk] vector g_t: one decay a key CHANNEL
+                               (Kimi Delta Attention)
     u_t  = (v_t - S^T k_t) beta_t
     S   <- S + k_t u_t^T
     o_t  = S^T q_t
@@ -54,8 +56,32 @@ Two stages, and a backward of its own (``jax.custom_vjp``):
   bf16; the CARRIED state is float32 throughout).
 
 What the custom VJP keeps for the backward is q, k, v, g, beta, those
-states and T (:func:`saved_bytes`; gauge ``hvd_gdn_saved_state_bytes{layer}``,
-stamped by the layer that calls the rule, and ``hvd_gdn_chunk``).
+states and T (:func:`saved_bytes`; gauge ``hvd_gdn_saved_state_bytes{layer}``
+or ``hvd_kda_saved_state_bytes{layer}``, stamped by the layer that calls the
+rule, and ``hvd_gdn_chunk``).
+
+**The gate per channel** (g [B, T, H, dk]; the section "a decay per
+channel" below). The decay then sits INSIDE the contractions,
+
+    A_ij   = beta_i sum_c k_ic k_jc exp(gam_ic - gam_jc)       (i > j)
+    Aqk_ij =        sum_c q_ic k_jc exp(gam_ic - gam_jc)       (i >= j)
+    W = T (beta K e^gam),   O = (Q e^gam) S + Aqk U',
+    S <- Diag(e^{gam_C}) S + (K e^{gam_C - gam})^T U'
+
+and ``(K e^gam)(K e^-gam)^T`` would overflow for a channel that forgets
+fast. The pairs (i, j) of a chunk are split by the highest bit in which i
+and j differ: at level l the rows whose bit l is set meet the rows of
+their block's other half, against the reference row r where that half
+starts, ``exp(gam_i - gam_r) exp(gam_r - gam_j)``, both exponents <= 0 and
+both sums of g over a stretch of rows (a 0/1 matrix times g, to float32's
+digits). log2(C) products of the chunk's shape make A, as many Aqk, and as
+many each of the four sums the transpose needs; ``e^-gam`` is never
+formed. The same code (``ops/kda_tile.py``) is the ``"xla"`` backend
+(mapped over the chunks) and the body of the kernels ``kda_local_fwd`` /
+``kda_local_bwd``; the walk's kernels ``kda_fwd`` / ``kda_bwd`` are
+``gdn_fwd`` / ``gdn_bwd`` with a [dk] vector where those have a number;
+the backward is written out (no transpose by jax) and returns dg
+[B, T, H, dk].
 """
 
 from __future__ import annotations
@@ -68,6 +94,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs.registry import registry as _registry
+from . import kda_tile
 
 # Float32 products (the solve, W and U, the solve's transpose): three bf16
 # passes. Measured on a v5e at the Qwen3-Next cell's shape (PERF.md PR 32):
@@ -135,6 +162,12 @@ def _prepare(t, q, k, v, g, beta):
             aqk.astype(dt), jnp.exp(last[..., 0]))
 
 
+def _over_state(e):
+    """e^{gam_C} of a chunk, [B, H] (a number a head) or [B, H, dk] (one a
+    key channel), as it multiplies the state [B, H, dk, dv]."""
+    return e[..., None, None] if e.ndim == 2 else e[..., None]
+
+
 def _chunks_first(*xs):
     return [jnp.moveaxis(x, 2, 0) for x in xs]
 
@@ -152,7 +185,7 @@ def scan_fwd_xla(qg, kd, w, u, aqk, e_last):
         s = S.astype(dt)
         un = (u_n - _mm("bhck,bhkv->bhcv", w_n, s)).astype(dt)
         o = _mm("bhck,bhkv->bhcv", qg_n, s) + _mm("bhij,bhjv->bhiv", a_n, un)
-        nxt = e_n[..., None, None] * S + _mm("bhck,bhcv->bhkv", kd_n, un)
+        nxt = _over_state(e_n) * S + _mm("bhck,bhcv->bhkv", kd_n, un)
         return nxt, (o.astype(dt), s)
 
     _, (o, states) = lax.scan(
@@ -181,8 +214,9 @@ def scan_bwd_xla(qg, kd, w, u, aqk, e_last, states, do):
                -_mm("bhcv,bhkv->bhck", dund, s),           # d w
                dun,                                        # d u
                _mm("bhiv,bhjv->bhij", do_n, un),           # d aqk
-               jnp.sum(S.astype(jnp.float32) * dS, axis=(-2, -1)))  # d e
-        nxt = (e_n[..., None, None] * dS
+               jnp.sum(S.astype(jnp.float32) * dS,                 # d e
+                       axis=(-2, -1) if e_n.ndim == 2 else -1))
+        nxt = (_over_state(e_n) * dS
                + _mm("bhck,bhcv->bhkv", qg_n, do_n)
                - _mm("bhck,bhcv->bhkv", w_n, dund))
         return nxt, out
@@ -307,6 +341,96 @@ def _rule_bwd(backend, local, res, do):
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
+# -- a decay per channel ---------------------------------------------------------
+#
+# The chunk-local stage is ``kda_tile``'s, a tile of one chunk at a time
+# (mapped over the chunks) with the products as XLA takes them, or the
+# kernels ``kda_local_fwd`` / ``kda_local_bwd`` with the same tile functions
+# as their body.
+
+
+class _XlaOps:
+    """The products as XLA takes them."""
+
+    @staticmethod
+    def dot(a, b, ca, cb, precision=None):
+        return lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+    @classmethod
+    def dot_hi(cls, a, b, ca, cb):
+        return cls.dot(a, b, ca, cb, _HI)
+
+    @classmethod
+    def dot_sum(cls, mask, x, ca=1):
+        return cls.dot(mask.astype(jnp.float32), x, ca, 0,
+                       lax.Precision.HIGHEST)
+
+
+def _over_chunks_xla(fn, *xs):
+    """``fn`` of one chunk's arrays, over [B, H, N, ...]."""
+    for _ in range(3):
+        fn = jax.vmap(fn)
+    return fn(*xs)
+
+
+def _channel_fwd_xla(q, k, v, g, beta, t=None):
+    """The stage for q, k, g [B, H, N, C, dk], v [B, H, N, C, dv], beta
+    [B, H, N, C]: (qg, kd, w, u, aqk in q's dtype, e^{gam_C} [B, H, N, dk]
+    float32, T [B, H, N, C, C] float32)."""
+    C = q.shape[-2]
+    fn = functools.partial(kda_tile.tile_fwd, _XlaOps, C, 1)
+    args = (q, k, v, g, beta[..., None]) + (() if t is None else (t,))
+    *prepared, e, t = _over_chunks_xla(fn, *args)
+    return (*(x.astype(q.dtype) for x in prepared), e[..., 0, :], t)
+
+
+def _channel_bwd_xla(q, k, v, g, beta, t, dqg, dkd, dw, du, daqk, de):
+    C = q.shape[-2]
+    *grads, dbeta = _over_chunks_xla(
+        functools.partial(kda_tile.tile_bwd, _XlaOps, C, 1), q, k, v, g,
+        beta[..., None], t, dqg, dkd, dw, du, daqk, de[..., None, :])
+    return (*grads, dbeta[..., 0])
+
+
+def _channel_stage(backend: str, chunk: int, dtype):
+    """(the stage, its transpose, whether they are the kernels
+    ``kda_local_fwd`` / ``kda_local_bwd``) for a backend."""
+    if _local_kernels(backend, chunk, dtype):
+        from . import pallas_gated_delta as pgd
+        return pgd.kda_local_fwd, pgd.kda_local_bwd, True
+    return _channel_fwd_xla, _channel_bwd_xla, False
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _channel_rule(q, k, v, g, beta, backend):
+    return _channel_rule_fwd(q, k, v, g, beta, backend)[0]
+
+
+def _channel_rule_fwd(q, k, v, g, beta, backend):
+    """q, k, g [B, H, N, C, dk], v [B, H, N, C, dv], beta [B, H, N, C]."""
+    stage = _channel_stage(backend, q.shape[-2], q.dtype)[0]
+    *prepared, t = stage(q, k, v, g, beta)
+    o, states = _scans(backend)[0](*prepared)
+    return o, (q, k, v, g, beta, states, t)
+
+
+def _channel_rule_bwd(backend, res, do):
+    """As ``_rule_bwd``: the stage again from the saved solve, the walk
+    back over the saved states, the stage's transpose."""
+    *inputs, states, t = res
+    stage, transpose, _ = _channel_stage(backend, inputs[0].shape[-2],
+                                         inputs[0].dtype)
+    dq, dk, dv, dg, dbeta = transpose(*inputs, t, *_scans(backend)[1](
+        *stage(*inputs, t)[:6], states, do.astype(inputs[0].dtype)))
+    dt = inputs[0].dtype
+    return (dq.astype(dt), dk.astype(dt), dv.astype(dt),
+            dg.astype(jnp.float32), dbeta.astype(jnp.float32))
+
+
+_channel_rule.defvjp(_channel_rule_fwd, _channel_rule_bwd)
+
 
 def resolve_backend(backend: str) -> str:
     """``"auto"``: the Pallas kernels on a TPU, the XLA scan elsewhere."""
@@ -331,15 +455,25 @@ def _local_kernels(backend: str, chunk: int, dtype) -> bool:
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
                      backend: str = "auto", layer=None):
     """The rule above for q, k [B, T, Hk, dk] (normalised and scaled by the
-    caller), v [B, T, Hv, dv], g (log-decay, <= 0) and beta [B, T, Hv];
-    each of the Hk key heads serves Hv / Hk consecutive value heads.
+    caller), v [B, T, Hv, dv], g (log-decay, <= 0) [B, T, Hv] (one a head)
+    or [B, T, Hv, dk] (one a key channel; then Hk = Hv: the decays differ
+    by value head) and beta [B, T, Hv]; each of the Hk key heads serves
+    Hv / Hk consecutive value heads.
     Returns o [B, T, Hv, dv] in v's dtype. T is padded to a multiple of
     ``chunk`` with rows that write nothing (k = 0, beta = 0, g = 0) and
     whose outputs are dropped. ``backend``: ``"xla"``, ``"pallas"`` (on a
     CPU: the kernels in interpret mode) or ``"auto"``. ``layer``: the
     calling layer's index, for the gauge ``hvd_gdn_local_kernel{layer}``."""
     backend = resolve_backend(backend)
-    local = _local_kernels(backend, chunk, q.dtype)
+    per_channel = g.ndim == 4
+    if per_channel and (chunk & (chunk - 1) or g.shape[2:] != q.shape[2:]
+                        or v.shape[2] != q.shape[2]):
+        raise ValueError(
+            f"gated_delta_rule: a gate per channel needs a chunk that is a "
+            f"power of two, a key head a value head and g of q's shape; got "
+            f"chunk {chunk}, g {g.shape} and v {v.shape} for q {q.shape}")
+    local = _channel_stage(backend, chunk, q.dtype)[2] if per_channel \
+        else _local_kernels(backend, chunk, q.dtype)
     if layer is not None:
         _m_local.labels(layer=str(layer)).set(int(local))
     B, T, Hk, _ = q.shape
@@ -355,24 +489,37 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
         x = jnp.moveaxis(x, 2, 1)                     # [B, H, T, ...]
         return x.reshape(B, *heads, n, chunk, *x.shape[3:])
     per_key = (Hk, Hv // Hk)
-    o = _rule(chunked(q, (Hk,)), chunked(k.astype(q.dtype), (Hk,)),
-              chunked(v.astype(q.dtype), per_key),
-              chunked(g.astype(jnp.float32), per_key),
-              chunked(beta.astype(jnp.float32), per_key), backend, local)
+    if per_channel:
+        o = _channel_rule(
+            chunked(q, (Hv,)), chunked(k.astype(q.dtype), (Hv,)),
+            chunked(v.astype(q.dtype), (Hv,)),
+            chunked(g.astype(jnp.float32), (Hv,)),
+            chunked(beta.astype(jnp.float32), (Hv,)), backend)
+    else:
+        o = _rule(chunked(q, (Hk,)), chunked(k.astype(q.dtype), (Hk,)),
+                  chunked(v.astype(q.dtype), per_key),
+                  chunked(g.astype(jnp.float32), per_key),
+                  chunked(beta.astype(jnp.float32), per_key), backend, local)
     o = jnp.moveaxis(o.reshape(B, Hv, n * chunk, -1), 1, 2)
     return o[:, :T].astype(v.dtype)
 
 
 def saved_bytes(q_shape, n_v_heads: int, dv: int, itemsize: int,
-                chunk: int = 64) -> int:
+                chunk: int = 64, per_channel: bool = False) -> int:
     """Bytes the rule's custom VJP keeps for the backward of one call
     (what ``_rule_fwd`` returns beside o), from shapes: q, k ([B, T, Hk,
     dk]), v and a [dk, dv] chunk-start state for every chunk and value head
     in the activations' dtype; g, beta and the solve T, ``chunk`` float32 a
     row and value head (T leaves flattened [.., chunk * chunk], or from
-    the kernels packed [.., chunk, 128]: full lanes, the same bytes)."""
+    the kernels packed [.., chunk, 128]: full lanes, the same bytes).
+    ``per_channel``: g is dk float32 a row and head (a key head a value
+    head)."""
     B, T, Hk, dk = q_shape
     rows = B * (T + -T % chunk)
+    if per_channel:
+        return (rows * n_v_heads * (2 * dk + dv) * itemsize
+                + rows * n_v_heads * (dk + 1 + chunk) * 4
+                + rows // chunk * n_v_heads * dk * dv * itemsize)
     return (rows * (2 * Hk * dk + n_v_heads * dv) * itemsize
             + rows * n_v_heads * (2 + chunk) * 4
             + rows // chunk * n_v_heads * dk * dv * itemsize)
@@ -383,19 +530,25 @@ _m_saved = _registry().gauge(
     "bytes the gated delta rule's custom VJP keeps for the backward of one "
     "layer's call (inputs and chunk-start states), from shapes at trace "
     "time", labels=("layer",))
+_m_saved_kda = _registry().gauge(
+    "hvd_kda_saved_state_bytes",
+    "as hvd_gdn_saved_state_bytes, for a layer whose gate is per channel "
+    "(Kimi Delta Attention): g counts dk float32 a row and head",
+    labels=("layer",))
 _m_local = _registry().gauge(
     "hvd_gdn_local_kernel",
     "1 where the layer's chunk-local stage was traced as the kernels "
-    "gdn_local_fwd / gdn_local_bwd, 0 where its shapes sent it to "
-    "jax.numpy", labels=("layer",))
+    "gdn_local_fwd / gdn_local_bwd (kda_local_fwd / kda_local_bwd under a "
+    "gate per channel), 0 where its shapes sent it to jax.numpy",
+    labels=("layer",))
 _m_chunk = _registry().gauge(
     "hvd_gdn_chunk", "chunk length the gated delta rule runs with, as last "
     "traced")
 
 
 def record_saved(layer: int, q_shape, n_v_heads: int, dv: int,
-                 itemsize: int, chunk: int) -> None:
+                 itemsize: int, chunk: int, per_channel: bool = False) -> None:
     """Stamp the two gauges for one layer's call (trace time: shapes)."""
-    _m_saved.labels(layer=str(layer)).set(
-        saved_bytes(q_shape, n_v_heads, dv, itemsize, chunk))
+    (_m_saved_kda if per_channel else _m_saved).labels(layer=str(layer)).set(
+        saved_bytes(q_shape, n_v_heads, dv, itemsize, chunk, per_channel))
     _m_chunk.set(chunk)

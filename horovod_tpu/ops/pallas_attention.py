@@ -22,7 +22,9 @@ split backward.
 Off-TPU (CPU tests) the kernels run in interpreter mode, bit-matching the
 compiled path's math. `flash_attention` falls back to plain XLA attention
 for shapes the kernel doesn't tile (tiny head_dim or sequences not divisible
-by the block).
+by the block) unless the caller forbids it (``fallback=False``: the
+latent-attention layers). q and k may be of another width than v (192
+against 128): the [BH, T, .] layout's kernels take the two widths apart.
 
 Kernel names (``pallas_call(name=)``; a device trace and the compiled HLO
 find the kernels by them, so they are API): ``flash_fwd``, ``flash_bwd``
@@ -455,10 +457,11 @@ _STAT_LANES = 8
 
 class _Layout:
     """Block index maps of one array layout. ``H`` None: q/k/v/o are
-    separate [BH, T, D] arrays; else packed columns for H heads."""
+    separate [BH, T, .] arrays, q and k ``D`` wide and v and o ``Dv``;
+    else packed columns for H heads of one width."""
 
-    def __init__(self, H: Optional[int], D: int):
-        self.H, self.D = H, D
+    def __init__(self, H: Optional[int], D: int, Dv: Optional[int] = None):
+        self.H, self.D, self.Dv = H, D, D if Dv is None else Dv
 
     def grid(self, lead: int):
         return (lead, 1) if self.H is None else (lead, self.H)
@@ -475,10 +478,16 @@ class _Layout:
                                      row(*ij), 0))
         col = {"q": 0, "k": 1, "v": 2}.get(kind)
         return pl.BlockSpec(
-            (1, rows, self.D),
+            (1, rows, self.D if kind in ("q", "k", "dqk") else self.Dv),
             lambda g0, g1, *ij: (
                 g0, row(*ij),
                 0 if H is None else (g1 if col is None else g1 * 3 + col)))
+
+    @property
+    def gate_d(self):
+        """The width the schedule's gate budgets for: the wider of the
+        two (a narrower v only leaves room)."""
+        return max(self.D, self.Dv)
 
 
 def _qi_row(qi, *kb):
@@ -501,11 +510,12 @@ def _q_row(causal):
         else (lambda kb, qi: qi)
 
 
-def _layout_of(q, H: Optional[int]):
+def _layout_of(q, H: Optional[int], v=None):
     """(_Layout, T) of the q array (or of the packed array standing in for
-    q, k and v alike)."""
+    q, k and v alike); ``v``: the value array, where its width is its
+    own."""
     D = q.shape[-1] if H is None else q.shape[-1] // (3 * H)
-    return _Layout(H, D), q.shape[1]
+    return _Layout(H, D, None if v is None else v.shape[-1]), q.shape[1]
 
 
 # The forward and the backward are jitted by themselves: a model calls them
@@ -521,12 +531,12 @@ def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
     (the no-grad primal) drops the lse output — Mosaic can't
     dead-code-eliminate an output buffer, and at long T the f32 lse write
     outweighs the bf16 output itself."""
-    lay, T = _layout_of(q, H)
-    D = lay.D
+    lay, T = _layout_of(q, H, None if H is not None else v)
+    D = lay.Dv
     b, sub = _blocks(T)
     lead = q.shape[0]
     n_heads = lead if lay.H is None else lead * lay.H
-    resident = _fits_vmem(T, D, q.dtype.itemsize, b=b, bwd=False,
+    resident = _fits_vmem(T, lay.gate_d, q.dtype.itemsize, b=b, bwd=False,
                           kv_resident=True)
     if resident:
         grid = lay.grid(lead) + (T // b,)
@@ -571,20 +581,20 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
     """Gradients of the three inputs: one packed [B, T, H*3*D] array from
     the fused kernel in the packed layout, else (dq, dk, dv) shaped like
     ``o``, by the kernels ``_bwd_plan`` picks."""
-    lay, T = _layout_of(q, H)
-    D, packed = lay.D, H is not None
+    lay, T = _layout_of(q, H, None if H is not None else v)
+    D, Dv, packed = lay.D, lay.Dv, H is not None
     b, sub = _blocks(T)
     lead, n_t = q.shape[0], T // b
     # Δ_i = Σ_d dO ∘ O — cheap elementwise reduction, XLA fuses it;
     # widened to _STAT_LANES like lse so the kernels read [b, 8] tiles.
     prod = do.astype(jnp.float32) * o.astype(jnp.float32)
     if packed:
-        delta = prod.reshape(lead, T, lay.H, D).sum(-1).transpose(0, 2, 1)
+        delta = prod.reshape(lead, T, lay.H, Dv).sum(-1).transpose(0, 2, 1)
     else:
         delta = prod.sum(-1)
     delta = jnp.broadcast_to(delta.reshape(-1, T, 1),
                              (lse.shape[0], T, _STAT_LANES))
-    plan = _bwd_plan(T, D, q.dtype.itemsize, b=b, packed=packed)
+    plan = _bwd_plan(T, lay.gate_d, q.dtype.itemsize, b=b, packed=packed)
     resident, fused = plan == "resident", plan != "split"
     kernel = functools.partial(
         _bwd_kernel, causal=causal, b=b, sub=sub, resident=resident,
@@ -601,6 +611,10 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
         kv_specs = [lay.spec(x, b, _kv_row(causal)) for x in "kv"]
         semantics = ("parallel", "parallel",
                      "arbitrary" if fused else "parallel", "arbitrary")
+    # dq and dk are as wide as q and k, dv as v (one width in the packed
+    # layout, where "dqk" and "o" name the same columns).
+    qk_like = jax.ShapeDtypeStruct(o.shape[:-1] + (
+        o.shape[-1] // Dv * D,), q.dtype)
     o_like = jax.ShapeDtypeStruct(o.shape, q.dtype)
     dq_acc = pltpu.VMEM((b, D), jnp.float32)
     if fused:
@@ -609,20 +623,21 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
                                      lambda g0, g1, *ij: (g0, 0, g1))
             out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
         else:
-            whole = lay.spec("o", T, lambda *ij: 0)
-            out_specs = [lay.spec("o", b, _qi_row), whole, whole]
-            out_shape = [o_like] * 3
+            out_specs = [lay.spec("dqk", b, _qi_row),
+                         lay.spec("dqk", T, lambda *ij: 0),
+                         lay.spec("o", T, lambda *ij: 0)]
+            out_shape = [qk_like, qk_like, o_like]
         return pl.pallas_call(
             kernel, grid=grid, in_specs=q_side + kv_specs + tail,
             out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=[dq_acc, pltpu.VMEM((T, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32)],
+                            pltpu.VMEM((T, Dv), jnp.float32)],
             compiler_params=_grid_params(semantics, _VMEM_BUDGET_BYTES),
             interpret=interpret, name="flash_bwd",
         )(q, k, v, do, lse, delta)
     dq = pl.pallas_call(
         kernel, grid=grid, in_specs=q_side + kv_specs + tail,
-        out_specs=lay.spec("o", b, _qi_row), out_shape=o_like,
+        out_specs=lay.spec("dqk", b, _qi_row), out_shape=qk_like,
         scratch_shapes=[dq_acc],
         compiler_params=_grid_params(semantics),
         interpret=interpret, name="flash_bwd_dq",
@@ -639,10 +654,10 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
                   lay.spec("o", b, _q_row(causal)),
                   lay.spec("stat", b, _q_row(causal)),
                   lay.spec("stat", b, _q_row(causal))],
-        out_specs=[lay.spec("o", b, krow)] * 2,
-        out_shape=[o_like] * 2,
+        out_specs=[lay.spec("dqk", b, krow), lay.spec("o", b, krow)],
+        out_shape=[qk_like, o_like],
         scratch_shapes=[pltpu.VMEM((b, D), jnp.float32),
-                        pltpu.VMEM((b, D), jnp.float32)],
+                        pltpu.VMEM((b, Dv), jnp.float32)],
         compiler_params=_grid_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret, name="flash_bwd_dkv",
@@ -766,44 +781,63 @@ _SCORE_BYTES_CUTOVER = 4 * 1024 ** 3
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     backend: str = "auto",
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    fallback: bool = True):
     """Multi-head attention: XLA by default, Pallas kernel for long context.
 
     Args:
-      q, k, v: [B, T, H, D].
+      q, k: [B, T, H, D]; v: [B, T, H, Dv] (Dv = D, or a width of its own:
+        latent attention's heads are 192 wide for q and k, 128 for v).
       causal: apply the causal mask.
-      sm_scale: softmax scale (default 1/sqrt(D)).
+      sm_scale: softmax scale (default 1/sqrt(D), D the width of q and k).
       backend: "auto" (XLA unless the score tensor would exceed ~4 GiB —
         measured on the target platform XLA's fused attention outruns
         Mosaic until memory becomes the binding constraint), "pallas", or
         "xla".
       interpret: force kernel interpreter mode (defaults to True off-TPU).
+      fallback: what ``backend="pallas"`` does with a shape the kernels do
+        not tile: True (the packed block's callers), the XLA path below,
+        whose [B, H, T, T] float32 scores exist in HBM; False, a
+        ValueError (the layers described by ``MLA``: they never fall
+        back silently).
 
-    Differentiable on every path (the Pallas path via a custom VJP whose
-    dq/dk/dv are themselves Pallas kernels). The kernel requires T
-    divisible by 128 and D a multiple of 128; other shapes always take the
-    XLA path.
+    Which shapes tile: T a multiple of 128 and Dv a multiple of 128, D any
+    width. q and k of a width that is no multiple of 128 enter the kernels
+    padded with zero columns to the next one (192 -> 256: on a 128-wide
+    MXU the contraction takes two passes either way), and their gradients
+    leave cut back; the scale stays that of the true width. Differentiable on
+    every path (the Pallas path via a custom VJP whose dq/dk/dv are
+    themselves Pallas kernels).
     """
     B, T, H, D = q.shape
+    Dv = v.shape[-1]
     if sm_scale is None:
         sm_scale = float(D) ** -0.5
-    tilable = qkv_flash_tilable(T, D)
+    tilable = qkv_flash_tilable(T, Dv)
     if backend == "auto":
         score_bytes = 4 * B * H * T * T
         backend = "pallas" if (tilable
                                and score_bytes > _SCORE_BYTES_CUTOVER) \
             else "xla"
+    if backend == "pallas" and not tilable and not fallback:
+        raise ValueError(
+            f"flash_attention: the kernels tile T % 128 == 0 and a value "
+            f"width that is a multiple of 128; got T={T}, q/k width {D}, "
+            f"v width {Dv}, and no fallback to [T, T] scores was allowed")
     if backend == "xla" or not tilable:
         return _xla_attention(q, k, v, causal, sm_scale)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     def to_bhtd(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+        x = x.transpose(0, 2, 1, 3).reshape(B * H, T, x.shape[-1])
+        if x.shape[-1] % 128:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, -x.shape[-1] % 128)))
+        return x
 
     out = _flash_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v), causal, sm_scale,
                       interpret)
-    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
 
 
 def _xla_attention(q, k, v, causal, sm_scale):

@@ -21,9 +21,18 @@ is the transpose of all that but the solve's inside (T is an input). The
 float32 products (the solve, W, U, dA's two) are three bf16 passes
 (``_dot3``), as ``Precision.HIGH`` is in the ``jax.numpy`` form.
 
+**A decay per channel** (Kimi Delta Attention; g [.., dk] a row). The walk
+is the same kernels under the names ``kda_fwd`` / ``kda_bwd`` with
+e^{gam_C} a [dk] vector a chunk, laid over the state's rows. The stage is
+``kda_local_fwd`` / ``kda_local_bwd`` (:func:`kda_local_fwd`,
+:func:`kda_local_bwd`): grid (batch x head, blocks of tiles), a tile's
+body ``kda_tile.tile_fwd`` / ``tile_bwd`` itself, with this file's
+products; nothing of a tile but its inputs and outputs leaves VMEM.
+
 Kernel names (``pallas_call(name=)``; a device trace and the compiled HLO
 find the kernels by them, so they are API): ``gdn_fwd``, ``gdn_bwd``,
-``gdn_local_fwd`` and ``gdn_local_bwd``.
+``gdn_local_fwd``, ``gdn_local_bwd``; ``kda_fwd``, ``kda_bwd``,
+``kda_local_fwd`` and ``kda_local_bwd``.
 """
 
 from __future__ import annotations
@@ -35,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kda_tile
 from .pallas_attention import _dot, _grid_params
 
 _BLOCK = 8           # chunks worked in one grid step, at most
@@ -49,13 +59,28 @@ def _block(n: int) -> int:
     return next(b for b in (_BLOCK, 4, 2, 1) if n % b == 0)
 
 
+def _rows_and_columns(n: int):
+    """(a row [1, n] as a column [n, 1], a column as a row), by masked sums
+    over the other axis (exact; no relayout)."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return (lambda row: jnp.sum(jnp.where(eye, row, 0.0), axis=1,
+                                keepdims=True),
+            lambda col: jnp.sum(jnp.where(eye, col, 0.0), axis=0,
+                                keepdims=True))
+
+
 def _fwd_kernel(qg_ref, kd_ref, w_ref, u_ref, a_ref, e_ref, o_ref, st_ref,
-                s_ref, *, block: int):
+                s_ref, *, block: int, channels: bool = False):
+    """``channels``: e_ref[j] is a row [1, dk] of one decay a key channel,
+    laid over the state's rows; else a number, replicated [1, dv]."""
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
     dt = u_ref.dtype
+    decay = _rows_and_columns(s_ref.shape[0])[0] if channels \
+        else (lambda e: e)
     for j in range(block):
         S = s_ref[...]
         s = S.astype(dt)
@@ -64,17 +89,21 @@ def _fwd_kernel(qg_ref, kd_ref, w_ref, u_ref, a_ref, e_ref, o_ref, st_ref,
               - _dot(w_ref[j], s, 1, 0)).astype(dt)
         o_ref[j] = (_dot(qg_ref[j], s, 1, 0)
                     + _dot(a_ref[j], un, 1, 0)).astype(o_ref.dtype)
-        s_ref[...] = S * e_ref[j] + _dot(kd_ref[j], un, 0, 0)
+        s_ref[...] = S * decay(e_ref[j]) + _dot(kd_ref[j], un, 0, 0)
 
 
 def _bwd_kernel(qg_ref, kd_ref, w_ref, u_ref, a_ref, e_ref, st_ref, do_ref,
                 dqg_ref, dkd_ref, dw_ref, du_ref, da_ref, de_ref, ds_ref, *,
-                block: int):
+                block: int, channels: bool = False):
     @pl.when(pl.program_id(1) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
     dt = u_ref.dtype
+    if channels:
+        decay, as_row = _rows_and_columns(ds_ref.shape[0])
+    else:
+        decay = lambda e: e                                 # noqa: E731
     for j in reversed(range(block)):
         s, dS = st_ref[j], ds_ref[...]
         ds, do = dS.astype(dt), do_ref[j]
@@ -87,11 +116,15 @@ def _bwd_kernel(qg_ref, kd_ref, w_ref, u_ref, a_ref, e_ref, st_ref, do_ref,
         dw_ref[j] = (-_dot(dund, s, 1, 1)).astype(dt)
         du_ref[j] = dund
         da_ref[j] = _dot(do, un, 1, 1).astype(dt)
-        de_ref[j] = jnp.broadcast_to(
-            jnp.sum(jnp.sum(s.astype(jnp.float32) * dS, axis=0,
-                            keepdims=True), axis=1,
-                    keepdims=True), de_ref.shape[1:])
-        ds_ref[...] = (dS * e_ref[j] + _dot(qg_ref[j], do, 0, 0)
+        if channels:
+            de_ref[j] = as_row(jnp.sum(s.astype(jnp.float32) * dS, axis=1,
+                                       keepdims=True))
+        else:
+            de_ref[j] = jnp.broadcast_to(
+                jnp.sum(jnp.sum(s.astype(jnp.float32) * dS, axis=0,
+                                keepdims=True), axis=1,
+                        keepdims=True), de_ref.shape[1:])
+        ds_ref[...] = (dS * decay(e_ref[j]) + _dot(qg_ref[j], do, 0, 0)
                        - _dot(w_ref[j], dund, 0, 0))
 
 
@@ -102,7 +135,10 @@ def _flat(x):
 
 def _lanes_of(e_last, dv: int):
     """e^{gam_C} [B, H, N] as rows [B*H, N, 1, dv] that multiply a state's
-    rows without a lane broadcast."""
+    rows without a lane broadcast; [B, H, N, dk] (one a key channel) as
+    rows [B*H, N, 1, dk]."""
+    if e_last.ndim == 4:
+        return _flat(e_last)[:, :, None, :].astype(jnp.float32)
     return jnp.broadcast_to(_flat(e_last)[..., None, None],
                             (e_last.shape[0] * e_last.shape[1],
                              e_last.shape[2], 1, dv)).astype(jnp.float32)
@@ -131,12 +167,14 @@ def scan_fwd(qg, kd, w, u, aqk, e_last, *, interpret):
     dv = u.shape[-1]
     blk = _block(N)
     spec = functools.partial(_spec, blk, at=lambda n: n)
+    channels = e_last.ndim == 4
     o, states = pl.pallas_call(
-        functools.partial(_fwd_kernel, block=blk), grid=(B * H, N // blk),
-        name="gdn_fwd", interpret=interpret,
+        functools.partial(_fwd_kernel, block=blk, channels=channels),
+        grid=(B * H, N // blk),
+        name="kda_fwd" if channels else "gdn_fwd", interpret=interpret,
         compiler_params=_grid_params(("parallel", "arbitrary"), _VMEM_LIMIT),
         in_specs=[spec(C, dk), spec(C, dk), spec(C, dk), spec(C, dv),
-                  spec(C, C), spec(1, dv)],
+                  spec(C, C), spec(1, dk if channels else dv)],
         out_specs=[spec(C, dv), spec(dk, dv)],
         out_shape=[jax.ShapeDtypeStruct((B * H, N, C, dv), u.dtype),
                    jax.ShapeDtypeStruct((B * H, N, dk, dv), u.dtype)],
@@ -154,24 +192,29 @@ def scan_bwd(qg, kd, w, u, aqk, e_last, states, do, *, interpret):
     last = N // blk - 1
     spec = functools.partial(_spec, blk, at=lambda n: last - n)
     dt = u.dtype
+    channels = e_last.ndim == 4
+    de_lanes = dk if channels else dv
 
     def like(*tail, dtype=dt):
         return jax.ShapeDtypeStruct((B * H, N, *tail), dtype)
     *grads, de = pl.pallas_call(
-        functools.partial(_bwd_kernel, block=blk), grid=(B * H, N // blk),
-        name="gdn_bwd", interpret=interpret,
+        functools.partial(_bwd_kernel, block=blk, channels=channels),
+        grid=(B * H, N // blk),
+        name="kda_bwd" if channels else "gdn_bwd", interpret=interpret,
         compiler_params=_grid_params(("parallel", "arbitrary"), _VMEM_LIMIT),
         in_specs=[spec(C, dk), spec(C, dk), spec(C, dk), spec(C, dv),
-                  spec(C, C), spec(1, dv), spec(dk, dv), spec(C, dv)],
+                  spec(C, C), spec(1, de_lanes), spec(dk, dv), spec(C, dv)],
         out_specs=[spec(C, dk), spec(C, dk), spec(C, dk), spec(C, dv),
-                   spec(C, C), spec(1, dv)],
+                   spec(C, C), spec(1, de_lanes)],
         out_shape=[like(C, dk), like(C, dk), like(C, dk), like(C, dv),
-                   like(C, C), like(1, dv, dtype=jnp.float32)],
+                   like(C, C), like(1, de_lanes, dtype=jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
     )(*(_flat(x) for x in (qg, kd, w, u, aqk)), _lanes_of(e_last, dv),
       _flat(states), _flat(do))
-    return tuple(g.reshape(B, H, *g.shape[1:]) for g in grads) \
-        + (de[:, :, 0, 0].reshape(B, H, N),)
+    grads = tuple(g.reshape(B, H, *g.shape[1:]) for g in grads)
+    if channels:
+        return grads + (de[:, :, 0].reshape(B, H, N, dk),)
+    return grads + (de[:, :, 0, 0].reshape(B, H, N),)
 
 
 # -- the chunk-local stage -------------------------------------------------------
@@ -544,3 +587,156 @@ def local_bwd(q, k, v, g, beta, t, dqg, dkd, dw, du, daqk, de, *, interpret):
       _rows(jnp.broadcast_to(heads(de)[..., None], g.shape), P))
     return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
             d_g.reshape(g.shape), d_beta.reshape(g.shape))
+
+
+# -- the chunk-local stage under a decay per channel ---------------------------
+#
+# Tiles as above (P chunks in 128 rows, block-diagonal [R, R] arrays); the
+# body is ``kda_tile.tile_fwd`` / ``tile_bwd`` with the products below. g
+# enters as [R, dk] float32 beside k; beta, as in the kernels above, as a
+# row [1, R].
+
+
+class _MosaicOps:
+    """The products of ``kda_tile``'s functions, as Mosaic takes them."""
+    dot = staticmethod(_dot)
+
+    @staticmethod
+    def dot_hi(a, b, ca, cb):
+        return _dot3(_split(a), _split(b), ca, cb)
+
+    @staticmethod
+    def dot_sum(mask, x, ca=1):
+        # 0/1 is exact in bf16: the sum is x's high part plus its low
+        # part, 16 bits of mantissa (an exponent's error: 2^-17 of it).
+        return _dot3((mask.astype(jnp.bfloat16), None), _split(x), ca, 0)
+
+
+def _channel_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, C: int,
+                        P: int, block: int, solve: bool):
+    if solve:
+        (qg_ref, kd_ref, w_ref, u_ref, aqk_ref, e_ref, t_ref) = rest
+    else:
+        (t_ref, qg_ref, kd_ref, w_ref, u_ref, aqk_ref, e_ref) = rest
+    tile = _Tile(C, P)
+    dt = q_ref.dtype
+    for j in range(block):
+        beta = tile.over_lanes(tile.eye, beta_ref[j])
+        t = None if solve else tile.from_packed(t_ref[j])
+        qg, kd, w, u, aqk, e, t = kda_tile.tile_fwd(
+            _MosaicOps, C, P, q_ref[j], k_ref[j], v_ref[j], g_ref[j], beta,
+            t)
+        qg_ref[j] = qg.astype(dt)
+        kd_ref[j] = kd.astype(dt)
+        w_ref[j] = w.astype(dt)
+        u_ref[j] = u.astype(dt)
+        aqk_ref[j] = tile.to_collapsed(aqk).astype(dt)
+        e_ref[j] = e
+        if solve:
+            t_ref[j] = tile.to_packed(t)
+
+
+def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dqg_ref,
+                        dkd_ref, dw_ref, du_ref, daqk_ref, de_ref, dq_ref,
+                        dk_ref, dv_ref, dg_ref, dbeta_ref, *, C: int, P: int,
+                        block: int):
+    tile = _Tile(C, P)
+    dt = q_ref.dtype
+    for j in range(block):
+        dq, dk, dv, dg, dbeta = kda_tile.tile_bwd(
+            _MosaicOps, C, P, q_ref[j], k_ref[j], v_ref[j], g_ref[j],
+            tile.over_lanes(tile.eye, beta_ref[j]),
+            tile.from_packed(t_ref[j]), dqg_ref[j], dkd_ref[j], dw_ref[j],
+            du_ref[j], tile.from_collapsed(daqk_ref[j]), de_ref[j])
+        dq_ref[j] = dq.astype(dt)
+        dk_ref[j] = dk.astype(dt)
+        dv_ref[j] = dv.astype(dt)
+        dg_ref[j] = dg
+        dbeta_ref[j] = tile.over_rows(tile.eye, dbeta)
+
+
+def _channel_plan(q):
+    """Of q [B, H, N, C, dk]: chunks a tile, tiles a head, tiles a grid
+    step, and the block spec of an array [B*H, N/P, ..]."""
+    N, C = q.shape[2:4]
+    P = local_tile(N, C)
+    blk = next(b for b in (_LOCAL_BLOCK, 1) if N // P % b == 0)
+
+    def spec(*tail):
+        return pl.BlockSpec((None, blk, *tail),
+                            lambda b, n: (b, n) + (0,) * len(tail))
+    return P, N // P, blk, spec
+
+
+@_traced_once
+def kda_local_fwd(q, k, v, g, beta, t=None, *, interpret):
+    """``gated_delta._channel_fwd_xla`` as the kernel ``kda_local_fwd``:
+    q, k, g [B, H, N, C, dk] (g float32), v [B, H, N, C, dv], beta
+    [B, H, N, C] float32 -> (Q e^gam, K e^{gam_C - gam}, W, U, Aqk
+    [B, H, N, C, .] in q's dtype, e^{gam_C} [B, H, N, dk] float32) and T
+    float32 PACKED [B, H, N/P, C, P*C]. Given ``t`` the solve is skipped
+    (and t handed back as it came)."""
+    B, H, N, C, dk = q.shape
+    dv, dt, f32 = v.shape[-1], q.dtype, jnp.float32
+    P, NT, blk, spec = _channel_plan(q)
+    R = P * C
+
+    def like(*tail, dtype=dt):
+        return jax.ShapeDtypeStruct((B * H, NT, *tail), dtype)
+    inputs = [_tiles(x, P, 2) for x in (q, k, v, g)] \
+        + [beta.reshape(B * H, NT, 1, R)]
+    in_specs = [spec(R, dk), spec(R, dk), spec(R, dv), spec(R, dk),
+                spec(1, R)]
+    out_specs = [spec(R, dk), spec(R, dk), spec(R, dk), spec(R, dv),
+                 spec(R, C), spec(P, dk)]
+    out_shape = [like(R, dk), like(R, dk), like(R, dk), like(R, dv),
+                 like(R, C), like(P, dk, dtype=f32)]
+    if t is None:
+        out_specs.append(spec(C, R))
+        out_shape.append(like(C, R, dtype=f32))
+    else:
+        inputs.append(t.reshape(B * H, NT, C, R))
+        in_specs.append(spec(C, R))
+    qg, kd, w, u, aqk, e, *solved = pl.pallas_call(
+        functools.partial(_channel_fwd_kernel, C=C, P=P, block=blk,
+                          solve=t is None),
+        grid=(B * H, NT // blk), name="kda_local_fwd", interpret=interpret,
+        compiler_params=_grid_params(("parallel", "parallel"), _VMEM_LIMIT),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+    )(*inputs)
+    out = tuple(x.reshape(B, H, N, C, x.shape[-1])
+                for x in (qg, kd, w, u, aqk))
+    out += (e.reshape(B, H, N, dk),)
+    return out + ((solved[0].reshape(B, H, NT, C, R),) if solved else (t,))
+
+
+@_traced_once
+def kda_local_bwd(q, k, v, g, beta, t, dqg, dkd, dw, du, daqk, de, *,
+                  interpret):
+    """The transpose of :func:`kda_local_fwd` as the kernel
+    ``kda_local_bwd``: (dq, dk, dv in q's dtype, dg [B, H, N, C, dk] and
+    dbeta [B, H, N, C] float32)."""
+    B, H, N, C, dk = q.shape
+    dv, dt, f32 = v.shape[-1], q.dtype, jnp.float32
+    P, NT, blk, spec = _channel_plan(q)
+    R = P * C
+
+    def like(*tail, dtype=dt):
+        return jax.ShapeDtypeStruct((B * H, NT, *tail), dtype)
+    d_q, d_k, d_v, d_g, d_beta = pl.pallas_call(
+        functools.partial(_channel_bwd_kernel, C=C, P=P, block=blk),
+        grid=(B * H, NT // blk), name="kda_local_bwd", interpret=interpret,
+        compiler_params=_grid_params(("parallel", "parallel"), _VMEM_LIMIT),
+        in_specs=[spec(R, dk), spec(R, dk), spec(R, dv), spec(R, dk),
+                  spec(1, R), spec(C, R), spec(R, dk), spec(R, dk),
+                  spec(R, dk), spec(R, dv), spec(R, C), spec(P, dk)],
+        out_specs=[spec(R, dk), spec(R, dk), spec(R, dv), spec(R, dk),
+                   spec(1, R)],
+        out_shape=[like(R, dk), like(R, dk), like(R, dv),
+                   like(R, dk, dtype=f32), like(1, R, dtype=f32)],
+    )(*(_tiles(x, P, 2) for x in (q, k, v, g)),
+      beta.reshape(B * H, NT, 1, R), t.reshape(B * H, NT, C, R),
+      *(_tiles(x, P, 2) for x in (dqg, dkd, dw, du, daqk)),
+      de.reshape(B * H, NT, P, dk))
+    return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
+            d_g.reshape(g.shape), d_beta.reshape(beta.shape))

@@ -44,6 +44,8 @@ from .tp import (  # noqa: F401
     row_parallel,
 )
 from .transformer import (  # noqa: F401
+    KimiDeltaAttention,
+    LatentAttention,
     TransformerConfig,
     decode_step,
     forward,

@@ -1,8 +1,11 @@
 """The expert layer: a top-k mixture of feed-forward experts of which each
 chip HOLDS some, routes over all, and drops nothing.
 
-Every token's router scores all ``E`` experts and keeps its ``top_k``
-(weights renormalised over the kept ones, or the raw probabilities). A chip
+Every token's router scores all ``E`` experts (a softmax over them, or a
+sigmoid of each logit) and keeps its ``top_k`` (of the scores, or of the
+scores plus a bias a expert that counts for the selection alone; weights
+renormalised over the kept ones, or the raw scores; times a scaling
+factor). A chip
 is told which experts it holds — the ``held`` consecutive experts from
 ``first_expert``, whose weights are the leading dimension of ``w_up`` /
 ``w_down`` (/ ``w_gate``) — and computes the part of the layer's output
@@ -228,7 +231,8 @@ def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
 
 
 def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
-            renormalize: bool = False, first_expert=0, axis_name=None):
+            renormalize: bool = False, first_expert=0, axis_name=None,
+            score: str = "softmax", select_bias=None, scale: float = 1.0):
     """The held experts' share of a top-k expert layer.
 
     Args:
@@ -242,10 +246,18 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
       first_expert: the first expert held here (on an ``axis_name`` of size
         n: by rank 0 of it; rank r holds the next ``held`` ones).
       axis_name: the mesh axis the experts are spread over, or None.
+      score: ``"softmax"`` (probabilities over the E experts) or
+        ``"sigmoid"`` (of each expert's logit by itself).
+      select_bias: [E] or None: added to the scores to pick the ``top_k``
+        and for nothing else (the weights are the unbiased scores of the
+        kept; a constant to the backward: its update rule, from the load
+        of the whole group, is not the loss's gradient).
+      scale: a factor on the kept weights, after any renormalisation.
 
     Returns ``(y [N, D], stats)``: ``stats["aux"]`` is the load-balancing
     loss (Shazeer et al.: E x sum over experts of the share of assignments
-    x the mean router probability), ``stats["held_load"]`` [held] the
+    x the mean router probability; under sigmoid scores, the scores over
+    their sum), ``stats["held_load"]`` [held] the
     assignments that fell to each held expert, ``stats["absent"]`` the
     assignments of these tokens that fell to experts not held here,
     ``stats["ids"]`` [N, top_k] the experts each token kept.
@@ -255,9 +267,23 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top, ids = lax.top_k(probs, top_k)
+        if score == "softmax":
+            scores = probs = jax.nn.softmax(logits, axis=-1)
+        elif score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        else:
+            raise ValueError(f"moe_ffn: score {score!r} is not 'softmax' or "
+                             f"'sigmoid'")
+        if select_bias is None:
+            top, ids = lax.top_k(scores, top_k)
+        else:
+            ids = lax.top_k(scores + lax.stop_gradient(
+                select_bias.astype(jnp.float32)), top_k)[1]
+            top = jnp.take_along_axis(scores, ids, axis=-1)
         gates = top / jnp.sum(top, -1, keepdims=True) if renormalize else top
+        if scale != 1.0:
+            gates = gates * scale
         share = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(
             1.0 / (N * top_k))
         aux = jnp.sum(share * jnp.mean(probs, axis=0)) * E

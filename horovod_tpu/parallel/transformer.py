@@ -57,7 +57,37 @@ class GatedDeltaNet:
     backend: str = "auto"
 
 
-LAYER_KINDS = ("attn", "gdn")
+@dataclasses.dataclass(frozen=True)
+class KimiDeltaAttention:
+    """A Kimi Delta Attention mixer (``ops/gated_delta.py``, the gate per
+    channel): ``n_heads`` heads of ``d_head`` for q, k and v alike; a
+    causal depthwise convolution of ``conv_width`` on each; the log-decay
+    (one value a key channel) and the output gate from low-rank pairs of
+    rank ``d_head``; the rule in chunks of ``chunk`` rows (a power of two)
+    by ``backend``."""
+    n_heads: int
+    d_head: int
+    conv_width: int = 4
+    chunk: int = 64
+    backend: str = "auto"
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention (MLA) without positions, over
+    ``cfg.n_heads`` heads: keys and values are expanded from ONE normed
+    latent of ``kv_rank`` a token (``d_nope`` of a key and ``d_v`` of a
+    value a head); ``d_shared`` further key columns are one vector a token
+    that every head shares (the published ``qk_rope_head_dim``: NOT
+    rotated here, ``mla_use_nope``). A query head is ``d_nope + d_shared``
+    wide and the softmax scale is that width's."""
+    kv_rank: int
+    d_nope: int
+    d_shared: int
+    d_v: int
+
+
+LAYER_KINDS = ("attn", "gdn", "kda", "mla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +115,15 @@ class TransformerConfig:
     # in the same feed-forward or expert layer.
     layer_pattern: Tuple[str, ...] = ()
     gdn: Optional[GatedDeltaNet] = None
+    kda: Optional[KimiDeltaAttention] = None    # describes "kda" layers
+    mla: Optional[LatentAttention] = None       # describes "mla" layers
+    # The first ``dense_layers`` layers end in a dense gated-SiLU
+    # feed-forward of width ``dense_ff`` whatever their mixer and whatever
+    # ``n_experts`` says of the others (a leading dense layer before
+    # expert layers).
+    dense_layers: int = 0
+    dense_ff: int = 0
+    norm_eps: float = 1e-6      # under the root of every RMSNorm
     norm_offset: bool = False   # RMSNorm weights as (1 + w), w from zero
     rope_fraction: float = 1.0  # RoPE over this leading share of a head
     attn_gate: bool = False     # wq is doubled per head: q and a sigmoid
@@ -102,6 +141,18 @@ class TransformerConfig:
     moe_renormalize: bool = False
     experts_held: int = 0
     first_expert: int = 0
+    # The router's scores (``parallel/moe.moe_ffn``): "softmax" over the
+    # experts, or "sigmoid" of each logit; ``moe_select_bias``: a bias a
+    # expert ("router_bias", from zero, not trained by the loss; its update
+    # rule over an ``ep`` group's load is not built, so it stays zero and
+    # changes no selection yet) added to the scores for the SELECTION
+    # alone; ``moe_scale`` multiplies the
+    # kept weights. ``shared_expert_gate`` False: the shared expert's
+    # output is added as it is.
+    moe_score: str = "softmax"
+    moe_select_bias: bool = False
+    moe_scale: float = 1.0
+    shared_expert_gate: bool = True
     dtype: Any = jnp.bfloat16
     # "pallas" so TRAINING never materializes [T, T] scores for backward
     # (the flash custom VJP recomputes tiles); untilable shapes still fall
@@ -169,6 +220,14 @@ def _check_pattern(cfg: TransformerConfig):
         if g.n_v_heads % g.n_k_heads:
             raise ValueError(f"gdn: {g.n_v_heads} value heads do not divide "
                              f"over {g.n_k_heads} key heads")
+    for kind, described in (("kda", cfg.kda), ("mla", cfg.mla)):
+        if kind in cfg.layer_pattern and described is None:
+            raise ValueError(f"layer_pattern has {kind!r} layers and "
+                             f"cfg.{kind} does not describe them")
+    if cfg.dense_layers and not (cfg.dense_ff and cfg.mlp == "swiglu"):
+        raise ValueError("dense_layers are gated-SiLU feed-forwards of "
+                         "width dense_ff: they need dense_ff > 0 and "
+                         "mlp='swiglu'")
     if cfg.shared_expert_ff and not (cfg.n_experts and cfg.mlp == "swiglu"):
         raise ValueError("shared_expert_ff is a gated expert beside routed "
                          "ones: it needs n_experts > 0 and mlp='swiglu'")
@@ -178,7 +237,21 @@ def _extended(cfg: TransformerConfig) -> bool:
     """Whether the configuration uses what the layer pattern brought (its
     parameters are drawn from more keys a layer)."""
     return bool(cfg.layer_pattern or cfg.norm_offset or cfg.shared_expert_ff
-                or cfg.attn_gate)
+                or cfg.attn_gate or _wide_draw(cfg))
+
+
+def _wide_draw(cfg: TransformerConfig) -> bool:
+    """Whether the configuration uses what the "kda" and "mla" kinds
+    brought (a layer draws from more keys still)."""
+    return bool({"kda", "mla"} & set(cfg.layer_pattern) or cfg.dense_layers
+                or cfg.moe_select_bias or cfg.moe_score != "softmax"
+                or cfg.moe_scale != 1.0 or not cfg.shared_expert_gate
+                or cfg.norm_eps != 1e-6)
+
+
+def _dense_ffn(cfg: TransformerConfig, i: int) -> bool:
+    """Whether layer ``i`` ends in a dense feed-forward."""
+    return not cfg.n_experts or i < cfg.dense_layers
 
 
 def _held(cfg: TransformerConfig) -> int:
@@ -194,7 +267,8 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
     # the described block of one kind.
     dense = (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head
              and not _extended(cfg))
-    per_layer = 6 if dense else 16 if _extended(cfg) else 12
+    per_layer = 6 if dense else 24 if _wide_draw(cfg) \
+        else 16 if _extended(cfg) else 12
     k = jax.random.split(rng, (4 if dense else 5) + per_layer * cfg.n_layers)
     ki = iter(range(len(k)))
     norm = lambda key, shape, s: (jax.random.normal(k[key], shape) * s)  # noqa: E731
@@ -233,6 +307,41 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
             layer["gdn_dt_bias"] = jnp.ones((g.n_v_heads,))
             layer["gdn_norm"] = jnp.ones((g.d_v,))      # plain weight
             layer["gdn_wout"] = norm(next(ki), (nv, d), nv ** -0.5)
+        elif layer_kind(cfg, i) == "kda":
+            a = cfg.kda
+            n, r = a.n_heads * a.d_head, a.d_head
+            # Columns [q | k | v], heads-major inside each.
+            layer["kda_wqkv"] = norm(next(ki), (d, 3 * n), d ** -0.5)
+            layer["kda_conv"] = norm(next(ki), (a.conv_width, 3 * n),
+                                     a.conv_width ** -0.5)
+            # The low-rank pairs of the decay (f) and of the output gate.
+            layer["kda_wf_down"] = norm(next(ki), (d, r), d ** -0.5)
+            layer["kda_wf_up"] = norm(next(ki), (r, n), r ** -0.5)
+            layer["kda_wg_down"] = norm(next(ki), (d, r), d ** -0.5)
+            layer["kda_wg_up"] = norm(next(ki), (r, n), r ** -0.5)
+            layer["kda_wbeta"] = norm(next(ki), (d, a.n_heads), d ** -0.5)
+            # As the published module draws them: A uniform in (1, 16), a
+            # head, kept as its logarithm; the step's bias, a channel, at
+            # one.
+            layer["kda_a_log"] = jnp.log(jax.random.uniform(
+                k[next(ki)], (a.n_heads,), minval=1.0, maxval=16.0))
+            layer["kda_dt_bias"] = jnp.ones((n,))
+            layer["kda_norm"] = jnp.ones((a.d_head,))
+            layer["kda_wout"] = norm(next(ki), (n, d), n ** -0.5)
+        elif layer_kind(cfg, i) == "mla":
+            m, H = cfg.mla, cfg.n_heads
+            layer["mla_wq"] = norm(next(ki), (d, H * (m.d_nope + m.d_shared)),
+                                   d ** -0.5)
+            # Columns [latent | shared key part].
+            layer["mla_wkva"] = norm(next(ki), (d, m.kv_rank + m.d_shared),
+                                     d ** -0.5)
+            layer["mla_kv_norm"] = gain((m.kv_rank,))
+            # Columns heads-major, each head's [key part | value].
+            layer["mla_wkvb"] = norm(next(ki),
+                                     (m.kv_rank, H * (m.d_nope + m.d_v)),
+                                     m.kv_rank ** -0.5)
+            layer["mla_wo"] = norm(next(ki), (H * m.d_v, d),
+                                   (H * m.d_v) ** -0.5)
         elif _packed_qkv(cfg):
             layer["wqkv"] = norm(next(ki), (d, 3 * d), d ** -0.5)
             layer["wo"] = norm(next(ki), (d, d), d ** -0.5)
@@ -257,38 +366,45 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
             layer["idx_k_scale"] = jnp.ones((ix.d_head,))
             layer["idx_k_bias"] = jnp.zeros((ix.d_head,))
         # Experts: a leading dim of the experts held, sharded over ep.
-        lead = (_held(cfg),) if cfg.n_experts else ()
-        if cfg.n_experts:
+        experts = not _dense_ffn(cfg, i)
+        lead = (_held(cfg),) if experts else ()
+        ff = cfg.d_ff if experts or not cfg.dense_layers else cfg.dense_ff
+        if experts:
             layer["router"] = norm(next(ki), (d, cfg.n_experts), d ** -0.5)
+            if cfg.moe_select_bias:
+                layer["router_bias"] = jnp.zeros((cfg.n_experts,))
         if cfg.mlp == "swiglu":
-            layer["w_gate"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
-            layer["w_up"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
-            layer["w_down"] = norm(next(ki), lead + (cfg.d_ff, d),
-                                   cfg.d_ff ** -0.5)
+            layer["w_gate"] = norm(next(ki), lead + (d, ff), d ** -0.5)
+            layer["w_up"] = norm(next(ki), lead + (d, ff), d ** -0.5)
+            layer["w_down"] = norm(next(ki), lead + (ff, d), ff ** -0.5)
         else:
-            layer["w1"] = norm(next(ki), lead + (d, cfg.d_ff), d ** -0.5)
-            layer["w2"] = norm(next(ki), lead + (cfg.d_ff, d),
-                               cfg.d_ff ** -0.5)
-        if cfg.shared_expert_ff:
+            layer["w1"] = norm(next(ki), lead + (d, ff), d ** -0.5)
+            layer["w2"] = norm(next(ki), lead + (ff, d), ff ** -0.5)
+        if cfg.shared_expert_ff and experts:
             f = cfg.shared_expert_ff
             layer["shared_gate"] = norm(next(ki), (d, f), d ** -0.5)
             layer["shared_up"] = norm(next(ki), (d, f), d ** -0.5)
             layer["shared_down"] = norm(next(ki), (f, d), f ** -0.5)
-            layer["shared_w"] = norm(next(ki), (d, 1), d ** -0.5)
+            if cfg.shared_expert_gate:
+                layer["shared_w"] = norm(next(ki), (d, 1), d ** -0.5)
         params["layers"].append(layer)
     return params
 
 
 _GDN_LEAVES = ("gdn_wqkvz", "gdn_wba", "gdn_conv", "gdn_a_log", "gdn_dt_bias",
                "gdn_norm", "gdn_wout")
+_KDA_LEAVES = ("kda_wqkv", "kda_conv", "kda_wf_down", "kda_wf_up",
+               "kda_wg_down", "kda_wg_up", "kda_wbeta", "kda_a_log",
+               "kda_dt_bias", "kda_norm", "kda_wout")
+_MLA_LEAVES = ("mla_wq", "mla_wkva", "mla_kv_norm", "mla_wkvb", "mla_wo")
 _SHARED_LEAVES = ("shared_gate", "shared_up", "shared_down", "shared_w")
 
 
 def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     """PartitionSpec tree matching :func:`init_params`: Megatron column
     (out-dim) / row (in-dim) sharding over tp; experts over ep; everything
-    else replicated (dp/sp replicate params; a "gdn" layer's mixer and the
-    shared expert are replicated whole)."""
+    else replicated (dp/sp replicate params; a "gdn", "kda" or "mla"
+    layer's mixer and the shared expert are replicated whole)."""
     tp = "tp" if "tp" in _axes(mesh) else None
     ep = "ep" if "ep" in _axes(mesh) else None
     col, row = P(None, tp), P(tp, None)   # heads / ff columns shard over tp
@@ -300,8 +416,11 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
         up, down = ("w1",), ("w2",)
     for i in range(cfg.n_layers):
         layer = {"ln1": P(), "ln2": P()}
-        if layer_kind(cfg, i) == "gdn":
-            layer.update({name: P() for name in _GDN_LEAVES})
+        kind = layer_kind(cfg, i)
+        if kind in ("gdn", "kda", "mla"):
+            layer.update({name: P() for name in {
+                "gdn": _GDN_LEAVES, "kda": _KDA_LEAVES,
+                "mla": _MLA_LEAVES}[kind]})
         elif _packed_qkv(cfg):
             layer.update(wqkv=col, wo=row)
         else:
@@ -311,23 +430,27 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
         if cfg.indexer and layer_kind(cfg, i) == "attn":
             layer.update({name: P() for name in (
                 "idx_wq", "idx_wk", "idx_ww", "idx_k_scale", "idx_k_bias")})
-        if cfg.n_experts:
+        if not _dense_ffn(cfg, i):
             layer["router"] = P()
+            if cfg.moe_select_bias:
+                layer["router_bias"] = P()
             layer.update({name: P(ep, None, None) for name in up + down})
+            if cfg.shared_expert_ff:
+                layer.update({name: P() for name in _SHARED_LEAVES
+                              if name != "shared_w"
+                              or cfg.shared_expert_gate})
         else:
             layer.update({name: col for name in up})
             layer.update({name: row for name in down})
-        if cfg.shared_expert_ff:
-            layer.update({name: P() for name in _SHARED_LEAVES})
         specs["layers"].append(layer)
     return specs
 
 
-def _rms_norm(x, scale, offset: bool = False):
+def _rms_norm(x, scale, offset: bool = False, eps: float = 1e-6):
     """RMSNorm, float32 inside; ``offset``: the weight multiplies as
     ``1 + scale`` (``TransformerConfig.norm_offset``)."""
     x32 = x.astype(jnp.float32)
-    rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+    rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return ((x32 / rms) * (1.0 + scale if offset else scale)).astype(x.dtype)
 
 
@@ -379,13 +502,16 @@ def _causal_conv(x, w):
 
 def shared_expert(layer, h, dtype):
     """The gated expert every token takes, behind its sigmoid gate:
-    ``sigmoid(h w_s) (silu(h Wg) * h Wu) Wd``. Every chip of an
-    expert-parallel group computes it for its own tokens: it is no part
-    of a share."""
+    ``sigmoid(h w_s) (silu(h Wg) * h Wu) Wd``; without the leaf
+    ``shared_w`` (``shared_expert_gate`` False), as it is. Every chip of
+    an expert-parallel group computes it for its own tokens: it is no
+    part of a share."""
     with jax.named_scope("moe.shared"):
         def dot(a, w):
             return a @ layer[w].astype(dtype)
         act = jax.nn.silu(dot(h, "shared_gate")) * dot(h, "shared_up")
+        if "shared_w" not in layer:
+            return dot(act, "shared_down")
         gate = jax.nn.sigmoid(dot(h, "shared_w").astype(jnp.float32))
         return (dot(act, "shared_down").astype(jnp.float32)
                 * gate).astype(dtype)
@@ -393,10 +519,16 @@ def shared_expert(layer, h, dtype):
 
 def _check_mesh(cfg: TransformerConfig, axes):
     """Refuse the mesh axes a configuration's layers cannot take."""
-    if "gdn" in _kinds(cfg) and ("sp" in axes or "tp" in axes):
+    for kind, why in (("gdn", "state runs over the whole sequence"),
+                      ("kda", "state runs over the whole sequence"),
+                      ("mla", "latent is expanded for all heads at once")):
+        if kind in _kinds(cfg) and ("sp" in axes or "tp" in axes):
+            raise NotImplementedError(
+                f"a {kind!r} layer's {why} and its heads are not sharded: "
+                f"no sp and no tp (dp and ep only)")
+    if cfg.dense_layers and "tp" in axes:
         raise NotImplementedError(
-            "a 'gdn' layer's state runs over the whole sequence and its "
-            "heads are not sharded: no sp and no tp (dp and ep only)")
+            "dense_layers beside expert layers are not sharded over tp")
     if not _packed_qkv(cfg) and "sp" in axes:
         raise NotImplementedError(
             "ring attention over sp takes the dense block only (no RoPE "
@@ -416,7 +548,10 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     differentiates, and per row, [B, T], which it does not), the routing
     load ``held_load`` / ``absent`` and the experts each token kept
     ``ids``, and with ``with_masks`` the int8 selection ``mask`` and a
-    "gdn" layer's rule output ``gdn_o`` [B, T, Hv, dv].
+    "gdn" or "kda" layer's rule output ``gdn_o`` / ``kda_o`` [B, T, Hv, dv]
+    and an "mla" layer's attention output ``mla_o`` [B, T, H, d_v], with
+    what went into them: ``kda_in`` (q, k, v, g, beta as the rule takes
+    them) and ``mla_in`` (q, k, v as the attention does).
 
     ``grad_sync(k, layer, x, carry) -> (layer, x, carry)``, an identity
     here, is the training step's hook for the gradient exchange
@@ -430,7 +565,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     tp_size = mesh.shape.get("tp", 1)
     n_heads_local = cfg.n_heads // tp_size
     d_head = _d_head(cfg)
-    offset = cfg.norm_offset
+    offset, eps = cfg.norm_offset, cfg.norm_eps
 
     def _attention(layer, h, extras):
         from ..ops.pallas_attention import (flash_attention,
@@ -565,11 +700,102 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             return gated_norm(o, qkvz, layer["gdn_norm"]) \
                 @ layer["gdn_wout"].astype(cfg.dtype)
 
+    def _kda_mixer(li, layer, h, extras):
+        """The Kimi Delta Attention mixer: h [B, T, D] -> [B, T, D]."""
+        from ..ops.gated_delta import gated_delta_rule, record_saved
+        a, f32 = cfg.kda, jnp.float32
+        B, T, _ = h.shape
+        H, dh = a.n_heads, a.d_head
+        n = H * dh
+
+        def dot(x, w):
+            return x @ layer[w].astype(cfg.dtype)
+        with jax.named_scope("kda.proj"):
+            qkv = dot(h, "kda_wqkv")
+            f_low, g_low = dot(h, "kda_wf_down"), dot(h, "kda_wg_down")
+            b = dot(h, "kda_wbeta").astype(f32)
+        # As in the "gdn" mixer, what is elementwise between a projection
+        # and the rule, and between the rule and the output projection, is
+        # recomputed in the backward. The low-rank pairs' second halves
+        # are in there too: what is kept of the decay and of the output
+        # gate is their d_head-wide first half, not H x d_head columns
+        # a row.
+        @jax.checkpoint
+        def conv_and_gates(qkv, f_low, b, taps, wf_up, a_log, dt_bias):
+            with jax.named_scope("kda.proj"):
+                f = (f_low @ wf_up.astype(cfg.dtype)).astype(f32)
+            with jax.named_scope("kda.conv"):
+                qkv = jax.nn.silu(_causal_conv(qkv, taps))
+            with jax.named_scope("kda.scan"):
+                q, k, v = (qkv[..., j * n:(j + 1) * n].reshape(B, T, H, dh)
+                           for j in range(3))
+                decay = -jnp.exp(a_log.astype(f32))[:, None] \
+                    * jax.nn.softplus(f.reshape(B, T, H, dh)
+                                      + dt_bias.astype(f32).reshape(H, dh))
+                return (_l2_norm(q) * dh ** -0.5, _l2_norm(k), v, decay,
+                        jax.nn.sigmoid(b))
+
+        @jax.checkpoint
+        def gated_norm(o, g_low, wg_up, scale):
+            with jax.named_scope("kda.proj"):
+                gate = (g_low @ wg_up.astype(cfg.dtype)).astype(f32)
+            with jax.named_scope("kda.out"):
+                o = _rms_norm(o.astype(f32), scale, eps=eps) \
+                    * jax.nn.sigmoid(gate.reshape(B, T, H, dh))
+                return o.astype(cfg.dtype).reshape(B, T, n)
+
+        q, k, v, decay, beta = conv_and_gates(
+            qkv, f_low, b, layer["kda_conv"], layer["kda_wf_up"],
+            layer["kda_a_log"], layer["kda_dt_bias"])
+        with jax.named_scope("kda.scan"):
+            record_saved(li, q.shape, H, dh, jnp.dtype(cfg.dtype).itemsize,
+                         a.chunk, per_channel=True)
+            o = gated_delta_rule(q, k, v, decay, beta, chunk=a.chunk,
+                                 backend=a.backend, layer=li)
+            if with_masks:
+                extras["kda_in"], extras["kda_o"] = (q, k, v, decay, beta), o
+        out = gated_norm(o, g_low, layer["kda_wg_up"], layer["kda_norm"])
+        with jax.named_scope("kda.out"):
+            return dot(out, "kda_wout")
+
+    def _mla_mixer(layer, h, extras):
+        """Latent attention without positions: h [B, T, D] -> [B, T, D].
+        Never the [T, T] scores: a shape the flash kernels cannot tile is
+        an error under ``attn_backend="pallas"``."""
+        from ..ops.pallas_attention import flash_attention
+        m, H = cfg.mla, cfg.n_heads
+        B, T, _ = h.shape
+
+        def dot(x, w):
+            return x @ layer[w].astype(cfg.dtype)
+        with jax.named_scope("attn.mla"):
+            q = dot(h, "mla_wq").reshape(B, T, H, m.d_nope + m.d_shared)
+            latent = dot(h, "mla_wkva")
+            kv = dot(_rms_norm(latent[..., :m.kv_rank], layer["mla_kv_norm"],
+                               offset, eps), "mla_wkvb").reshape(
+                B, T, H, m.d_nope + m.d_v)
+            # One shared key part a token, the same for every head.
+            shared = jnp.broadcast_to(latent[:, :, None, m.kv_rank:],
+                                      (B, T, H, m.d_shared))
+            k = jnp.concatenate([kv[..., :m.d_nope], shared], axis=-1)
+            v = kv[..., m.d_nope:]
+            o = flash_attention(q, k, v, causal=True,
+                                backend=cfg.attn_backend, fallback=False)
+            if with_masks:
+                extras["mla_in"], extras["mla_o"] = (q, k, v), o
+            return dot(o.astype(cfg.dtype).reshape(B, T, H * m.d_v),
+                       "mla_wo")
+
     def _layer_fwd(li, layer, x):
         extras = {}
-        h = _rms_norm(x, layer["ln1"], offset)
-        if layer_kind(cfg, li) == "gdn":
+        h = _rms_norm(x, layer["ln1"], offset, eps)
+        kind = layer_kind(cfg, li)
+        if kind == "gdn":
             proj = _gdn_mixer(li, layer, h, extras)
+        elif kind == "kda":
+            proj = _kda_mixer(li, layer, h, extras)
+        elif kind == "mla":
+            proj = _mla_mixer(layer, h, extras)
         else:
             # Among layers of several kinds the softmax layer has a scope
             # of its own.
@@ -580,10 +806,10 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             if has_tp:
                 proj = lax.psum(proj, "tp")           # row-parallel combine
         x = x + proj
-        h = _rms_norm(x, layer["ln2"], offset)
+        h = _rms_norm(x, layer["ln2"], offset, eps)
         gated = cfg.mlp == "swiglu"
         w_up, w_down = ("w_up", "w_down") if gated else ("w1", "w2")
-        if cfg.n_experts:
+        if not _dense_ffn(cfg, li):
             B, T, _ = h.shape
             y, stats = moe_ffn(
                 h.reshape(-1, cfg.d_model), layer["router"],
@@ -591,18 +817,23 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                 w_gate=layer["w_gate"].astype(cfg.dtype) if gated else None,
                 top_k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
                 first_expert=cfg.first_expert,
-                axis_name="ep" if "ep" in axes else None)
+                axis_name="ep" if "ep" in axes else None,
+                score=cfg.moe_score, scale=cfg.moe_scale,
+                select_bias=layer.get("router_bias"))
             extras.update(stats)
             y = y.reshape(B, T, cfg.d_model)
             if cfg.shared_expert_ff:
                 y = y + shared_expert(layer, h, cfg.dtype)
             return x + y, extras
-        up = h @ layer[w_up].astype(cfg.dtype)
-        if gated:
-            up = jax.nn.silu(h @ layer["w_gate"].astype(cfg.dtype)) * up
-        else:
-            up = jax.nn.gelu(up)
-        down = up @ layer[w_down].astype(cfg.dtype)
+        # Among expert layers a dense feed-forward has a scope of its own.
+        with (jax.named_scope("ffn.dense") if cfg.dense_layers
+              else contextlib.nullcontext()):
+            up = h @ layer[w_up].astype(cfg.dtype)
+            if gated:
+                up = jax.nn.silu(h @ layer["w_gate"].astype(cfg.dtype)) * up
+            else:
+                up = jax.nn.gelu(up)
+            down = up @ layer[w_down].astype(cfg.dtype)
         if has_tp:
             down = lax.psum(down, "tp")
         return x + down, extras
@@ -622,7 +853,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             layer, x, carry = grad_sync(k, layer, x, carry)
         x, extras = _layer(k)(layer, x)
         per_layer.append(extras)
-    return _rms_norm(x, params["lnf"], offset), per_layer
+    return _rms_norm(x, params["lnf"], offset, eps), per_layer
 
 
 def _aux_total(per_layer):
@@ -795,9 +1026,11 @@ def _check_dense(cfg: TransformerConfig, what: str):
             f"{what} runs the dense block only (packed wqkv, GELU, tied "
             f"head): grouped key/value heads, q/k norm, RoPE, a gated "
             f"feed-forward, an untied head, sparse attention, layers of "
-            f"several kinds (a 'gdn' layer's state is no key/value cache), "
-            f"(1 + w) norms, an output gate and a shared expert exist on "
-            f"the training path alone")
+            f"several kinds (a 'gdn' or 'kda' layer's state is no "
+            f"key/value cache, and an 'mla' layer's latent has no cache "
+            f"of its own yet), leading dense layers, (1 + w) norms, an "
+            f"output gate and a shared expert exist on the training path "
+            f"alone")
 
 
 def init_kv_cache(cfg: TransformerConfig, max_slots: int, max_len: int,

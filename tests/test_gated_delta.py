@@ -528,13 +528,22 @@ def test_serving_refuses_the_new_kinds():
 # sha256 of ``step.lower(...).as_text()`` and of the seeded parameters' bytes
 # on the parent of PR 32 (commit b488b94), this installation, under this
 # suite's conftest (x64 on). ResNet-50's builders (``training.py``,
-# ``models/``) are files PR 32 does not touch.
+# ``models/``) are files PR 32 does not touch. The two ``gdn_shaped``
+# entries: on the parent of PR 34 (commit fa1c571), whose rule gained a
+# second gate beside this one (``toy()`` on the XLA backend, and on the
+# kernels, interpreted, at a chunk they tile).
 BEFORE = {
     "lm": ("dfa287d6c7f2df30b47a56f3a974f52d6c5439d08b6458204ab7a720766602c7",
            "3b085b22eeff759f2bc5510aee823ac7371bdff9ed119cdc635d6a1cfe64f42b"),
     "keye_shaped": (
         "1d1cf750595a66f307bb4bca3ee83b67e0f52f634725a972bdb57d2cec73ebf9",
         "4e2db50b6056ce5652824f4e44e1891ddad1472eef652f54a0e99bc6e07a846d"),
+    "gdn_shaped": (
+        "936eb95027d8bbf555e1e81d4a0456430646fde5b70440757f1419201344e483",
+        "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
+    "gdn_shaped_kernels": (
+        "eb74292990029841e743ec69acd54bda07daec424fdc0e51b20ff1bc64854cd7",
+        "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
 }
 DESCRIPTIONS = {
     "lm": (dict(vocab=256, d_model=64, n_heads=4, n_layers=2, d_ff=128,
@@ -546,13 +555,18 @@ DESCRIPTIONS = {
         moe_renormalize=True, experts_held=4, first_expert=2,
         dtype=jnp.float32, attn_backend="xla", unembed_dtype=jnp.float32),
         {"aux_weight": 0.0}),
+    "gdn_shaped": (toy, {"aux_weight": 0.0}),
+    "gdn_shaped_kernels": (
+        lambda: toy(gdn=GatedDeltaNet(2, 4, 16, 16, chunk=64,
+                                      backend="pallas")),
+        {"aux_weight": 0.0}),
 }
 
 
 @pytest.mark.parametrize("name", list(DESCRIPTIONS))
 def test_descriptions_of_one_kind_initialise_and_lower_as_before(name):
     fields, step_args = DESCRIPTIONS[name]
-    cfg = TransformerConfig(**fields)
+    cfg = fields() if callable(fields) else TransformerConfig(**fields)
     init_state, step = make_parallel_train_step(
         cfg, create_hybrid_mesh(devices=jax.devices()[:1], dp=1),
         optax.adamw(3e-4), **step_args)

@@ -303,3 +303,60 @@ def test_gated_delta_kernels_compile_and_carry_their_names(
         assert any("gdn.scan" in ln and name + "." in ln for ln in kernels)
     # The forward's kernel, and again in the backward from the saved T.
     assert sum("gdn_local_fwd." in ln.split(" = ")[0] for ln in kernels) == 2
+
+
+def test_per_channel_delta_kernels_compile_and_carry_their_names(
+        one_chip, monkeypatch):
+    """The rule with a decay per channel at the Kimi-Linear cell's shape
+    (1 x 8192 rows, 32 heads of 128, chunks of 64): the walk's ``kda_fwd``
+    / ``kda_bwd`` (a [1, 128] row of decays laid over the state's rows by a
+    masked sum, and back) and the chunk-local stage's ``kda_local_fwd`` /
+    ``kda_local_bwd`` (tiles of two chunks; integer masks of the six
+    levels, 0/1 matrices as bf16 operands, sublane slices and
+    concatenations of a chunk's last row)."""
+    from horovod_tpu.ops import gated_delta as gd
+    from horovod_tpu.ops import pallas_gated_delta as pgd
+    monkeypatch.setattr(pgd, "_interpret", lambda: False)
+    B, T, Hh = 1, 8192, 32
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("kda.scan"):
+            o = gd.gated_delta_rule(q, k, v, g, beta, backend="pallas")
+        return jnp.sum(o.astype(jnp.float32))
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            shape(B, T, Hh, D), shape(B, T, Hh, D), shape(B, T, Hh, D),
+            shape(B, T, Hh, D, dtype=jnp.float32),
+            shape(B, T, Hh, dtype=jnp.float32)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    found = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for ln in kernels}
+    assert found == {"kda_fwd", "kda_bwd", "kda_local_fwd", "kda_local_bwd"}
+    for name in found:
+        assert any("kda.scan" in ln and name + "." in ln for ln in kernels)
+
+
+@pytest.mark.parametrize("T", [2048, 8192])
+def test_flash_kernels_compile_at_a_key_width_of_their_own(one_chip, T):
+    """Latent attention's heads: 192 wide for q and k (padded to 256 on
+    entry), 128 for v; the forward and the backward the gate picks."""
+    def shape(d):
+        return jax.ShapeDtypeStruct((1, T, 32, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(
+            q, k, v, causal=True, backend="pallas", interpret=False,
+            fallback=False).astype(jnp.float32))
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(192), shape(192), shape(128)).compile().as_text()
+    names = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for ln in text.splitlines() if "tpu_custom_call" in ln}
+    assert "flash_fwd" in names and any(n.startswith("flash_bwd")
+                                        for n in names)
+    assert f"[32,{T},{T}]" not in text
