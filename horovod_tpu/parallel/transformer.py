@@ -47,7 +47,9 @@ class GatedDeltaNet:
     ``n_v_heads // n_k_heads`` consecutive value heads of ``d_v``; a causal
     depthwise convolution of ``conv_width`` over time on q, k and v; the
     rule in chunks of ``chunk`` rows by ``backend`` (``"auto"``: the Pallas
-    kernels on a TPU, the XLA scan elsewhere)."""
+    kernels on a TPU, the XLA scan elsewhere), which also chooses the
+    convolution's form (``ops/causal_conv.py``: the kernels
+    ``conv_silu_fwd`` / ``conv_silu_bwd`` where its shapes tile)."""
     n_k_heads: int
     n_v_heads: int
     d_k: int
@@ -64,7 +66,8 @@ class KimiDeltaAttention:
     causal depthwise convolution of ``conv_width`` on each; the log-decay
     (one value a key channel) and the output gate from low-rank pairs of
     rank ``d_head``; the rule in chunks of ``chunk`` rows (a power of two)
-    by ``backend``."""
+    by ``backend``, which also chooses the convolution's form
+    (``ops/causal_conv.py``)."""
     n_heads: int
     d_head: int
     conv_width: int = 4
@@ -489,17 +492,6 @@ def _l2_norm(x):
                             + 1e-6)).astype(x.dtype)
 
 
-def _causal_conv(x, w):
-    """Depthwise convolution over time, causal: x [B, T, C], w [W, C];
-    ``y_t = sum_j w[j] x_{t - (W - 1) + j}`` with zeros before the start
-    (no bias; no state carried in from another sequence)."""
-    W, T = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
-    y = sum(padded[:, j:j + T].astype(jnp.float32) * w[j].astype(jnp.float32)
-            for j in range(W))
-    return y.astype(x.dtype)
-
-
 def shared_expert(layer, h, dtype):
     """The gated expert every token takes, behind its sigmoid gate:
     ``sigmoid(h w_s) (silu(h Wg) * h Wu) Wd``; without the leaf
@@ -651,6 +643,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
 
     def _gdn_mixer(li, layer, h, extras):
         """The Gated DeltaNet mixer: h [B, T, D] -> [B, T, D]."""
+        from ..ops.causal_conv import causal_conv_silu
         from ..ops.gated_delta import gated_delta_rule, record_saved
         g, f32 = cfg.gdn, jnp.float32
         B, T, _ = h.shape
@@ -661,13 +654,15 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
         # What lies between the projections and the rule, and between the
         # rule and the output projection, is elementwise over 12288
         # columns a row: it is recomputed in the backward from the
-        # projections' outputs (taken whole: a slice would be a copy), not
-        # saved (3.4 GB over three layers at 2 x 8192 rows, compiled for a
-        # v5e).
+        # projections' outputs (taken whole: a slice would be a copy; the
+        # convolution reads its 2 nk + nv columns of qkvz where they lie),
+        # not saved (3.4 GB over three layers at 2 x 8192 rows, compiled
+        # for a v5e).
         @jax.checkpoint
         def conv_and_gates(qkvz, ba, taps, a_log, dt_bias):
             with jax.named_scope("gdn.conv"):
-                qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * nk + nv], taps))
+                qkv = causal_conv_silu(qkvz, taps, backend=g.backend,
+                                       layer=li)
             with jax.named_scope("gdn.scan"):
                 q = qkv[..., :nk].reshape(B, T, g.n_k_heads, g.d_k)
                 k = qkv[..., nk:2 * nk].reshape(B, T, g.n_k_heads, g.d_k)
@@ -702,6 +697,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
 
     def _kda_mixer(li, layer, h, extras):
         """The Kimi Delta Attention mixer: h [B, T, D] -> [B, T, D]."""
+        from ..ops.causal_conv import causal_conv_silu
         from ..ops.gated_delta import gated_delta_rule, record_saved
         a, f32 = cfg.kda, jnp.float32
         B, T, _ = h.shape
@@ -725,7 +721,8 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             with jax.named_scope("kda.proj"):
                 f = (f_low @ wf_up.astype(cfg.dtype)).astype(f32)
             with jax.named_scope("kda.conv"):
-                qkv = jax.nn.silu(_causal_conv(qkv, taps))
+                qkv = causal_conv_silu(qkv, taps, backend=a.backend,
+                                       layer=li)
             with jax.named_scope("kda.scan"):
                 q, k, v = (qkv[..., j * n:(j + 1) * n].reshape(B, T, H, dh)
                            for j in range(3))

@@ -531,7 +531,11 @@ def test_serving_refuses_the_new_kinds():
 # ``models/``) are files PR 32 does not touch. The two ``gdn_shaped``
 # entries: on the parent of PR 34 (commit fa1c571), whose rule gained a
 # second gate beside this one (``toy()`` on the XLA backend, and on the
-# kernels, interpreted, at a chunk they tile).
+# kernels, interpreted, at a chunk they tile). ``gdn_shaped_kernels`` was
+# replaced by PR 35 (eb742929... on its parent): under ``"pallas"`` the
+# mixer's convolution + SiLU is now the kernels ``conv_silu_fwd`` /
+# ``conv_silu_bwd`` (128 columns and 64 rows tile); ``gdn_shaped``, on the
+# XLA backend, held through the move of ``_causal_conv`` to ``ops/``.
 BEFORE = {
     "lm": ("dfa287d6c7f2df30b47a56f3a974f52d6c5439d08b6458204ab7a720766602c7",
            "3b085b22eeff759f2bc5510aee823ac7371bdff9ed119cdc635d6a1cfe64f42b"),
@@ -542,7 +546,7 @@ BEFORE = {
         "936eb95027d8bbf555e1e81d4a0456430646fde5b70440757f1419201344e483",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
     "gdn_shaped_kernels": (
-        "eb74292990029841e743ec69acd54bda07daec424fdc0e51b20ff1bc64854cd7",
+        "0094aa78c0a5445b2fedbb5a852ebe10bab4641b1334c20e436c29e27cd3b951",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
 }
 DESCRIPTIONS = {

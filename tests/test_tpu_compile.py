@@ -360,3 +360,38 @@ def test_flash_kernels_compile_at_a_key_width_of_their_own(one_chip, T):
     assert "flash_fwd" in names and any(n.startswith("flash_bwd")
                                         for n in names)
     assert f"[32,{T},{T}]" not in text
+
+
+@pytest.mark.parametrize("B,T,Cx,C,W,dtype", [
+    (2, 8192, 12288, 8192, 4, jnp.bfloat16),   # Qwen3-Next: q, k, v of qkvz
+    (1, 8192, 12288, 12288, 4, jnp.bfloat16),  # Kimi-Linear: qkv whole
+    (1, 256, 128, 128, 2, jnp.float32),        # float32, two taps
+], ids=["qwen3next_8k", "kimi_8k", "float32"])
+def test_causal_conv_kernels_compile_and_carry_their_names(
+        one_chip, monkeypatch, B, T, Cx, C, W, dtype):
+    """The convolution + SiLU's two kernels (ops/pallas_causal_conv.py) at
+    the two cells' shapes: blocks of [2048, 512] with their 16 rows before
+    and after through clamped index maps, sublane rotations of (8 rows ‖ 64
+    rows) float32 arrays, single-row loads of the taps and a single-row
+    accumulation of dw, dynamic row offsets in a ``fori_loop`` — what
+    interpret mode cannot refuse."""
+    from horovod_tpu.ops import causal_conv as cc
+    from horovod_tpu.ops import pallas_gated_delta as pgd
+    monkeypatch.setattr(pgd, "_interpret", lambda: False)
+
+    def loss(x, w):
+        with jax.named_scope("gdn.conv"):
+            y = cc.causal_conv_silu(x, w, backend="pallas")
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.ShapeDtypeStruct((B, T, Cx), dtype, sharding=one_chip),
+            jax.ShapeDtypeStruct((W, C), jnp.float32, sharding=one_chip),
+        ).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    found = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for ln in kernels}
+    assert found == {"conv_silu_fwd", "conv_silu_bwd"}
+    for name in found:
+        assert any("gdn.conv" in ln and name + "." in ln for ln in kernels)
