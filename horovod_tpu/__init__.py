@@ -16,6 +16,13 @@ cached compiled dispatches (single-controller) or a host DCN coordination
 plane (multi-process) eagerly. See ``runtime.py`` and ``ops/``.
 """
 
+import time as _time
+
+# The span ``hvd.import`` starts here, before anything is imported: jax,
+# flax, optax, Pallas and the package's own modules are a job's first
+# seconds (docs/timeline.md). Recorded by this file's last statement.
+_IMPORT_START_NS = _time.perf_counter_ns()
+
 from .version import __version__  # noqa: F401
 
 # Before anything can start the TPU backend: libtpu reads its arguments once.
@@ -101,3 +108,6 @@ from .exceptions import (  # noqa: F401
     CheckpointTimeoutError,
     NonFiniteGradError,
 )
+
+from .utils.timeline import record_span as _record_span
+_record_span("hvd.import", _IMPORT_START_NS, _time.perf_counter_ns())

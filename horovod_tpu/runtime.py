@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Any, Optional, Sequence
 
 import jax
@@ -49,6 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .exceptions import NotInitializedError
 from .utils import config as _config
+from .utils.timeline import record_span as _record_span
 
 # The world axis name. Every collective in this framework reduces over it.
 AXIS: str = "hvd"
@@ -102,6 +104,9 @@ def init(devices: Optional[Sequence[jax.Device]] = None,
     with _lock:
         if _world is not None:
             return _world
+        # The call that does the work is the span ``hvd.init``: for a user
+        # who has not touched jax yet, the backend's start-up is in here.
+        start_ns = time.perf_counter_ns()
         _generation += 1
 
         _maybe_init_jax_distributed()
@@ -187,6 +192,7 @@ def init(devices: Optional[Sequence[jax.Device]] = None,
             env_world=env_world,
         )
         _start_observability(_world)
+        _record_span("hvd.init", start_ns, time.perf_counter_ns(), size=size)
         return _world
 
 

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import atexit
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -215,27 +216,87 @@ def thread_names() -> Dict[int, str]:
     return dict(_thread_names)
 
 
-# Recompilations, where they happen: the runtime reports each backend
-# compile's duration after the fact; it becomes a counter tick and an
-# ``xla.compile`` span ending now on the compiling thread, so a gap or a
-# slow step can be put down to a compile.
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# What jax does before a program's first step, and recompilations where
+# they happen: the runtime reports each trace, each lowering to MLIR and
+# each backend compile after the fact, with the function's name. Each
+# becomes a span ending now on the thread that did the work and seconds on
+# a counter, so a slow set-up, a gap or a slow step can be put down to a
+# phase and a function. The persistent cache says on the same thread,
+# before the compile's report, whether it was asked and whether it had
+# the executable: that is the ``cache`` id of ``xla.compile``.
+_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_NOTES = {"/jax/compilation_cache/compile_requests_use_cache": "miss",
+                "/jax/compilation_cache/cache_hits": "hit"}
 _m_compiles = _metrics_registry().counter(
     "hvd_compiles_total",
     "XLA backend compile requests in this process: a program traced for "
     "a new shape, compiled or loaded from the persistent cache. A repeat "
     "of a shape already compiled is none")
+_m_compile_seconds = _metrics_registry().counter(
+    "hvd_compile_seconds_total",
+    "Seconds jax spent tracing functions to jaxprs (phase=trace), lowering "
+    "them to MLIR (lower) and in backend compile requests, cache loads "
+    "included (compile): the sum of the xla.trace, xla.lower and "
+    "xla.compile spans, nested ones counted in both", labels=("phase",))
+_m_compile_cache = _metrics_registry().counter(
+    "hvd_compile_cache_total",
+    "Backend compile requests that asked the persistent compilation "
+    "cache, by what it had: result=hit loaded the executable, miss "
+    "compiled it", labels=("result",))
+_phase_seconds = {phase: _m_compile_seconds.labels(phase=phase)
+                  for phase in _PHASES.values()}
 
 
-def _on_event_duration(event: str, duration_s: float, **_kw) -> None:
-    if event != _BACKEND_COMPILE_EVENT:
+def _on_event(event: str, **_kw) -> None:
+    note = _CACHE_NOTES.get(event)
+    # jax "asks" its cache also where it was given no directory to keep
+    # one in: that request is no miss.
+    if note is not None and jax.config.jax_compilation_cache_dir:
+        _tls.cache = note
+
+
+def _on_event_duration(event: str, duration_s: float, fun_name: str = "",
+                       **_kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
         return
-    _m_compiles.inc()
     now = time.perf_counter_ns()
-    record_span("xla.compile", now - int(duration_s * 1e9), now)
+    ids = {"fun": fun_name}
+    if phase == "compile":
+        _m_compiles.inc()
+        ids["cache"] = cache = getattr(_tls, "cache", "off")
+        _tls.cache = "off"
+        if cache != "off":
+            _m_compile_cache.labels(result=cache).inc()
+    _phase_seconds[phase].inc(duration_s)
+    record_span("xla." + phase, now - int(duration_s * 1e9), now, **ids)
 
 
+jax.monitoring.register_event_listener(_on_event)
 jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+
+# Collector pauses: a full (generation-2) collection, and any collection
+# of a millisecond or more, is the span ``host.gc`` on the thread it
+# stopped. The rest cost two clock reads and leave nothing.
+_GC_SPAN_NS = 1_000_000
+_gc_start_ns = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_start_ns
+    if phase == "start":
+        _gc_start_ns = _now_ns()
+        return
+    end = _now_ns()
+    if info["generation"] == 2 or end - _gc_start_ns >= _GC_SPAN_NS:
+        record_span("host.gc", _gc_start_ns, end,
+                    generation=info["generation"],
+                    collected=info["collected"])
+
+
+gc.callbacks.append(_on_gc)
 
 
 @contextlib.contextmanager

@@ -3,7 +3,11 @@ in-memory recorder of ``utils/timeline.py``, the spans ``Trainer.fit``,
 ``prefetch_to_device`` and the step wrappers record, the compile counter,
 and the named scopes of the lowered step."""
 
+import gc
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -105,9 +109,13 @@ def test_two_threads_keep_their_own_stacks():
 
 def test_ring_stays_bounded_and_keeps_the_newest():
     assert tl.RING_SPANS >= 65536
-    for i in range(tl.RING_SPANS + 100):
-        with tl.span("flood", i=i):
-            pass
+    gc.disable()             # a full collection would be a span among them
+    try:
+        for i in range(tl.RING_SPANS + 100):
+            with tl.span("flood", i=i):
+                pass
+    finally:
+        gc.enable()
     got = tl.spans()
     assert len(got) == tl.RING_SPANS
     assert got[-1].ids == {"i": tl.RING_SPANS + 99}
@@ -335,3 +343,153 @@ def test_parallel_transformer_step_carries_the_same_scopes():
                   'transpose(jvp(forward))/optimizer/allreduce.bucket0/psum',
                   '"optimizer/allreduce.bucket1/psum'):
         assert scope in text, scope
+
+
+# -- set-up: what jax does before the first step, by phase and function --------
+
+def _phases(since, name):
+    """The trace, lowering and compile of the function ``name`` among
+    ``since`` (jax names the last two ``jit(<name>)``)."""
+    return [s for s in since if s.name.startswith("xla.")
+            and s.ids.get("fun") in (name, f"jit({name})")]
+
+
+def test_a_fresh_function_leaves_a_trace_a_lowering_and_a_compile():
+    seconds = registry().counter("hvd_compile_seconds_total",
+                                 labels=("phase",))
+    before = {p: seconds.labels(phase=p).value
+              for p in ("trace", "lower", "compile")}
+
+    def setup_phases_probe(x):
+        return jnp.tanh(x) * 3 - 1
+
+    f = jax.jit(setup_phases_probe)
+    mark = _mark()
+    f(jnp.ones((7, 3)))
+    got = _phases(_since(mark), "setup_phases_probe")
+    assert [s.name for s in got] == ["xla.trace", "xla.lower", "xla.compile"]
+    assert [s.ids["fun"] for s in got] == [
+        "setup_phases_probe", "jit(setup_phases_probe)",
+        "jit(setup_phases_probe)"]
+    trace, lower, compiled = got
+    assert trace.start_ns < trace.end_ns <= lower.end_ns <= compiled.end_ns
+    assert lower.start_ns < compiled.start_ns
+    assert "cache" not in trace.ids and "cache" not in lower.ids
+    main = threading.current_thread().ident
+    assert {s.thread for s in got} == {main}
+    for phase, s in zip(("trace", "lower", "compile"), got):
+        grew = seconds.labels(phase=phase).value - before[phase]
+        assert grew >= (s.end_ns - s.start_ns) / 1e9 * 0.99 > 0
+    # A repeat of a compiled shape leaves nothing.
+    mark = _mark()
+    f(jnp.ones((7, 3)))
+    assert not _since(mark)
+
+
+def test_a_trace_inside_an_open_span_is_its_child():
+    def nested_probe(x):
+        return x + 2
+
+    with tl.span("test.outer") as outer:
+        jax.jit(nested_probe)(jnp.ones((2,)))
+    inside = [s for s in tl.spans() if s.parent == outer.id]
+    assert {"xla.trace", "xla.lower", "xla.compile"} <= {
+        s.name for s in inside}
+
+
+def test_compile_spans_say_what_the_persistent_cache_did(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cache = registry().counter("hvd_compile_cache_total",
+                               labels=("result",))
+
+    def counted():
+        """(hits, misses): clearing jax's caches makes the small programs
+        around the probe compile again too, so these move by one or more."""
+        return tuple(cache.labels(result=r).value for r in ("hit", "miss"))
+
+    start = counted()
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+
+    def cached_probe(x):
+        return jnp.sin(x) + 41
+
+    def compile_again():
+        jax.clear_caches()
+        mark = _mark()
+        jax.jit(cached_probe)(jnp.ones((5,)))
+        compiled = [s for s in _phases(_since(mark), "cached_probe")
+                    if s.name == "xla.compile"]
+        assert len(compiled) == 1
+        return compiled[0].ids["cache"]
+
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        cc.reset_cache()
+        assert compile_again() == "miss"
+        cold = counted()
+        assert cold[0] == start[0] and cold[1] > start[1]
+        assert compile_again() == "hit"
+        warm = counted()
+        assert warm[0] > cold[0] and warm[1] == cold[1]
+        jax.config.update("jax_compilation_cache_dir", None)
+        cc.reset_cache()
+        assert compile_again() == "off"
+        assert counted() == warm
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was[1])
+        cc.reset_cache()
+
+
+def test_import_is_one_span_and_nothing_starts_before_it():
+    code = ("import json, horovod_tpu\n"
+            "from horovod_tpu.utils import timeline\n"
+            "print(json.dumps([[s.name, s.start_ns, s.end_ns, s.thread] "
+            "for s in timeline.spans()]))")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    imports = [s for s in got if s[0] == "hvd.import"]
+    assert len(imports) == 1
+    _, start, end, thread = imports[0]
+    assert end - start > 100_000_000, "jax alone takes longer to import"
+    assert all(s[1] >= start for s in got)
+    # It is the last thing the import does: what jax traced while the
+    # modules loaded lies inside it, on the same thread.
+    assert got[-1][0] == "hvd.import"
+    assert all(s[2] <= end and s[3] == thread for s in got)
+
+
+def test_init_is_one_span_over_two_calls():
+    hvd.shutdown()
+    mark = _mark()
+    hvd.init()
+    hvd.init()
+    inits = [s for s in _since(mark) if s.name == "hvd.init"]
+    assert len(inits) == 1
+    assert inits[0].ids == {"size": hvd.size()}
+    assert inits[0].end_ns > inits[0].start_ns
+
+
+# -- collector pauses ----------------------------------------------------------
+
+def test_full_collections_are_spans_and_quick_young_ones_are_not():
+    gc.collect()                       # leave little for the next ones
+    mark = _mark()
+    gc.collect()
+    got = [s for s in _since(mark) if s.name == "host.gc"]
+    assert len(got) == 1
+    assert got[0].ids["generation"] == 2 and got[0].ids["collected"] >= 0
+    assert got[0].end_ns > got[0].start_ns
+    assert got[0].thread == threading.current_thread().ident
+    mark = _mark()
+    gc.collect(0)
+    young = [s for s in _since(mark) if s.name == "host.gc"]
+    assert all(s.end_ns - s.start_ns >= 1_000_000 for s in young), \
+        "a collection under a millisecond left a span"
+    assert not young or young[0].ids["generation"] == 0
