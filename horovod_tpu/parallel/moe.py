@@ -37,33 +37,13 @@ Named scopes (a device trace splits the step by them): ``moe.route`` and
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..obs.registry import registry as _registry
-
-
-@jax.custom_vjp
-def _rows_of_tokens(x, token):
-    """x [M, D] -> x[token] [rows, D]. A token is taken once for each of
-    its assignments among the rows, so the transpose adds; it adds in
-    float32 whatever x's dtype."""
-    return x[token]
-
-
-def _rows_of_tokens_fwd(x, token):
-    return x[token], (token, jnp.zeros((x.shape[0], 0), x.dtype))
-
-
-def _rows_of_tokens_bwd(res, g):
-    token, like = res
-    dx = jnp.zeros((like.shape[0], g.shape[1]), jnp.float32).at[token].add(
-        g.astype(jnp.float32))
-    return dx.astype(like.dtype), None
-
-
-_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 def _tile(n: int, most: int) -> int:
@@ -72,7 +52,6 @@ def _tile(n: int, most: int) -> int:
 
 
 _GMM_ROWS = 512      # rows of a grouped product's tile on the TPU
-_SCAN_CHUNKS = 8     # chunks one scan takes, skipped ones included
 
 
 def _on_tpu() -> bool:
@@ -136,10 +115,105 @@ def _grouped(rows, w, sizes, real):
     return jnp.where(real, out, 0)
 
 
+def _chunk(c, k, order, sizes, ends):
+    """Chunk ``c`` of the sorted assignments: (the tokens of its rows
+    [cap], ``through``). ``through(gathered, ws, weight_c)`` takes the
+    tokens' rows of x through their experts ``ws`` = (w_gate or None,
+    w_up, w_down), weighted: [cap, D] float32, zeros where a row is of no
+    group."""
+    cap = order.shape[1]
+    lo = c * cap
+    sizes_c = (jnp.clip(ends, lo, lo + cap)
+               - jnp.clip(ends - sizes, lo, lo + cap))
+    real = (lo + jnp.arange(cap, dtype=jnp.int32) < ends[-1])[:, None]
+
+    def through(gathered, ws, weight_c):
+        w_gate, w_up, w_down = ws
+        rows = jnp.where(real, gathered, 0)
+        up = _grouped(rows, w_up, sizes_c, real)
+        if w_gate is None:
+            h = jax.nn.gelu(up)
+        else:
+            h = jax.nn.silu(_grouped(rows, w_gate, sizes_c, real)) * up
+        out = _grouped(h.astype(rows.dtype), w_down, sizes_c, real)
+        return out.astype(jnp.float32) * weight_c[:, None]
+    return order[c] // k, through
+
+
+def _entered(ends, cap):
+    """Chunks that hold a row (a device scalar): the loops' trip count."""
+    return -(-ends[-1] // cap)
+
+
+def _first_then_entered(add, acc, order, ends):
+    """``add(c, acc)`` for chunk 0, straight, then for the chunks after it
+    that hold a row, as a loop (none is written where one chunk holds
+    every row)."""
+    n_chunks, cap = order.shape
+    acc = add(0, acc)
+    if n_chunks > 1:
+        acc = lax.fori_loop(1, _entered(ends, cap), add, acc)
+    return acc
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunks(k, x, ws, weight, order, sizes, ends):
+    """Every chunk's rows through their experts, added to their tokens:
+    y [M, D] float32. ``weight`` / ``order`` [n_chunks, cap] are the sorted
+    assignments' weights and indices, ``sizes`` / ``ends`` [held] the
+    groups.
+
+    Chunk 0 is worked straight; chunks 1 .. n_entered - 1 are a loop whose
+    TRIP COUNT is the load, in both directions. A chunk that holds no row
+    is then nothing in the program: a ``lax.scan`` over all the chunks
+    with the empty ones skipped by a ``cond`` still paid, in its
+    transpose, zeros the size of x and of every weight written and added
+    a skipped turn (measured on a v5e: PERF.md PR 32, 8.6 ms a turn and
+    layer; PR 37, 3.9 in the Keye cell), and a ``cond`` around the turns
+    after the first paid them once."""
+    def add(c, y):
+        token, through = _chunk(c, k, order, sizes, ends)
+        return y.at[token].add(through(x[token], ws, weight[c]))
+    return _first_then_entered(add, jnp.zeros(x.shape, jnp.float32), order,
+                               ends)
+
+
+def _chunks_fwd(k, x, ws, weight, order, sizes, ends):
+    # What is saved does not grow with the chunks: the backward works a
+    # chunk again from its indices.
+    return (_chunks(k, x, ws, weight, order, sizes, ends),
+            (x, ws, weight, order, sizes, ends))
+
+
+def _chunks_bwd(k, res, g):
+    """The cotangents of x (added up in float32, a token once for each of
+    its assignments), of the weights and of the assignments' weights:
+    chunk 0's own, then each entered chunk's ADDED in the loop's carry,
+    which a while loop updates in place."""
+    x, ws, weight, order, sizes, ends = res
+
+    def add(c, acc):
+        dx, dws, dweight = acc
+        token, through = _chunk(c, k, order, sizes, ends)
+        vjp = jax.vjp(through, x[token], ws, weight[c])[1]
+        d_rows, d_ws, d_weight_c = vjp(g[token])
+        return (dx.at[token].add(d_rows.astype(jnp.float32)),
+                d_ws if dws is None else jax.tree_util.tree_map(
+                    jnp.add, dws, d_ws),
+                dweight.at[c].set(d_weight_c))
+    dx, dws, dweight = _first_then_entered(
+        add, (jnp.zeros(x.shape, jnp.float32), None, jnp.zeros_like(weight)),
+        order, ends)
+    return dx.astype(x.dtype), dws, dweight, None, None, None
+
+
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
+
+
 def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
     """The held experts' share of the output for tokens ``x`` [M, D] with
     routing ``ids`` / ``gates`` [M, k]: (y [M, D], assignments per held
-    expert [held]).
+    expert [held], chunks that held a row).
 
     Assignments are sorted by expert, absent experts last, and worked in
     chunks of half as many rows again as a balanced router would send here
@@ -148,10 +222,10 @@ def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
     of exactly that load would be followed by a second, nearly empty one
     every other step, which costs the same rows. A shorter chunk pays more
     tiles (one more for each expert's boundary). A chunk gathers and scatters
-    all its rows, a chunk past the last row held here is skipped, and the
-    grouped products visit only the tiles that hold a group's rows: the
-    work follows the load the router sends, and a step takes longer when
-    the held experts are popular."""
+    all its rows, the chunks past the last row held here are never entered
+    (``_chunks``), and the grouped products visit only the tiles that hold
+    a group's rows: the work follows the load the router sends, and a step
+    takes longer when the held experts are popular."""
     M, D = x.shape
     k, held = ids.shape[1], w_up.shape[0]
     local = ids.reshape(-1) - first
@@ -170,64 +244,8 @@ def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
     pad = n_chunks * cap - n_rows
     order = jnp.pad(order, (0, pad)).reshape(n_chunks, cap)
     weight = jnp.pad(weight, (0, pad)).reshape(n_chunks, cap)
-
-    def work(c, order_c, weight_c):
-        """The chunk's rows through their experts, weighted: [cap, D]
-        float32, zeros where a row is of no group."""
-        lo = c * cap
-        sizes_c = (jnp.clip(ends, lo, lo + cap)
-                   - jnp.clip(ends - sizes, lo, lo + cap))
-        real = (lo + jnp.arange(cap, dtype=jnp.int32) < ends[-1])[:, None]
-        rows = jnp.where(real, _rows_of_tokens(x, order_c // k), 0)
-        up = _grouped(rows, w_up, sizes_c, real)
-        if w_gate is None:
-            h = jax.nn.gelu(up)
-        else:
-            h = jax.nn.silu(_grouped(rows, w_gate, sizes_c, real)) * up
-        out = _grouped(h.astype(x.dtype), w_down, sizes_c, real)
-        return out.astype(jnp.float32) * weight_c[:, None]
-
-    # The backward recomputes a chunk from its indices: what a chunk saves
-    # must not grow with the chunks (x and the weights are the scan's
-    # constants; inside a cond they would be saved once a chunk).
-    @jax.checkpoint
-    def rows_out(c, order_c, weight_c):
-        if n_chunks == 1:
-            return work(c, order_c, weight_c)
-        return lax.cond(c * cap < ends[-1], work,
-                        lambda *_: jnp.zeros((cap, D), jnp.float32),
-                        c, order_c, weight_c)
-
-    def chunk(y, inp):
-        c, order_c, weight_c = inp
-        out = rows_out(c, order_c, weight_c)
-
-        def add(y):
-            return y.at[order_c // k].add(out)
-        if n_chunks == 1:
-            return add(y), None
-        return lax.cond(c * cap < ends[-1], add, lambda y: y, y), None
-    def run(y, chunks):
-        return lax.scan(chunk, y, chunks)[0]
-    y = jnp.zeros((M, D), jnp.float32)
-    chunks = (jnp.arange(n_chunks, dtype=jnp.int32), order, weight)
-    # A skipped turn of the scan is not free: its transpose still adds a
-    # zero the size of x and of every weight to their cotangents (8.6 ms a
-    # turn and layer at [16384, 2048] and 16 experts of 512, measured on a
-    # v5e, PERF.md PR 32). Up to _SCAN_CHUNKS chunks one scan takes them
-    # all; a smaller share's turns after the first go under ONE cond,
-    # which a balanced load (two thirds of a chunk) never enters.
-    if n_chunks <= _SCAN_CHUNKS:
-        return run(y, chunks).astype(x.dtype), sizes
-    # The checkpoint goes outside the cond (inside, what the turns close
-    # over would be saved once a turn: PERF.md PR 28).
-    @jax.checkpoint
-    def overflow(rest):
-        return lax.cond(cap < ends[-1], run, lambda y, _: y,
-                        jnp.zeros((M, D), jnp.float32), rest)
-    y = run(y, tuple(a[:1] for a in chunks)) \
-        + overflow(tuple(a[1:] for a in chunks))
-    return y.astype(x.dtype), sizes
+    y = _chunks(k, x, (w_gate, w_up, w_down), weight, order, sizes, ends)
+    return y.astype(x.dtype), sizes, _entered(ends, cap)
 
 
 def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
@@ -260,7 +278,10 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
     their sum), ``stats["held_load"]`` [held] the
     assignments that fell to each held expert, ``stats["absent"]`` the
     assignments of these tokens that fell to experts not held here,
-    ``stats["ids"]`` [N, top_k] the experts each token kept.
+    ``stats["ids"]`` [N, top_k] the experts each token kept,
+    ``stats["chunks"]`` (int32) the chunks of the held experts' sorted
+    assignments that held a row and were worked (``_held_experts``: the
+    load over a chunk's rows, rounded up; 1 under a balanced load).
     """
     N, _ = x.shape
     E, held = router_w.shape[1], w_up.shape[0]
@@ -304,13 +325,15 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
         xs, ids_s, gates_s = (lax.all_gather(a, axis_name, tiled=True)
                               for a in (x, ids, gates))
     with jax.named_scope("moe.experts"):
-        y, load = _held_experts(xs, ids_s, gates_s.astype(jnp.float32),
-                                w_gate, w_up, w_down, first_expert, E)
+        y, load, chunks = _held_experts(
+            xs, ids_s, gates_s.astype(jnp.float32), w_gate, w_up, w_down,
+            first_expert, E)
     if spread:
         y = lax.psum_scatter(y, axis_name, tiled=True)
     local = ids - first_expert
     absent = N * top_k - jnp.sum((local >= 0) & (local < held))
-    return y, {"aux": aux, "held_load": load, "absent": absent, "ids": ids}
+    return y, {"aux": aux, "held_load": load, "absent": absent, "ids": ids,
+               "chunks": chunks}
 
 
 _m_load = _registry().gauge(
@@ -327,11 +350,22 @@ _m_absent = _registry().gauge(
     "layer, as last recorded", labels=("layer",))
 
 
-def record_routing(layer: int, held_load, absent) -> None:
+_m_chunks = _registry().gauge(
+    "hvd_moe_chunks_entered",
+    "chunks of sorted assignments that held a row for the experts held on "
+    "this chip (1 under a balanced load; each one more is a chunk's worth "
+    "of rows beyond 1.5 x balanced, which the step paid for), by layer, as "
+    "last recorded", labels=("layer",))
+
+
+def record_routing(layer: int, held_load, absent, chunks=None) -> None:
     """Stamp one layer's routing load (host values, off the dispatch path:
-    from a step's small outputs after it completed)."""
+    from a step's small outputs after it completed); ``chunks``, where the
+    caller has it, is ``moe_ffn``'s ``stats["chunks"]``."""
     load = [float(v) for v in held_load]
     mean = sum(load) / len(load)
     _m_load.labels(layer=str(layer)).set(max(load) / mean if mean else 0.0)
     _m_held.labels(layer=str(layer)).set(sum(load))
     _m_absent.labels(layer=str(layer)).set(float(absent))
+    if chunks is not None:
+        _m_chunks.labels(layer=str(layer)).set(float(chunks))

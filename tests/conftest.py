@@ -68,7 +68,9 @@ def pytest_configure(config):
 # four older LM-family cells to what PR 34 found. PR 35 MEANT to change the
 # Qwen3-Next cell's program (the mixers' convolution + SiLU became the
 # kernels conv_silu_fwd / conv_silu_bwd); tests/test_lowered_pins.py pins
-# that cell's new hash, and the Kimi cell's. The other three cases hold.
+# that cell's new hash, and the Kimi cell's. PR 37 MEANT to change the Keye
+# cell's (the expert layer's chunks after the first became a loop whose trip
+# count is the load) and pins its new hash there too. The two LM cases hold.
 _EXPECTED = (
     ("tests/benchmark/test_bench_gdn_stages.py::"
      "test_entries_in_the_manifest", "[-2:] ==",
@@ -78,6 +80,11 @@ _EXPECTED = (
      "test_lowered_step_is_the_one_pinned[qwen3next_gdn_train_8k_1chip]",
      "== LOWERED[cell]",
      "PR 35 changed this cell's program by design; its new hash is pinned "
+     "in tests/test_lowered_pins.py (for a benchmark PR)"),
+    ("tests/benchmark/test_bench_lowered_steps.py::"
+     "test_lowered_step_is_the_one_pinned[keye_dsa_train_8k_1chip]",
+     "== LOWERED[cell]",
+     "PR 37 changed this cell's program by design; its new hash is pinned "
      "in tests/test_lowered_pins.py (for a benchmark PR)"),
 )
 

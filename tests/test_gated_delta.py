@@ -12,6 +12,7 @@ descriptions initialising and lowering exactly as before."""
 import dataclasses
 import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -407,37 +408,30 @@ def test_the_shares_add_up_with_the_shared_expert_counted_once():
                          - whole).max()) > 1e-2
 
 
-@pytest.mark.parametrize("popular", [False, True],
-                         ids=["balanced", "overflowing"])
-def test_a_small_share_drops_nothing_whatever_its_load(popular):
-    """2 of 64 experts held: the assignments make 16 chunks, more than one
-    scan takes; the turns after the first lie under one cond. A balanced
-    router never enters it; one that sends nearly every token to the held
-    experts does, and every assignment is still worked: forward and the
-    gradients of x and of the experts against each held expert applied to
-    every token."""
-    from horovod_tpu.parallel import moe
-    n, held, first, M = 64, 2, 5, 128
-    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+def _routed(n, held, first, rows_sent, M=128, seed=11):
+    """``M`` tokens top-2 over ``n`` experts of which ``held`` from
+    ``first`` are held here, a router that sends ABOUT ``rows_sent`` of the
+    2 M assignments to the held ones (a balanced one where that is less
+    than a balanced load), and the held experts' weights."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     x = jax.random.normal(ks[0], (M, D))
     router = jax.random.normal(ks[1], (D, n)) * D ** -0.5
-    if popular:
-        router = router.at[:, first:first + held].add(
-            0.4 * jnp.sign(jnp.sum(x, 0))[:, None])
+    # The first m tokens carry a mark that the router's first row reads for
+    # the held experts alone: both of their assignments fall here.
+    balanced = 2 * held / n
+    m = max(0, min(M, round((rows_sent - M * balanced) / (2 - balanced))))
+    x = x.at[:, 0].set(jnp.where(jnp.arange(M) < m, 1.0, 0.0))
+    router = router.at[0].set(0.0).at[0, first:first + held].set(9.0)
     w = {"w_gate": jax.random.normal(ks[2], (held, D, F)) * D ** -0.5,
          "w_up": jax.random.normal(ks[3], (held, D, F)) * D ** -0.5,
          "w_down": jax.random.normal(ks[4], (held, F, D)) * F ** -0.5}
-    n_rows = M * 2
-    cap = max(8, -(-n_rows * held * 3 // (n * 2) // 8) * 8)
-    assert -(-n_rows // cap) > moe._SCAN_CHUNKS
+    return x, router, w
 
-    def system(x, w):
-        y, stats = moe_ffn(x, router, w["w_up"], w["w_down"],
-                           w_gate=w["w_gate"], top_k=2, renormalize=True,
-                           first_expert=first)
-        return jnp.sum(y * jnp.cos(y)), (y, stats)
 
-    def plain(x, w):
+def _plain_experts(first):
+    """``reference._experts`` with no shared expert, as a loss of (x, w,
+    router)."""
+    def plain(x, w, router):
         layer = dict(w, router=router, shared_gate=jnp.zeros((D, 1)),
                      shared_up=jnp.zeros((D, 1)),
                      shared_down=jnp.zeros((1, D)),
@@ -445,18 +439,124 @@ def test_a_small_share_drops_nothing_whatever_its_load(popular):
         y, _ = reference._experts(x, layer, {"experts_per_tok": 2,
                                              "first_expert": first}, None)
         return jnp.sum(y * jnp.cos(y)), y
+    return plain
+
+
+def _close_at_each_leafs_scale(grads, want_grads):
+    """float32 sums of up to 250 rows an expert: 2e-5 of a leaf's scale."""
+    for got, ref in zip(jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(
+            got, ref, atol=2e-5 * max(1.0, float(jnp.abs(ref).max())))
+
+
+def chunks_gauge(layer):
+    return parse_exposition(registry().render())[
+        ("hvd_moe_chunks_entered", (("layer", str(layer)),))]
+
+
+@pytest.mark.parametrize("load", ["balanced", "one_chunk_over",
+                                  "nearly_every_token"])
+@pytest.mark.parametrize("n,held,n_chunks", [(8, 4, 2), (16, 2, 6),
+                                             (64, 2, 16)],
+                         ids=["a_half", "an_eighth", "a_thirty_second"])
+def test_a_small_share_drops_nothing_whatever_its_load(n, held, n_chunks,
+                                                       load):
+    """A half, an eighth (the Keye cell's: six chunks of 1.5 x a balanced
+    load) and a thirty-second of the experts held, under a balanced router
+    (chunk 0 alone is worked), one that sends a chunk's worth too many, and
+    one that sends nearly every token here: every assignment is worked
+    whatever the load, ``stats["chunks"]`` and the gauge say how many
+    chunks that took, forward and the gradients of x and of every expert
+    weight against each held expert applied to every token."""
+    from horovod_tpu.parallel import moe
+    first, M = 5 if n > 8 else 2, 128
+    n_rows = M * 2
+    cap = max(8, -(-n_rows * held * 3 // (n * 2) // 8) * 8)
+    assert -(-n_rows // cap) == n_chunks
+    sent = {"balanced": 0, "one_chunk_over": min(1.5 * cap,
+                                                 (cap + n_rows) / 2),
+            "nearly_every_token": n_rows - 6}[load]
+    x, router, w = _routed(n, held, first, sent)
+
+    def system(x, w):
+        y, stats = moe_ffn(x, router, w["w_up"], w["w_down"],
+                           w_gate=w["w_gate"], top_k=2, renormalize=True,
+                           first_expert=first)
+        return jnp.sum(y * jnp.cos(y)), (y, stats)
 
     (_, (y, stats)), grads = jax.value_and_grad(
         system, argnums=(0, 1), has_aux=True)(x, w)
     (_, want), want_grads = jax.value_and_grad(
-        plain, argnums=(0, 1), has_aux=True)(x, w)
-    load = int(jnp.sum(stats["held_load"]))
-    assert load + int(stats["absent"]) == n_rows
-    assert (load > cap) == popular
+        _plain_experts(first), argnums=(0, 1), has_aux=True)(x, w, router)
+    rows = int(jnp.sum(stats["held_load"]))
+    assert rows + int(stats["absent"]) == n_rows
+    entered = {"balanced": 1, "one_chunk_over": 2,
+               "nearly_every_token": n_chunks}[load]
+    assert -(-rows // cap) == entered, (rows, cap)
+    assert int(stats["chunks"]) == entered
+    moe.record_routing(40 + n_chunks, stats["held_load"], stats["absent"],
+                       chunks=stats["chunks"])
+    assert chunks_gauge(40 + n_chunks) == entered
     np.testing.assert_allclose(y, want, atol=2e-5)
-    for got, ref in zip(jax.tree_util.tree_leaves(grads),
-                        jax.tree_util.tree_leaves(want_grads)):
-        np.testing.assert_allclose(got, ref, atol=2e-5)
+    _close_at_each_leafs_scale(grads, want_grads)
+
+
+def test_chunks_that_hold_no_row_are_not_in_the_program():
+    """The gradient of the six-chunk share, lowered: ONE loop each way
+    (its trip count is the load) and nothing conditional, so no chunk is a
+    skipped turn of a scan or an untaken branch whose transpose writes
+    zeros the size of x and of every weight (PERF.md PRs 32 and 37); and
+    no zeros of a weight's shape are made to be added to."""
+    x, router, w = _routed(16, 2, 5, 0)
+
+    def loss(x, w):
+        y, _ = moe_ffn(x, router, w["w_up"], w["w_down"], w_gate=w["w_gate"],
+                       top_k=2, renormalize=True, first_expert=5)
+        return jnp.sum(y * jnp.cos(y))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).as_text()
+    assert text.count("stablehlo.while") == 2
+    assert not re.search(r"stablehlo\.(case|if)\b", text)
+    made = re.findall(r"stablehlo\.(?:broadcast_in_dim|constant).*-> "
+                      r"tensor<2x(?:64x32|32x64)xf32>", text)
+    assert not made, made
+
+
+@pytest.mark.parametrize("popular", [False, True],
+                         ids=["balanced", "second_chunk_entered"])
+def test_ep2_router_gradient_against_the_reference(popular):
+    """Two ranks hold half the experts each, so every expert is present and
+    the assignments' weights are differentiable: forward and the gradients
+    of x, the ROUTER and every expert weight against the reference holding
+    all eight, under a balanced router (each rank works chunk 0 alone) and
+    one that sends rank 0 more than a chunk (its loop is entered, and the
+    custom backward returns that chunk's weights' cotangent too)."""
+    from jax.sharding import PartitionSpec as P
+    n, M = 8, 128
+    x, router, w = _routed(n, n // 2, 0, 230 if popular else 0)
+    w = {k: jnp.concatenate([v, v[::-1] * 0.5]) for k, v in w.items()}
+    mesh = create_hybrid_mesh(ep=2, devices=jax.devices()[:2])
+
+    def local(x, w, router):
+        y, stats = moe_ffn(x, router, w["w_up"], w["w_down"],
+                           w_gate=w["w_gate"], top_k=2, renormalize=True,
+                           axis_name="ep")
+        return y, stats["chunks"][None]
+
+    def system(x, w, router):
+        y, chunks = jax.shard_map(
+            local, mesh=mesh, in_specs=(P("ep"), P("ep"), P()),
+            out_specs=(P("ep"), P("ep")), check_vma=False)(x, w, router)
+        return jnp.sum(y * jnp.cos(y)), (y, chunks)
+
+    (_, (y, chunks)), grads = jax.jit(jax.value_and_grad(
+        system, argnums=(0, 1, 2), has_aux=True))(x, w, router)
+    (_, want), want_grads = jax.value_and_grad(
+        _plain_experts(0), argnums=(0, 1, 2), has_aux=True)(x, w, router)
+    assert chunks.tolist() == ([2, 1] if popular else [1, 1])
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    assert float(jnp.abs(want_grads[2]).max()) > 1e-2
+    _close_at_each_leafs_scale(grads, want_grads)
 
 
 # -- the step -----------------------------------------------------------------
@@ -536,17 +636,21 @@ def test_serving_refuses_the_new_kinds():
 # mixer's convolution + SiLU is now the kernels ``conv_silu_fwd`` /
 # ``conv_silu_bwd`` (128 columns and 64 rows tile); ``gdn_shaped``, on the
 # XLA backend, held through the move of ``_causal_conv`` to ``ops/``.
+# The three that hold an expert layer were replaced by PR 37 (1d1cf750...,
+# 936eb950..., 0094aa78... on its parent): a share's chunks after the first
+# are a loop under a custom VJP (``parallel/moe._chunks``), where they were
+# turns of a scan under ``jax.checkpoint``. The drawn parameters held.
 BEFORE = {
     "lm": ("dfa287d6c7f2df30b47a56f3a974f52d6c5439d08b6458204ab7a720766602c7",
            "3b085b22eeff759f2bc5510aee823ac7371bdff9ed119cdc635d6a1cfe64f42b"),
     "keye_shaped": (
-        "1d1cf750595a66f307bb4bca3ee83b67e0f52f634725a972bdb57d2cec73ebf9",
+        "9fb6b3a3f270a8103fa880e3153434f95ba7e1e58c0e8c82f3d9a3caceb3c6e7",
         "4e2db50b6056ce5652824f4e44e1891ddad1472eef652f54a0e99bc6e07a846d"),
     "gdn_shaped": (
-        "936eb95027d8bbf555e1e81d4a0456430646fde5b70440757f1419201344e483",
+        "c4f4eb6782b437617a9487b2126893997ae3104d3baf8167afdebd66eca9f5fb",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
     "gdn_shaped_kernels": (
-        "0094aa78c0a5445b2fedbb5a852ebe10bab4641b1334c20e436c29e27cd3b951",
+        "816ee8f08b643649c9f21b37cef80105bd3b57bbc51e2e5799b7b3e558831ca7",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
 }
 DESCRIPTIONS = {

@@ -1,12 +1,14 @@
-"""The lowered training step of the two hybrid linear-attention cells, for a
+"""The lowered training step of the three expert-layer cells, for a
 described v5e, hashed by ``benchmarks/lowered_sha.py`` with the kernels'
-debug locations taken out: what PR 35 left, which put the kernels
-``conv_silu_fwd`` / ``conv_silu_bwd`` into both. (The benchmark's own
+debug locations taken out: what PR 37 left, which made the expert layer's
+chunks after the first a loop whose trip count is the load, forward and
+backward, in all three (PR 35 had put the kernels ``conv_silu_fwd`` /
+``conv_silu_bwd`` into the two hybrid cells). (The benchmark's own
 ``tests/benchmark/test_bench_lowered_steps.py`` pins the four older cells
-to PR 34's programs and is not this PR's to edit: its Qwen3-Next case is
-reported as expected by ``tests/conftest.py`` and its guard lives on here.)
-A PR that means to change a cell's program replaces that cell's hash with
-what the tool prints, and says so."""
+to PR 34's programs and is not this PR's to edit: its Qwen3-Next and Keye
+cases are reported as expected by ``tests/conftest.py`` and their guard
+lives on here.) A PR that means to change a cell's program replaces that
+cell's hash with what the tool prints, and says so."""
 
 import json
 import os
@@ -17,12 +19,17 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-LOWERED = {
+HYBRID = {
     "qwen3next_gdn_train_8k_1chip":
-        "6d48876077ab9bdd2999f176a874557827423da0cc7e9e2351562fddd9158ce3",
+        "e58c801eaf27c8c04240acdd75fcb4e9620e8ec7ed5c52c1649c80ce68b29594",
     "kimi_kda_train_8k_1chip":
-        "a0c1afe30da160ad98266f8d00832e5e24939eee7e80ba751c075d61f1c42344",
+        "36dd192ab09b9a9c00317bddfe00ed37b1907f8f203f2e90d1d5bfa572bf0c82",
 }
+KEYE = {
+    "keye_dsa_train_8k_1chip":
+        "b4efd8d977d93c9c75142d4f7bee55c8e8c17c329fb06d27239fca76eec0fa7d",
+}
+LOWERED = {**HYBRID, **KEYE}
 
 
 @pytest.fixture(scope="module")
@@ -43,10 +50,11 @@ def test_lowered_step_is_the_one_pinned(lines, cell):
     assert lines[cell]["sha256_without_kernel_locations"] == LOWERED[cell]
 
 
-@pytest.mark.parametrize("cell", list(LOWERED))
+@pytest.mark.parametrize("cell", list(HYBRID))
 def test_the_cell_holds_the_convolutions_kernels(lines, cell):
-    """Three kernel bodies more than the parent's lowered program had (16
-    in either cell): ``conv_silu_fwd``, the same again inside the
-    checkpoint's recomputation, ``conv_silu_bwd``; the layers of a cell
-    share them (``_traced_once``)."""
-    assert lines[cell]["kernels"] == 19
+    """Three kernel bodies more than PR 35's parent had (16 in either
+    cell): ``conv_silu_fwd``, the same again inside the checkpoint's
+    recomputation, ``conv_silu_bwd``; the layers of a cell share them
+    (``_traced_once``). And four more since PR 37: the grouped products'
+    bodies are lowered once for chunk 0 and once inside the loops."""
+    assert lines[cell]["kernels"] == 23
