@@ -53,6 +53,7 @@ from .transformer import (  # noqa: F401
     init_params,
     kv_cache_specs,
     make_parallel_train_step,
+    mla_from_interleaved,
     param_specs,
     prefill,
 )

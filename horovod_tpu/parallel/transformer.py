@@ -77,17 +77,22 @@ class KimiDeltaAttention:
 
 @dataclasses.dataclass(frozen=True)
 class LatentAttention:
-    """Multi-head latent attention (MLA) without positions, over
-    ``cfg.n_heads`` heads: keys and values are expanded from ONE normed
-    latent of ``kv_rank`` a token (``d_nope`` of a key and ``d_v`` of a
-    value a head); ``d_shared`` further key columns are one vector a token
-    that every head shares (the published ``qk_rope_head_dim``: NOT
-    rotated here, ``mla_use_nope``). A query head is ``d_nope + d_shared``
-    wide and the softmax scale is that width's."""
+    """Multi-head latent attention (MLA) over ``cfg.n_heads`` heads: keys
+    and values are expanded from ONE normed latent of ``kv_rank`` a token
+    (``d_nope`` of a key and ``d_v`` of a value a head); ``d_shared``
+    further key columns are one vector a token that every head shares (the
+    published ``qk_rope_head_dim``). A query head is ``d_nope + d_shared``
+    wide and the softmax scale is that width's. ``rope_theta`` > 0: the
+    trailing ``d_shared`` columns of every query head and the shared key
+    part are rotated by position (RoPE of base ``rope_theta``, pairs
+    (i, i + d_shared/2); a checkpoint that rotates the interleaved pairs
+    (2i, 2i + 1) is taken by :func:`mla_from_interleaved`); 0: no
+    positions (``mla_use_nope``)."""
     kv_rank: int
     d_nope: int
     d_shared: int
     d_v: int
+    rope_theta: float = 0.0
 
 
 LAYER_KINDS = ("attn", "gdn", "kda", "mla")
@@ -449,6 +454,38 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     return specs
 
 
+def mla_from_interleaved(params, cfg: TransformerConfig,
+                         inverse: bool = False):
+    """Parameters whose latent attention rotates the interleaved pairs
+    (2i, 2i + 1) of its rotated columns (the DeepSeek-V3 block's
+    checkpoints: ``rope_interleave``) as this file's, which rotate the
+    pairs (i, i + d/2) at the same frequency: the even columns first, then
+    the odd ones, of each query head's trailing ``d_shared`` columns of
+    ``mla_wq`` and of the last ``d_shared`` of ``mla_wkva``. A fixed
+    permutation, so that both compute the same function. ``inverse``: this
+    file's parameters as such a checkpoint."""
+    m = cfg.mla
+    if m is None or not m.rope_theta:
+        return params
+    d, width = m.d_shared, m.d_nope + m.d_shared
+    order = list(range(0, d, 2)) + list(range(1, d, 2))
+    if inverse:
+        order = sorted(range(d), key=order.__getitem__)
+    q_cols = [h * width + j for h in range(cfg.n_heads)
+              for j in [*range(m.d_nope), *(m.d_nope + o for o in order)]]
+    kva_cols = [*range(m.kv_rank), *(m.kv_rank + o for o in order)]
+    layers = []
+    for i, layer in enumerate(params["layers"]):
+        if layer_kind(cfg, i) == "mla":
+            layer = dict(layer,
+                         mla_wq=jnp.take(layer["mla_wq"], jnp.array(q_cols),
+                                         axis=1),
+                         mla_wkva=jnp.take(layer["mla_wkva"],
+                                           jnp.array(kva_cols), axis=1))
+        layers.append(layer)
+    return dict(params, layers=layers)
+
+
 def _rms_norm(x, scale, offset: bool = False, eps: float = 1e-6):
     """RMSNorm, float32 inside; ``offset``: the weight multiplies as
     ``1 + scale`` (``TransformerConfig.norm_offset``)."""
@@ -756,7 +793,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             return dot(out, "kda_wout")
 
     def _mla_mixer(layer, h, extras):
-        """Latent attention without positions: h [B, T, D] -> [B, T, D].
+        """Latent attention: h [B, T, D] -> [B, T, D].
         Never the [T, T] scores: a shape the flash kernels cannot tile is
         an error under ``attn_backend="pallas"``."""
         from ..ops.pallas_attention import flash_attention
@@ -771,9 +808,18 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             kv = dot(_rms_norm(latent[..., :m.kv_rank], layer["mla_kv_norm"],
                                offset, eps), "mla_wkvb").reshape(
                 B, T, H, m.d_nope + m.d_v)
+            shared = latent[:, :, None, m.kv_rank:]
+            if m.rope_theta:
+                # The shared key part is rotated ONCE a token, before it
+                # goes to the heads: its gradient is summed over the heads
+                # first.
+                with jax.named_scope("mla.rope"):
+                    q = jnp.concatenate(
+                        [q[..., :m.d_nope],
+                         _rope(q[..., m.d_nope:], m.rope_theta)], axis=-1)
+                    shared = _rope(shared, m.rope_theta)
             # One shared key part a token, the same for every head.
-            shared = jnp.broadcast_to(latent[:, :, None, m.kv_rank:],
-                                      (B, T, H, m.d_shared))
+            shared = jnp.broadcast_to(shared, (B, T, H, m.d_shared))
             k = jnp.concatenate([kv[..., :m.d_nope], shared], axis=-1)
             v = kv[..., m.d_nope:]
             o = flash_attention(q, k, v, causal=True,
@@ -1396,7 +1442,8 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     bucket a layer, issued when the layer's backward has produced it and
     due before the backward goes on below the layer underneath, so the
     all-reduce runs under that layer's backward
-    (``ops/fusion.reduce_in_backward``; the layers must be of one kind); the embedding, the head and the
+    (``ops/fusion.reduce_in_backward``; the layers must have the same
+    leaves); the embedding, the head and the
     final norm follow after the backward. ``False`` keeps the plan that
     reduces everything after the backward. Where the in-backward plan
     does not apply, ``True`` is PR 6's barrier-chained emission after the
@@ -1431,14 +1478,18 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     # crosses chips and the step is the old one, op for op; ZeRO's
     # reduce-scatter and microbatch accumulation (one exchange per
     # accumulated step) keep the plan that reduces after the backward.
-    # One carry serves every layer's bucket, so the layers must be of one
-    # kind (``ops/fusion.backward_carry``): a model of several kinds keeps
-    # the plan that reduces after the backward.
+    # One carry serves every layer's bucket, so the layers must have the
+    # same leaves (``ops/fusion.backward_carry``): a model of several kinds,
+    # or with leading dense layers before expert layers, keeps the plan
+    # that reduces after the backward.
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
     layer_syncs = plan_grad_sync(
-        jax.tree_util.tree_leaves(
-            specs["layers"][:1], is_leaf=lambda x: isinstance(x, P)), mesh)
+        jax.tree_util.tree_leaves(specs["layers"][:1], is_leaf=is_spec),
+        mesh)
     in_backward = (overlap is not False and not zero and accum_steps == 1
-                   and len(_kinds(cfg)) == 1
+                   and len({jax.tree_util.tree_structure(layer,
+                                                         is_leaf=is_spec)
+                            for layer in specs["layers"]}) == 1
                    and any(mesh.shape[a] > 1
                            for s in layer_syncs for a in s.psum))
     if in_backward:

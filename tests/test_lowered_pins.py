@@ -1,9 +1,10 @@
-"""The lowered training step of the three expert-layer cells, for a
+"""The lowered training step of the four expert-layer cells, for a
 described v5e, hashed by ``benchmarks/lowered_sha.py`` with the kernels'
 debug locations taken out: what PR 37 left, which made the expert layer's
 chunks after the first a loop whose trip count is the load, forward and
 backward, in all three (PR 35 had put the kernels ``conv_silu_fwd`` /
-``conv_silu_bwd`` into the two hybrid cells). (The benchmark's own
+``conv_silu_bwd`` into the two hybrid cells); and PR 38's latent-attention
+cell, whose rotation left the other three as they were. (The benchmark's own
 ``tests/benchmark/test_bench_lowered_steps.py`` pins the four older cells
 to PR 34's programs and is not this PR's to edit: its Qwen3-Next and Keye
 cases are reported as expected by ``tests/conftest.py`` and their guard
@@ -29,7 +30,12 @@ KEYE = {
     "keye_dsa_train_8k_1chip":
         "b4efd8d977d93c9c75142d4f7bee55c8e8c17c329fb06d27239fca76eec0fa7d",
 }
-LOWERED = {**HYBRID, **KEYE}
+# PR 38's cell: latent attention on every layer, its key part rotated.
+LATENT = {
+    "kanana2_mla_train_8k_1chip":
+        "ac137344745623680b1488b0e93a931ba87b2c1cd6d2398ec94e219f138a5bb9",
+}
+LOWERED = {**HYBRID, **KEYE, **LATENT}
 
 
 @pytest.fixture(scope="module")
