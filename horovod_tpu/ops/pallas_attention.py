@@ -13,11 +13,15 @@ keeps its own blockwise accumulation across chips.
 Causal calls pay for no masked score beyond the diagonal's own strips: no
 grid step, no DMA and no arithmetic for a tile pair above the diagonal,
 and of a pair on it only the row strips up to the diagonal
-(``_tile_schedule``). Which schedule runs is chosen from the shape by ONE
-gate (``_fits_vmem``): K/V whole in VMEM with an in-kernel loop over k
-tiles where that fits (the training shapes), K/V tiles streamed over a
-grid axis clamped at the diagonal where it does not, and the fused or the
-split backward.
+(``_tile_schedule``). Which schedule runs, and the scoped VMEM its kernel
+asks for, is chosen from the shape by ONE gate (``_plan``): K/V whole in
+VMEM with an in-kernel loop over k tiles where that fits, K/V tiles
+streamed over a grid axis clamped at the diagonal where it does not, and
+the fused or the split backward. The gate has two rungs: the compiler's
+default 16 MiB, and only where that refuses the whole-sequence residents
+the 64 MiB every other Pallas kernel here asks for (T 8192 at a width of
+256); ``hvd_flash_bwd_plan_total{plan=, grant=}`` counts the backward's
+traces by both.
 
 Off-TPU (CPU tests) the kernels run in interpreter mode, bit-matching the
 compiled path's math. `flash_attention` falls back to plain XLA attention
@@ -38,7 +42,6 @@ Speeds quoted in this file are of one machine and one day each: PERF.md
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -278,7 +281,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     in a [b, D] scratch; dk/dv accumulate over the whole (batch, head)
     visit in full-T [T, D] f32 scratch and flush at its last step. That
     costs 2·T·D f32 of VMEM, so callers fall back to the split kernels
-    when ``_fits_vmem`` says so. ``packed``: the single output block is
+    when ``_plan`` says so. ``packed``: the single output block is
     head h's column stripe ``[1, T, 3D]`` (q|k|v) of the packed gradient,
     resident for the whole visit, so the gradient exists in exactly one
     materialization. Not ``fused``: the split path's dq kernel."""
@@ -375,27 +378,31 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-# Scoped VMEM one kernel may claim. 16 MiB is the v5e compiler's own
-# default scoped limit; the kernels with whole-sequence residents also
-# pass this number as their ``vmem_limit_bytes``, so the gate below and
-# the compiler hold the same limit (an override moves both). Read once at
-# import so every rank traces the same graph — a trace-time env read could
-# diverge across ranks, and would not retrace a cached function.
-_VMEM_BUDGET_BYTES = int(os.environ.get("HVD_VMEM_BUDGET_MB", "16")) * 2**20
+# Scoped VMEM a kernel with whole-sequence residents may claim, in two
+# rungs: the v5e compiler's own default, and the limit every other Pallas
+# kernel of the package asks for (of the chip's 128 MiB). Such a kernel
+# passes the rung its sum fits as ``vmem_limit_bytes``, so the gate and the
+# compiler hold the same number.
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
-def _fits_vmem(T: int, D: int, itemsize: int, *, b: int, bwd: bool,
-               kv_resident: bool, packed: bool = False) -> bool:
-    """Whether a kernel with whole-sequence residents fits its scoped VMEM
-    limit — the ONE gate that picks the schedule from the shape: K/V
-    resident (in-kernel loop) or streamed for the forward (``bwd=False``)
-    and for the fused backward, and fused or split backward.
+def _vmem_need(T: int, D: int, itemsize: int, *, b: int, bwd: bool,
+               kv_resident: bool, packed: bool = False) -> int:
+    """Bytes of scoped VMEM a kernel with whole-sequence residents needs:
+    the forward (``bwd=False``) or the fused backward, K/V resident
+    (in-kernel loop) or streamed.
 
-    The sum is what the v5e compiler allocates, checked against it at
-    H=16 for D in {128, 256}, bf16 and f32, T = 128..16384 (the smallest
-    ``vmem_limit_bytes`` that compiles sits within the margin of this
-    sum; ``tests/test_tpu_compile.py`` keeps the near-boundary shapes
-    compiling):
+    The sum bounds what the v5e compiler allocates from above: the
+    smallest ``vmem_limit_bytes`` that compiles sat under it at H=16 for D
+    in {128, 256}, bf16 and f32, T = 128..16384 near the default rung,
+    and near the 64 MiB rung (bisected to 0.25 MiB for both directions
+    and every schedule, D 128 and 256, bf16 and f32, T 1024..32768, both
+    layouts) by 0.25-4.25 MiB, and by 6.25-26.5 MiB where v is narrower
+    than q and k or the backward's K/V are resident in the [BH, T, D]
+    layout (the latent cells' backward: 29.0 against 55.5).
+    ``tests/test_tpu_compile.py`` keeps the near-boundary shapes
+    compiling:
 
     * scratch, f32: forward acc [b, D] + two stat tiles; fused backward
       dq_acc [b, D] + the dk/dv accumulators 2×[T, D];
@@ -420,18 +427,39 @@ def _fits_vmem(T: int, D: int, itemsize: int, *, b: int, bwd: bool,
     else:
         scratch = 4 * b * D + 2 * stat
         piped = 2 * tile + stat + kv
-    return scratch + 2 * piped + stack <= _VMEM_BUDGET_BYTES
+    return scratch + 2 * piped + stack
 
 
-def _bwd_plan(T: int, D: int, itemsize: int, *, b: int, packed: bool) -> str:
-    """Which backward a shape gets: the fused kernel with K/V ``resident``,
-    the fused kernel with K/V ``streamed``, or the ``split`` dq and dkv
-    kernels (long sequences)."""
-    fits = functools.partial(_fits_vmem, T, D, itemsize, b=b, bwd=True,
-                             packed=packed)
-    if fits(kv_resident=True):
-        return "resident"
-    return "streamed" if fits(kv_resident=False) else "split"
+def _plan(T: int, D: int, itemsize: int, *, b: int, bwd: bool,
+          packed: bool = False) -> tuple[str, Optional[int]]:
+    """-> (schedule, ``vmem_limit_bytes``): the ONE gate that picks both
+    from the shape. The forward is ``resident`` or ``streamed``; the
+    backward the fused kernel with K/V ``resident``, the fused kernel with
+    K/V ``streamed``, or the ``split`` dq and dkv kernels.
+
+    The first rung whose limit holds a schedule's sum wins, resident before
+    streamed within a rung: every shape the compiler's default holds keeps
+    the schedule and the limit it had with that rung alone, and the 64 MiB
+    rung serves only shapes the default refuses. The streamed forward and
+    the split backward keep tiles alone in VMEM and ask for no limit."""
+    kinds = (("resident", True), ("streamed", False)) if bwd \
+        else (("resident", True),)
+    for limit in (_VMEM_DEFAULT, _VMEM_LIMIT):
+        for plan, kv_resident in kinds:
+            if _vmem_need(T, D, itemsize, b=b, bwd=bwd,
+                          kv_resident=kv_resident, packed=packed) <= limit:
+                return plan, limit
+    return ("split" if bwd else "streamed"), None
+
+
+def _plan_counter():
+    from ..obs.registry import registry
+    return registry().counter(
+        "hvd_flash_bwd_plan_total",
+        "traces of the flash backward, by the kernels its shape got "
+        "(resident, streamed: flash_bwd; split: flash_bwd_dq + "
+        "flash_bwd_dkv) and the scoped VMEM they asked for (default: the "
+        "compiler's 16 MiB; 64MiB)", labels=("plan", "grant"))
 
 
 # Lane width of the per-row stat tensors (lse, delta) on the wire between
@@ -536,8 +564,8 @@ def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
     b, sub = _blocks(T)
     lead = q.shape[0]
     n_heads = lead if lay.H is None else lead * lay.H
-    resident = _fits_vmem(T, lay.gate_d, q.dtype.itemsize, b=b, bwd=False,
-                          kv_resident=True)
+    plan, vmem = _plan(T, lay.gate_d, q.dtype.itemsize, b=b, bwd=False)
+    resident = plan == "resident"
     if resident:
         grid = lay.grid(lead) + (T // b,)
         kv_specs = [lay.spec(x, T, lambda qi: 0) for x in "kv"]
@@ -566,8 +594,7 @@ def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
             pltpu.VMEM((b, 128), jnp.float32),          # running max
             pltpu.VMEM((b, 128), jnp.float32),          # running sum
         ],
-        compiler_params=_grid_params(
-            semantics, _VMEM_BUDGET_BYTES if resident else None),
+        compiler_params=_grid_params(semantics, vmem),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -580,7 +607,7 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
          q_scale: float, grad_scale: float, interpret: bool):
     """Gradients of the three inputs: one packed [B, T, H*3*D] array from
     the fused kernel in the packed layout, else (dq, dk, dv) shaped like
-    ``o``, by the kernels ``_bwd_plan`` picks."""
+    ``o``, by the kernels ``_plan`` picks."""
     lay, T = _layout_of(q, H, None if H is not None else v)
     D, Dv, packed = lay.D, lay.Dv, H is not None
     b, sub = _blocks(T)
@@ -594,7 +621,10 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
         delta = prod.sum(-1)
     delta = jnp.broadcast_to(delta.reshape(-1, T, 1),
                              (lse.shape[0], T, _STAT_LANES))
-    plan = _bwd_plan(T, lay.gate_d, q.dtype.itemsize, b=b, packed=packed)
+    plan, vmem = _plan(T, lay.gate_d, q.dtype.itemsize, b=b, bwd=True,
+                       packed=packed)
+    _plan_counter().labels(
+        plan=plan, grant="64MiB" if vmem == _VMEM_LIMIT else "default").inc()
     resident, fused = plan == "resident", plan != "split"
     kernel = functools.partial(
         _bwd_kernel, causal=causal, b=b, sub=sub, resident=resident,
@@ -632,7 +662,7 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
             out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=[dq_acc, pltpu.VMEM((T, D), jnp.float32),
                             pltpu.VMEM((T, Dv), jnp.float32)],
-            compiler_params=_grid_params(semantics, _VMEM_BUDGET_BYTES),
+            compiler_params=_grid_params(semantics, vmem),
             interpret=interpret, name="flash_bwd",
         )(q, k, v, do, lse, delta)
     dq = pl.pallas_call(

@@ -42,8 +42,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import _grid_params
-from .pallas_gated_delta import _VMEM_LIMIT, _traced_once
+from .pallas_attention import _VMEM_LIMIT, _grid_params
+from .pallas_gated_delta import _traced_once
 
 _HALO = 16      # rows of the blocks before and after: a packed bf16 tile
 _TAPS = 8       # taps at most: the shifts come out of ONE 8-row group

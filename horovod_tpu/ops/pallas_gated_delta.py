@@ -45,10 +45,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import kda_tile
-from .pallas_attention import _dot, _grid_params
+from .pallas_attention import _VMEM_LIMIT, _dot, _grid_params
 
 _BLOCK = 8           # chunks worked in one grid step, at most
-_VMEM_LIMIT = 64 * 2 ** 20
 
 
 def _interpret() -> bool:

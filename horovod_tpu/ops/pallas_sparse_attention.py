@@ -37,11 +37,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import _dot, _grid_params, _lanes
+from .pallas_attention import _VMEM_LIMIT, _dot, _grid_params, _lanes
 
 _TQ, _TK = 256, 512
 _STAT_LANES = 8          # lse / delta: one lane per head of the group
-_VMEM_LIMIT = 64 * 2 ** 20
 # Width of the strips the fused backward cuts the DIAGONAL k tile into (a
 # q tile sees the first strip of its diagonal tile, or both). Measured on
 # a v5e (PERF.md PR 29, the kernel alone at the Keye shape): 256 -> 15.38

@@ -70,7 +70,9 @@ def pytest_configure(config):
 # kernels conv_silu_fwd / conv_silu_bwd); tests/test_lowered_pins.py pins
 # that cell's new hash, and the Kimi cell's. PR 37 MEANT to change the Keye
 # cell's (the expert layer's chunks after the first became a loop whose trip
-# count is the load) and pins its new hash there too. The two LM cases hold.
+# count is the load) and pins its new hash there too; the fused flash
+# backward at T 8192 changed the Qwen3-Next cell's again. The two LM cases
+# hold.
 _EXPECTED = (
     ("tests/benchmark/test_bench_gdn_stages.py::"
      "test_entries_in_the_manifest", "[-2:] ==",

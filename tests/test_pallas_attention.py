@@ -68,26 +68,29 @@ def _force_plan(monkeypatch, pa, plan):
     grid axis, fused backward) or ``split`` (streamed, dq + dkv kernels).
     The [B,T,H,D] entry is jitted, so traces made under another plan are
     dropped first."""
-    def fits(T, D, itemsize, *, b, bwd, kv_resident, packed=False):
+    def forced(T, D, itemsize, *, b, bwd, packed=False):
+        if bwd:
+            return plan, None if plan == "split" else pa._VMEM_DEFAULT
         if plan == "resident":
-            return kv_resident
-        return plan == "streamed" and bwd and not kv_resident
-    monkeypatch.setattr(pa, "_fits_vmem", fits)
+            return plan, pa._VMEM_DEFAULT
+        return "streamed", None
+    monkeypatch.setattr(pa, "_plan", forced)
     jax.clear_caches()
 
 
-def _dense_packed(pa, qkv, H, causal):
-    B, T, cols = qkv.shape
-    D = cols // (3 * H)
-    r = qkv.reshape(B, T, H, 3, D)
-    return pa._xla_attention(r[..., 0, :], r[..., 1, :], r[..., 2, :],
-                             causal, D ** -0.5).reshape(B, T, H * D)
+def _plan_count(pa, plan):
+    return pa._plan_counter().labels(plan=plan, grant="default").value
 
 
 # (T, preferred tile, diagonal strip): patched down so that every T has a
 # pair above the diagonal (never visited), plain pairs and diagonal pairs,
 # and both strip heights occur (128: whole-tile and 2 strips; 256: 2 strips).
 _SCHEDULE_SHAPES = [(256, 128, 128), (512, 256, 128), (1024, 512, 256)]
+# The widths of q/k and of v each entry takes: latent attention's heads
+# (192 against 128) enter the [B, T, H, D] entry padded to 256, and the
+# fused backward then writes dq, dk and dv as three outputs of two widths.
+_ENTRY_WIDTHS = {"packed": (128, 128), "bthd": (128, 128),
+                 "bthd-qk192-v128": (192, 128)}
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
@@ -95,20 +98,27 @@ _SCHEDULE_SHAPES = [(256, 128, 128), (512, 256, 128), (1024, 512, 256)]
                          ids=[f"T{t}-b{w}-s{u}" for t, w, u in
                               _SCHEDULE_SHAPES])
 @pytest.mark.parametrize("plan", ["resident", "streamed", "split"])
-@pytest.mark.parametrize("entry", ["packed", "bthd"])
+@pytest.mark.parametrize("entry", list(_ENTRY_WIDTHS))
 def test_tile_schedule_matches_dense(monkeypatch, entry, plan, T, want, sub,
                                      causal):
-    """Output and gradients of every schedule the gate can pick, through
-    both entry points, against dense attention at the module's standing
-    tolerances."""
+    """Output and the three gradients of every schedule the gate can pick,
+    through both entry points and at a q/k width of its own, against dense
+    attention at the module's standing tolerances; one tick of the plan
+    counter a trace of the backward."""
     import horovod_tpu.ops.pallas_attention as pa
     monkeypatch.setattr(pa, "_WANT_BLOCK", want)
     monkeypatch.setattr(pa, "_DIAG_SUB", sub)
     _force_plan(monkeypatch, pa, plan)
-    B, H, D = 1, 2, 128
+    B, H = 1, 2
+    dk, dv = _ENTRY_WIDTHS[entry]
     rng = np.random.RandomState(T + want)
-    qkv = jnp.asarray(rng.randn(B, T, H * 3 * D), jnp.float32) * 0.5
-    cot = jnp.asarray(rng.randn(B, T, H * D), jnp.float32)
+    # One array holds q, k and v, head-major: (q | k | v) columns a head.
+    qkv = jnp.asarray(rng.randn(B, T, H * (2 * dk + dv)), jnp.float32) * 0.5
+    cot = jnp.asarray(rng.randn(B, T, H * dv), jnp.float32)
+
+    def split(x):
+        r = x.reshape(B, T, H, 2 * dk + dv)
+        return r[..., :dk], r[..., dk:2 * dk], r[..., 2 * dk:]
 
     if entry == "packed":
         def kern(x):
@@ -116,21 +126,52 @@ def test_tile_schedule_matches_dense(monkeypatch, entry, plan, T, want, sub,
                                           interpret=True)
     else:
         def kern(x):
-            r = x.reshape(B, T, H, 3, D)
             return pa.flash_attention(
-                r[..., 0, :], r[..., 1, :], r[..., 2, :], causal=causal,
-                backend="pallas", interpret=True).reshape(B, T, H * D)
+                *split(x), causal=causal, backend="pallas",
+                interpret=True, fallback=False).reshape(B, T, H * dv)
 
     def dense(x):
-        return _dense_packed(pa, x, H, causal)
+        return pa._xla_attention(*split(x), causal, dk ** -0.5).reshape(
+            B, T, H * dv)
 
     np.testing.assert_allclose(np.asarray(kern(qkv)), np.asarray(dense(qkv)),
                                rtol=2e-4, atol=2e-5)
+    before = _plan_count(pa, plan)
     got = jax.grad(lambda x: jnp.sum(kern(x) * cot))(qkv)
+    assert _plan_count(pa, plan) == before + 1
     want_g = jax.grad(lambda x: jnp.sum(dense(x) * cot))(qkv)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want_g),
-                               rtol=2e-3, atol=2e-4)
+    for g, w, name in zip(split(got), split(want_g), "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-3, atol=2e-4, err_msg="d" + name)
     jax.clear_caches()      # leave no trace made under the pinned plan
+
+
+@pytest.mark.parametrize("T,dk,dv,plan,grant", [
+    (2048, 128, 128, "resident", "default"),    # the dense LM cells
+    (8192, 192, 128, "resident", "64MiB"),      # the latent-attention layers
+    (8192, 256, 256, "resident", "64MiB"),      # Qwen3-Next's attention
+    (32768, 128, 128, "split", "default"),
+], ids=["T2048", "T8192-qk192-v128", "T8192-d256", "T32768"])
+def test_plan_counter_ticks_by_schedule_and_grant(T, dk, dv, plan, grant):
+    """``hvd_flash_bwd_plan_total`` counts a trace of the backward under
+    the schedule the gate gave the shape and the VMEM rung it asked for;
+    tracing the gradient's shapes is enough, nothing runs."""
+    import horovod_tpu.ops.pallas_attention as pa
+    jax.clear_caches()
+    counter = pa._plan_counter().labels(plan=plan, grant=grant)
+    before = counter.value
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, backend="pallas", interpret=True,
+            fallback=False).astype(jnp.float32))
+
+    def shape(d):
+        return jax.ShapeDtypeStruct((1, T, 2, d), jnp.bfloat16)
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), shape(dk), shape(dk),
+                   shape(dv))
+    assert counter.value == before + 1
+    jax.clear_caches()
 
 
 @pytest.mark.parametrize("b,sub", [(128, 128), (256, 128), (512, 128),

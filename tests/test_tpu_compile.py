@@ -63,37 +63,40 @@ def _n_kernels(fn, *shapes):
             "tpu_custom_call")
 
 
-def _bwd_plan(T, dtype, packed, d=D):
-    """The backward the GATE picks (resident | streamed | split). A shape
-    the gate admits and the compiler refuses fails the compile, not this
-    function."""
-    return pa._bwd_plan(T, d, jnp.dtype(dtype).itemsize,
-                        b=pa._pick_block(T, pa._WANT_BLOCK), packed=packed)
+def _plan(T, dtype, packed=False, d=D, bwd=True):
+    """(schedule, vmem_limit_bytes) the GATE picks: resident | streamed |
+    split backward, resident | streamed forward. A shape the gate admits
+    and the compiler refuses fails the compile, not this function."""
+    return pa._plan(T, d, jnp.dtype(dtype).itemsize,
+                    b=pa._pick_block(T, pa._WANT_BLOCK), bwd=bwd,
+                    packed=packed)
 
 
 def _expected_kernels(T, dtype, packed, d=D):
     """Forward + fused backward = 2 kernels; forward + split dq/dkv = 3."""
-    return 3 if _bwd_plan(T, dtype, packed, d) == "split" else 2
+    return 3 if _plan(T, dtype, packed, d)[0] == "split" else 2
 
 
 # (B, T, dtype, d_head): the bench shape; then, for each dtype and head
-# size, the shapes on both sides of the gate's two boundaries (resident |
-# streamed | split backward; resident | streamed forward) — among them the
-# shapes the compiler refused the fused kernel for before the gate was
-# corrected (T=8192 bf16, T=4096 f32) and a long split shape.
+# size, shapes on both sides of the gate's boundaries: resident | streamed
+# fused backward under the compiler's default 16 MiB, the same two under
+# 64 MiB, split beyond; resident forward under either rung, streamed
+# beyond. Comments: forward, backward, and the rung (16 or 64 MiB).
 _QKV_CASES = [
-    (8, 2048, jnp.bfloat16, 128),       # resident, resident
-    (2, 4096, jnp.bfloat16, 128),       # resident forward, streamed fused
-    (2, 8192, jnp.bfloat16, 128),       # resident forward, split
-    (1, 16384, jnp.bfloat16, 128),      # streamed forward, split
-    (2, 1024, jnp.float32, 128),        # resident, resident
-    (2, 2048, jnp.float32, 128),        # resident forward, streamed fused
-    (2, 4096, jnp.float32, 128),        # resident forward, split
-    (1, 8192, jnp.float32, 128),        # streamed forward, split
-    (2, 1024, jnp.bfloat16, 256),       # resident, resident
-    (2, 2048, jnp.bfloat16, 256),       # resident forward, split
-    (1, 8192, jnp.bfloat16, 256),       # streamed forward, split
-    (2, 1024, jnp.float32, 256),        # resident forward, split
+    (8, 2048, jnp.bfloat16, 128),       # resident, resident; 16
+    (2, 4096, jnp.bfloat16, 128),       # resident, streamed fused; 16
+    (2, 8192, jnp.bfloat16, 128),       # resident 16, resident 64
+    (1, 16384, jnp.bfloat16, 128),      # resident, resident; 64 (60.8 MiB)
+    (2, 1024, jnp.float32, 128),        # resident, resident; 16
+    (2, 2048, jnp.float32, 128),        # resident, streamed fused; 16
+    (2, 4096, jnp.float32, 128),        # resident 16, resident 64
+    (1, 8192, jnp.float32, 128),        # resident, resident; 64
+    (2, 1024, jnp.bfloat16, 256),       # resident, resident; 16
+    (2, 2048, jnp.bfloat16, 256),       # resident 16, resident 64
+    (1, 8192, jnp.bfloat16, 256),       # resident, resident; 64 (63 MiB)
+    (2, 1024, jnp.float32, 256),        # resident 16, resident 64
+    (1, 20480, jnp.bfloat16, 128),      # resident 64, streamed 64 (55 MiB)
+    (1, 24576, jnp.bfloat16, 128),      # resident 64, split (65 MiB wanted)
 ]
 
 
@@ -114,7 +117,7 @@ def test_flash_attention_qkv_fwd_bwd_compiles(one_chip, B, T, dtype, d):
 
 @pytest.mark.parametrize("T,backward", [
     (2048, ("flash_bwd",)),                           # fused
-    (8192, ("flash_bwd_dq", "flash_bwd_dkv")),        # split
+    (32768, ("flash_bwd_dq", "flash_bwd_dkv")),       # split: 85 MiB wanted
 ], ids=["fused", "split"])
 def test_flash_kernels_carry_their_names(one_chip, T, backward):
     """``pallas_call(name=)`` becomes the compiled instruction's name and a
@@ -122,7 +125,7 @@ def test_flash_kernels_carry_their_names(one_chip, T, backward):
     by (benchmarks/layer_metrics/device_scopes.py). The forward and the
     backward are jitted by themselves (one trace for all layers), which
     adds a ``jit(_fwd)`` / ``jit(_bwd)`` component under the scope."""
-    qkv = jax.ShapeDtypeStruct((2, T, H * 3 * D), jnp.bfloat16,
+    qkv = jax.ShapeDtypeStruct((1, T, H * 3 * D), jnp.bfloat16,
                                sharding=one_chip)
 
     def loss(x):
@@ -144,10 +147,16 @@ def test_flash_kernels_carry_their_names(one_chip, T, backward):
                    in ln for ln in kernels), name
 
 
-@pytest.mark.parametrize("B,T", [(8, 2048), (2, 8192)],
-                         ids=["B8-T2048", "B2-T8192"])
-def test_flash_attention_pallas_backend_fwd_bwd_compiles(one_chip, B, T):
-    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+@pytest.mark.parametrize("B,T,h,d", [
+    (8, 2048, H, D), (2, 8192, H, D),
+    (2, 8192, 16, 256),         # the Qwen3-Next cell's attention layer
+], ids=["B8-T2048", "B2-T8192", "qwen3next_8k"])
+def test_flash_attention_pallas_backend_fwd_bwd_compiles(one_chip, B, T, h,
+                                                         d):
+    """The [B, T, H, D] entry: ``flash_fwd`` and the fused ``flash_bwd``
+    alone, the 8k shapes under the gate's 64 MiB rung (the latent layers'
+    192 / 128: ``test_flash_kernels_compile_at_a_key_width_of_their_own``)."""
+    x = jax.ShapeDtypeStruct((B, T, h, d), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
         return jnp.sum(pa.flash_attention(
@@ -155,8 +164,7 @@ def test_flash_attention_pallas_backend_fwd_bwd_compiles(one_chip, B, T):
             interpret=False).astype(jnp.float32))
 
     assert _n_kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                      x, x, x) == \
-        _expected_kernels(T, jnp.bfloat16, packed=False)
+                      x, x, x) == 2
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -176,13 +184,16 @@ def test_paged_decode_kernel_compiles(one_chip, dtype):
         sds((S,), jnp.int32)) == 1
 
 
-@pytest.mark.parametrize("T,causal", [(2048, True), (8192, True),
-                                      (16384, True), (2048, False)],
-                         ids=["T2048", "T8192", "T16384", "T2048-full"])
-def test_flash_forward_only_compiles(one_chip, T, causal):
+@pytest.mark.parametrize("T,causal,dtype", [
+    (2048, True, jnp.bfloat16), (8192, True, jnp.bfloat16),
+    (16384, True, jnp.bfloat16), (2048, False, jnp.bfloat16),
+    (32768, True, jnp.float32),
+], ids=["T2048", "T8192", "T16384", "T2048-full", "T32768-float32"])
+def test_flash_forward_only_compiles(one_chip, T, causal, dtype):
     """The no-grad primal (no lse output) — the serving prefill's call —
-    with K/V resident (to T=8192 in bf16) and streamed (beyond)."""
-    x = jax.ShapeDtypeStruct((1, T, H, D), jnp.bfloat16, sharding=one_chip)
+    with K/V resident under the compiler's default (to T=8192 in bf16),
+    resident under 64 MiB (T=16384) and streamed (beyond that rung)."""
+    x = jax.ShapeDtypeStruct((1, T, H, D), dtype, sharding=one_chip)
 
     def fwd(q, k, v):
         return pa.flash_attention(q, k, v, causal=causal, backend="pallas",
@@ -191,34 +202,41 @@ def test_flash_forward_only_compiles(one_chip, T, causal):
     assert _n_kernels(fwd, x, x, x) == 1
 
 
-@pytest.mark.parametrize("T,dtype,packed,plan", [
-    (2048, jnp.bfloat16, True, "resident"),    # the bench shape
-    (4096, jnp.bfloat16, True, "streamed"),    # still fused
-    (8192, jnp.bfloat16, True, "split"),       # 25 MiB wanted, 16 granted
-    (8192, jnp.bfloat16, False, "split"),
-    (4096, jnp.float32, True, "split"),
-    (4096, jnp.float32, False, "split"),
-    (4096, jnp.bfloat16, False, "streamed"),
-    (2048, jnp.float32, True, "streamed"),
-    (1024, jnp.float32, True, "resident"),
-], ids=lambda v: getattr(v, "__name__", str(v)))
-def test_fused_backward_gate(T, dtype, packed, plan):
-    """The gate's verdicts at D=128 (no compiler needed): what the v5e
-    compiler was measured to accept within its 16 MiB scoped VMEM."""
-    assert _bwd_plan(T, dtype, packed) == plan
+_16, _64 = 16 * 2 ** 20, 64 * 2 ** 20
 
 
-@pytest.mark.parametrize("T,dtype,resident", [
-    (8192, jnp.bfloat16, True),
-    (16384, jnp.bfloat16, False),
-    (4096, jnp.float32, True),
-    (8192, jnp.float32, False),
+@pytest.mark.parametrize("T,dtype,packed,d,plan,grant", [
+    (2048, jnp.bfloat16, True, D, "resident", _16),   # the bench shape
+    (4096, jnp.bfloat16, True, D, "streamed", _16),   # still the default's
+    (8192, jnp.bfloat16, True, D, "resident", _64),   # 33 MiB wanted
+    (8192, jnp.bfloat16, False, D, "resident", _64),
+    (4096, jnp.float32, True, D, "resident", _64),
+    (4096, jnp.float32, False, D, "resident", _64),
+    (4096, jnp.bfloat16, False, D, "streamed", _16),
+    (2048, jnp.float32, True, D, "streamed", _16),
+    (1024, jnp.float32, True, D, "resident", _16),
+    (8192, jnp.bfloat16, False, 256, "resident", _64),  # the 8k cells
+    (16384, jnp.bfloat16, True, D, "resident", _64),  # 61 MiB: the last
+    (20480, jnp.bfloat16, True, D, "streamed", _64),  # 75 resident, 55
+    (24576, jnp.bfloat16, True, D, "split", None),    # 65 MiB streamed
 ], ids=lambda v: getattr(v, "__name__", str(v)))
-def test_forward_residency_gate(T, dtype, resident):
+def test_fused_backward_gate(T, dtype, packed, d, plan, grant):
+    """The gate's verdicts (no compiler needed): what the v5e compiler was
+    measured to accept within its default 16 MiB of scoped VMEM, then
+    within the 64 MiB rung, for shapes the default refuses."""
+    assert _plan(T, dtype, packed, d) == (plan, grant)
+
+
+@pytest.mark.parametrize("T,dtype,plan,grant", [
+    (8192, jnp.bfloat16, "resident", _16),
+    (16384, jnp.bfloat16, "resident", _64),
+    (4096, jnp.float32, "resident", _16),
+    (8192, jnp.float32, "resident", _64),
+    (32768, jnp.float32, "streamed", None),     # 69 MiB wanted
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_forward_residency_gate(T, dtype, plan, grant):
     """The same gate for the forward: K and V whole in VMEM or streamed."""
-    assert pa._fits_vmem(T, D, jnp.dtype(dtype).itemsize,
-                         b=pa._pick_block(T, pa._WANT_BLOCK), bwd=False,
-                         kv_resident=True) is resident
+    assert _plan(T, dtype, bwd=False) == (plan, grant)
 
 
 _FUSED, _SPLIT = ["dsa_bwd", "dsa_fwd"], ["dsa_bwd_dkv", "dsa_bwd_dq", "dsa_fwd"]
@@ -343,7 +361,8 @@ def test_per_channel_delta_kernels_compile_and_carry_their_names(
 @pytest.mark.parametrize("T", [2048, 8192])
 def test_flash_kernels_compile_at_a_key_width_of_their_own(one_chip, T):
     """Latent attention's heads: 192 wide for q and k (padded to 256 on
-    entry), 128 for v; the forward and the backward the gate picks."""
+    entry), 128 for v; the forward and the fused backward, under the
+    compiler's default at T 2048 and the 64 MiB rung at 8192."""
     def shape(d):
         return jax.ShapeDtypeStruct((1, T, 32, d), jnp.bfloat16,
                                     sharding=one_chip)
@@ -357,8 +376,7 @@ def test_flash_kernels_compile_at_a_key_width_of_their_own(one_chip, T):
             shape(192), shape(192), shape(128)).compile().as_text()
     names = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
              for ln in text.splitlines() if "tpu_custom_call" in ln}
-    assert "flash_fwd" in names and any(n.startswith("flash_bwd")
-                                        for n in names)
+    assert names == {"flash_fwd", "flash_bwd"}
     assert f"[32,{T},{T}]" not in text
 
 
