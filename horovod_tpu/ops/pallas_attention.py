@@ -72,9 +72,13 @@ def _pick_block(t: int, want: int) -> int:
     return b
 
 
-def _blocks(T: int):
-    """(tile side, diagonal strip height) for sequence length T."""
-    b = _pick_block(T, _WANT_BLOCK)
+def _blocks(T: int, window: Optional[int] = None):
+    """(tile side, diagonal strip height) for sequence length T. A window
+    caps the tile at its largest power of two, so that no score of a
+    diagonal tile falls out of the band."""
+    want = _WANT_BLOCK if window is None \
+        else 1 << (min(window, _WANT_BLOCK).bit_length() - 1)
+    b = _pick_block(T, want)
     return b, min(b, _DIAG_SUB)
 
 
@@ -106,6 +110,63 @@ def _tile_schedule(b: int, sub: int, diagonal: bool):
     return [(i * sub, sub, (i + 1) * sub, True) for i in range(b // sub)]
 
 
+# ---------------------------------------------------------------------------
+# The banded (sliding-window) schedule. Query i sees key j iff
+# i - window < j <= i. With tiles of side b <= window (``_blocks``), q tile
+# qi meets the k tiles qi - d for d = 0 (the diagonal pair, cut as above),
+# d = 1..plain (whole and unmasked) and d in ``edges`` (the band's left
+# edge: one [b, b] block masked by a second static triangle, d·b + row -
+# col < window). No tile further left is fetched, computed or masked.
+# ---------------------------------------------------------------------------
+
+
+def _band(b: int, window: int, n_t: int):
+    """-> (last, plain, edges, window) of the band over ``n_t`` tiles: the
+    largest distance d = qi - kb of a visited pair, the largest one whose
+    pair is whole in the band, and the distances of the pairs the band's
+    edge cuts. The kernels take it whole (static)."""
+    last = min(-(-(window - 1) // b), n_t - 1)
+    plain = min(window // b - 1, last)
+    return last, plain, tuple(range(plain + 1, last + 1)), window
+
+
+def _pair_blocks(b: int, sub: int, d: Optional[int], window=None):
+    """The blocks of the pair at distance ``d`` (None: any pair under the
+    diagonal that is whole) as ``[(row0, rows, cols, masked, band), ...]``:
+    ``_tile_schedule``'s, and ``band`` = (shift, window) where the band's
+    edge cuts the pair (keep d·b + row - col < window)."""
+    if d is None or d == 0:
+        return [s + (None,) for s in _tile_schedule(b, sub, d == 0)]
+    return [(0, b, b, False, (d * b, window))]
+
+
+def tile_pairs(T: int, window: Optional[int] = None):
+    """(tile pairs the kernels visit, causal tile pairs at the same tile
+    side) of one (batch, head) at length T, from the schedule itself."""
+    b, _ = _blocks(T, window)
+    n = T // b
+    causal = n * (n + 1) // 2
+    if window is None or window >= T:
+        return causal, causal
+    last = _band(b, window, n)[0]
+    return sum(min(qi, last) + 1 for qi in range(n)), causal
+
+
+def record_tile_pairs(layer, T: int, window: Optional[int]) -> None:
+    """Stamp ``hvd_swa_tile_pairs{layer, kind}`` for one windowed layer's
+    call (trace time: shapes): the tile pairs its kernels visit a (batch,
+    head), and the causal kernels' at the same length."""
+    from ..obs.registry import registry
+    gauge = registry().gauge(
+        "hvd_swa_tile_pairs",
+        "tile pairs a windowed layer's flash kernels visit (kind=visited) "
+        "and the causal kernels' (kind=causal), one (batch, head), from "
+        "the schedule at trace time", labels=("layer", "kind"))
+    visited, causal = tile_pairs(T, window)
+    gauge.labels(layer=str(layer), kind="visited").set(visited)
+    gauge.labels(layer=str(layer), kind="causal").set(causal)
+
+
 def _dot(a, b, ca: int, cb: int):
     """MXU matmul contracting a's dim ``ca`` with b's ``cb`` in the input
     dtype (bf16 passes), f32 accumulation."""
@@ -113,17 +174,24 @@ def _dot(a, b, ca: int, cb: int):
                                preferred_element_type=jnp.float32)
 
 
-def _scores(q, k, masked: bool):
+def _scores(q, k, masked: bool, band=None):
     """Log2-domain score block [rows, cols] (q arrives scaled), shared by
     the forward and every backward kernel so the mask cannot
     desynchronize. A masked block is a diagonal strip: its LAST row sees
-    all its columns, so the mask is a static triangle."""
+    all its columns, so the mask is a static triangle. ``band`` = (shift,
+    window): a pair the window's left edge cuts, whose row r keeps the
+    columns c with shift + r - c < window, another static triangle."""
     s = _dot(q, k, 1, 1)
     if masked:
         rows, cols = s.shape
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(row + (cols - rows) >= col, s, -1e30)
+    if band is not None:
+        shift, window = band
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(row + (shift - window) < col, s, -1e30)
     return s
 
 
@@ -145,14 +213,14 @@ def _lanes(x, width: int):
     return jnp.tile(x, (1, width // 128))
 
 
-def _softmax_update(q, k, v, masked, acc_ref, m_ref, l_ref, rows):
+def _softmax_update(q, k, v, masked, band, acc_ref, m_ref, l_ref, rows):
     """One online-softmax step of the state rows ``rows`` (a static slice)
     for the q rows ``q`` against one K/V block. The running max and sum
     are kept REPLICATED over their 128 lanes: a [rows, 1] column costs as
     many vregs, and every use of it against a [rows, cols] block would pay
     a lane broadcast (measured on a v5e, PERF.md PR 27: the forward kernel
     2.31 -> 1.43 ms against lane-0 stats, strips of 128)."""
-    s = _scores(q, k, masked)
+    s = _scores(q, k, masked, band)
     m_prev = m_ref[rows, :]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp2(m_prev - m_new)
@@ -164,19 +232,25 @@ def _softmax_update(q, k, v, masked, acc_ref, m_ref, l_ref, rows):
     m_ref[rows, :] = m_new
 
 
-def _for_each_pair(k_ref, v_ref, *, b: int, causal: bool, resident: bool,
-                   begin, visit, end):
+def _for_each_pair(k_ref, v_ref, *, b: int, sub: int, causal: bool,
+                   resident: bool, begin, visit, end, band=None):
     """The pairs of q tile ``program_id(2)``, in k order: ``begin()``,
-    ``visit(kb, kv, diagonal)`` for every pair that contributes —
-    ``kv(w)`` hands out the first w rows of K/V tile kb — then ``end()``.
+    ``visit(kb, kv, blocks)`` for every pair that contributes —
+    ``kv(w)`` hands out the first w rows of K/V tile kb, ``blocks`` are
+    the pair's (``_pair_blocks``) — then ``end()``.
 
     ``resident``: K and V of the (batch, head) are whole in VMEM (fetched
     once per head, not once per q tile) and an in-kernel loop walks the k
     tiles up to the diagonal — no grid step exists for a pair above it.
     Otherwise K/V tiles stream over a kb grid axis (``program_id(3)``)
     whose index maps are clamped at the diagonal (``_kv_row``), so the
-    steps above it fetch nothing and do nothing."""
+    steps above it fetch nothing and do nothing. ``band`` (``_band``): the
+    band's pairs alone; streamed, the kb axis is ``last + 1`` steps wide,
+    step j meeting tile qi - last + j, and the steps left of tile 0
+    re-name it and do nothing."""
     qi = pl.program_id(2)
+    plain_blocks = _pair_blocks(b, sub, None)
+    diag_blocks = _pair_blocks(b, sub, 0)
     if resident:
         def tile(kb):
             def kv(w):
@@ -185,33 +259,62 @@ def _for_each_pair(k_ref, v_ref, *, b: int, causal: bool, resident: bool,
             return kv
 
         def plain(kb, carry):
-            visit(kb, tile(kb), False)
+            visit(kb, tile(kb), plain_blocks)
             return carry
 
         begin()
-        jax.lax.fori_loop(0, qi if causal else k_ref.shape[1] // b,
+        if band is None:
+            first = 0
+        else:
+            _, n_plain, edges, window = band
+            for d in reversed(edges):
+                pl.when(qi >= d)(functools.partial(
+                    lambda d: visit(qi - d, tile(qi - d),
+                                    _pair_blocks(b, sub, d, window)), d))
+            first = jnp.maximum(qi - n_plain, 0)
+        jax.lax.fori_loop(first, qi if causal else k_ref.shape[1] // b,
                           plain, 0)
         if causal:
-            visit(qi, tile(qi), True)
+            visit(qi, tile(qi), diag_blocks)
         end()
         return
 
     def kv(w):
         return k_ref[0, :w, :], v_ref[0, :w, :]
 
+    if band is not None:
+        last, n_plain, edges, window = band
+        j = pl.program_id(3)
+        d, kb = last - j, qi - last + j
+        pl.when(j == 0)(begin)
+        if n_plain:
+            pl.when((d <= n_plain) & (d >= 1) & (kb >= 0))(
+                lambda: visit(kb, kv, plain_blocks))
+        for e in edges:
+            pl.when((d == e) & (kb >= 0))(functools.partial(
+                lambda e: visit(kb, kv, _pair_blocks(b, sub, e, window)),
+                e))
+
+        @pl.when(j == last)
+        def _diagonal():
+            visit(qi, kv, diag_blocks)
+            end()
+        return
+
     kb = pl.program_id(3)
     last = qi if causal else pl.num_programs(3) - 1
     pl.when(kb == 0)(begin)
-    pl.when(kb < last)(lambda: visit(kb, kv, False))
+    pl.when(kb < last)(lambda: visit(kb, kv, plain_blocks))
 
     @pl.when(kb == last)
     def _last():
-        visit(kb, kv, causal)
+        visit(kb, kv, diag_blocks if causal else plain_blocks)
         end()
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, b: int, sub: int,
-                resident: bool, q_scale: float, with_lse: bool):
+                resident: bool, q_scale: float, with_lse: bool,
+                band=None):
     """Forward for one [b, D] q tile, grid (g0, g1, qi[, kb]) (the two
     schedules: ``_for_each_pair``). The online-softmax state (acc/m/l)
     lives in scratch; the normalized output and the row log2-sum-exp2
@@ -228,10 +331,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, b: int, sub: int,
         m_ref[:] = jnp.full_like(m_ref, -1e30)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def visit(kb, kv, diagonal):
-        for r0, n, w, masked in _tile_schedule(b, sub, diagonal):
-            _softmax_update(q[r0:r0 + n], *kv(w), masked, acc_ref, m_ref,
-                            l_ref, slice(r0, r0 + n))
+    def visit(kb, kv, blocks):
+        for r0, n, w, masked, band in blocks:
+            _softmax_update(q[r0:r0 + n], *kv(w), masked, band, acc_ref,
+                            m_ref, l_ref, slice(r0, r0 + n))
 
     def finish():
         l = l_ref[:]
@@ -243,12 +346,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, b: int, sub: int,
             lse = jnp.where(l == 0.0, -1e30, m_ref[:] + jnp.log2(safe))
             lse_ref[0] = lse[:, :_STAT_LANES]
 
-    _for_each_pair(k_ref, v_ref, b=b, causal=causal, resident=resident,
-                   begin=init, visit=visit, end=finish)
+    _for_each_pair(k_ref, v_ref, b=b, sub=sub, causal=causal,
+                   resident=resident, begin=init, visit=visit, end=finish,
+                   band=band)
 
 
 def _bwd_visit(qs, q, do, lse, delta, kv, schedule, add_dq, add_dkv):
-    """The backward of one tile pair, block by block of ``schedule``.
+    """The backward of one tile pair, block by block of ``schedule``
+    (``_pair_blocks``).
 
     Recomputes each probability block from (q, k, lse) — the
     flash-backward trade: score blocks never leave VMEM.
@@ -256,10 +361,10 @@ def _bwd_visit(qs, q, do, lse, delta, kv, schedule, add_dq, add_dkv):
     dA·K go to ``add_dq(rows, ·)``, dAᵀ·Q (raw q) and Pᵀ·dO — for the
     pair's first ``cols`` K/V rows — to ``add_dkv(cols, ·, ·)``; either
     may be None (the split kernels)."""
-    for r0, n, w, masked in schedule:
+    for r0, n, w, masked, band in schedule:
         r = slice(r0, r0 + n)
         k, v = kv(w)
-        p = jnp.exp2(_scores(qs[r], k, masked) - lse[r])
+        p = jnp.exp2(_scores(qs[r], k, masked, band) - lse[r])
         ds = (p * (_dot(do[r], v, 1, 1) - delta[r])).astype(k.dtype)
         if add_dq is not None:
             add_dq(r, _dot(ds, k, 1, 0))
@@ -271,7 +376,7 @@ def _bwd_visit(qs, q, do, lse, delta, kv, schedule, add_dq, add_dkv):
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 causal: bool, b: int, sub: int, resident: bool,
                 fused: bool, packed: bool, q_scale: float,
-                grad_scale: float):
+                grad_scale: float, band=None):
     """Backward for one [b, D] q tile, grid (g0, g1, qi[, kb]), with the
     forward's two schedules (``_for_each_pair``).
 
@@ -298,13 +403,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def add_dq(r, x):
         dq_acc[r, :] += x
 
-    def visit(kb, kv, diagonal):
+    def visit(kb, kv, blocks):
         def add_dkv(w, dk, dv):
             rows = pl.ds(pl.multiple_of(kb * b, b), w)
             dk_acc[rows, :] += dk
             dv_acc[rows, :] += dv
-        _bwd_visit(qs, q, do, lse, delta, kv,
-                   _tile_schedule(b, sub, diagonal), add_dq,
+        _bwd_visit(qs, q, do, lse, delta, kv, blocks, add_dq,
                    add_dkv if fused else None)
 
     def init_kv():
@@ -338,16 +442,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if fused:
             pl.when(qi == n_qi - 1)(write_kv)
 
-    _for_each_pair(k_ref, v_ref, b=b, causal=causal, resident=resident,
-                   begin=begin, visit=visit, end=end)
+    _for_each_pair(k_ref, v_ref, b=b, sub=sub, causal=causal,
+                   resident=resident, begin=begin, visit=visit, end=end,
+                   band=band)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc, dv_acc, *, causal: bool, b: int, sub: int,
-                q_scale: float, grad_scale: float):
+                q_scale: float, grad_scale: float, band=None):
     """Split path, grid (g0, g1, kb, qi): dk/dv of one K/V tile over the
     q tiles at and below the diagonal (the steps above it — they come
-    FIRST here — fetch nothing: ``_q_row`` clamps)."""
+    FIRST here — fetch nothing: ``_q_row`` clamps). ``band``: the qi
+    axis is ``last + 1`` steps wide, step j meeting q tile kb + j; the
+    steps past the last tile re-name it and do nothing."""
     kb = pl.program_id(2)
     qi = pl.program_id(3)
 
@@ -360,17 +467,29 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc[:w, :] += dk
         dv_acc[:w, :] += dv
 
-    def visit(diagonal):
+    def visit(d):
         _bwd_visit(_scaled(q_ref, q_scale), q_ref[0], do_ref[0],
                    lse_ref[0][:, :1], delta_ref[0][:, :1],
                    lambda w: (k_ref[0, :w, :], v_ref[0, :w, :]),
-                   _tile_schedule(b, sub, diagonal), None, add_dkv)
+                   _pair_blocks(b, sub, d, None if band is None else band[3]),
+                   None, add_dkv)
 
-    if causal:
-        pl.when(qi == kb)(lambda: visit(True))
-        pl.when(qi > kb)(lambda: visit(False))
+    if band is not None:
+        # Here the step is the distance d = qi - kb itself.
+        d, n_t = qi, pl.num_programs(2)
+        _, n_plain, edges, _ = band
+        pl.when(d == 0)(lambda: visit(0))
+        if n_plain:
+            pl.when((d >= 1) & (d <= n_plain) & (kb + d < n_t))(
+                lambda: visit(None))
+        for e in edges:
+            pl.when((d == e) & (kb + d < n_t))(
+                functools.partial(visit, e))
+    elif causal:
+        pl.when(qi == kb)(lambda: visit(0))
+        pl.when(qi > kb)(lambda: visit(None))
     else:
-        visit(False)
+        visit(None)
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _finish():
@@ -523,17 +642,24 @@ def _qi_row(qi, *kb):
     return qi
 
 
-def _kv_row(causal):
+def _kv_row(causal, band=None):
     """Row-block map of a streamed K/V tile on grid (.., qi, kb): clamped
     at the diagonal, so a step above it re-names the tile already in VMEM
-    and nothing is fetched."""
+    and nothing is fetched. ``band``: grid (.., qi, j) over the band, step
+    j meeting tile qi - last + j, clamped from below at tile 0."""
+    if band is not None:
+        return lambda qi, j: jnp.maximum(qi - band[0] + j, 0)
     return (lambda qi, kb: jnp.minimum(kb, qi)) if causal \
         else (lambda qi, kb: kb)
 
 
-def _q_row(causal):
+def _q_row(causal, band=None, n_t=0):
     """The same for the q-side tiles of the dkv kernel's grid (.., kb, qi),
-    where the dead steps come first."""
+    where the dead steps come first. ``band``: grid (.., kb, j) over the
+    band, step j meeting q tile kb + j, clamped from above at the last of
+    the ``n_t``; there the dead steps come last."""
+    if band is not None:
+        return lambda kb, j: jnp.minimum(kb + j, n_t - 1)
     return (lambda kb, qi: jnp.maximum(qi, kb)) if causal \
         else (lambda kb, qi: qi)
 
@@ -552,16 +678,19 @@ def _layout_of(q, H: Optional[int], v=None):
 # unrolled diagonal strips make the bodies several times longer to trace
 # than one tile pair's (PERF.md PR 27: the LM cell's set-up).
 @functools.partial(jax.jit, static_argnames=(
-    "H", "causal", "q_scale", "interpret", "with_lse"))
+    "H", "causal", "q_scale", "interpret", "with_lse", "window"))
 def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
-         interpret: bool, with_lse: bool = True):
+         interpret: bool, with_lse: bool = True,
+         window: Optional[int] = None):
     """-> (o, lse2 [B*H, T, _STAT_LANES] f32 | None). ``with_lse=False``
     (the no-grad primal) drops the lse output — Mosaic can't
     dead-code-eliminate an output buffer, and at long T the f32 lse write
-    outweighs the bf16 output itself."""
+    outweighs the bf16 output itself. ``window``: causal attention over
+    the band of that many keys (``_band``)."""
     lay, T = _layout_of(q, H, None if H is not None else v)
     D = lay.Dv
-    b, sub = _blocks(T)
+    b, sub = _blocks(T, window)
+    band = None if window is None else _band(b, window, T // b)
     lead = q.shape[0]
     n_heads = lead if lay.H is None else lead * lay.H
     plan, vmem = _plan(T, lay.gate_d, q.dtype.itemsize, b=b, bwd=False)
@@ -571,8 +700,9 @@ def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
         kv_specs = [lay.spec(x, T, lambda qi: 0) for x in "kv"]
         semantics = ("parallel",) * 3
     else:
-        grid = lay.grid(lead) + (T // b, T // b)
-        kv_specs = [lay.spec(x, b, _kv_row(causal)) for x in "kv"]
+        grid = lay.grid(lead) + (T // b, T // b if band is None
+                                 else band[0] + 1)
+        kv_specs = [lay.spec(x, b, _kv_row(causal, band)) for x in "kv"]
         semantics = ("parallel",) * 3 + ("arbitrary",)
     o_cols = D if lay.H is None else lay.H * D
     out_specs = [lay.spec("o", b, _qi_row)]
@@ -584,7 +714,7 @@ def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, b=b, sub=sub,
                           resident=resident, q_scale=q_scale,
-                          with_lse=with_lse),
+                          with_lse=with_lse, band=band),
         grid=grid,
         in_specs=[lay.spec("q", b, _qi_row)] + kv_specs,
         out_specs=out_specs,
@@ -602,16 +732,22 @@ def _fwd(q, k, v, *, H: Optional[int], causal: bool, q_scale: float,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "H", "causal", "q_scale", "grad_scale", "interpret"))
+    "H", "causal", "q_scale", "grad_scale", "interpret", "window"))
 def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
-         q_scale: float, grad_scale: float, interpret: bool):
+         q_scale: float, grad_scale: float, interpret: bool,
+         window: Optional[int] = None):
     """Gradients of the three inputs: one packed [B, T, H*3*D] array from
     the fused kernel in the packed layout, else (dq, dk, dv) shaped like
-    ``o``, by the kernels ``_plan`` picks."""
+    ``o``, by the kernels ``_plan`` picks; ``window`` as ``_fwd``'s. The
+    gate sizes a windowed kernel as a causal one: it keeps the same
+    whole-sequence residents (K/V, and the fused kernel's dk/dv
+    accumulators), through which its band moves."""
     lay, T = _layout_of(q, H, None if H is not None else v)
     D, Dv, packed = lay.D, lay.Dv, H is not None
-    b, sub = _blocks(T)
+    b, sub = _blocks(T, window)
     lead, n_t = q.shape[0], T // b
+    band = None if window is None else _band(b, window, n_t)
+    n_k = n_t if band is None else band[0] + 1
     # Δ_i = Σ_d dO ∘ O — cheap elementwise reduction, XLA fuses it;
     # widened to _STAT_LANES like lse so the kernels read [b, 8] tiles.
     prod = do.astype(jnp.float32) * o.astype(jnp.float32)
@@ -628,7 +764,8 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
     resident, fused = plan == "resident", plan != "split"
     kernel = functools.partial(
         _bwd_kernel, causal=causal, b=b, sub=sub, resident=resident,
-        fused=fused, packed=packed, q_scale=q_scale, grad_scale=grad_scale)
+        fused=fused, packed=packed, q_scale=q_scale, grad_scale=grad_scale,
+        band=band)
     q_side = [lay.spec("q", b, _qi_row)]
     tail = [lay.spec("o", b, _qi_row), lay.spec("stat", b, _qi_row),
             lay.spec("stat", b, _qi_row)]
@@ -637,8 +774,8 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
         kv_specs = [lay.spec(x, T, lambda qi: 0) for x in "kv"]
         semantics = ("parallel", "parallel", "arbitrary")
     else:
-        grid = lay.grid(lead) + (n_t, n_t)
-        kv_specs = [lay.spec(x, b, _kv_row(causal)) for x in "kv"]
+        grid = lay.grid(lead) + (n_t, n_k)
+        kv_specs = [lay.spec(x, b, _kv_row(causal, band)) for x in "kv"]
         semantics = ("parallel", "parallel",
                      "arbitrary" if fused else "parallel", "arbitrary")
     # dq and dk are as wide as q and k, dv as v (one width in the packed
@@ -675,15 +812,16 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
     # dk/dv iterate the OTHER way: grid (.., kb, qi), one K/V tile
     # accumulated over q tiles.
     krow = lambda kb, qi: kb                                 # noqa: E731
+    q_row = _q_row(causal, band, n_t)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, b=b, sub=sub,
-                          q_scale=q_scale, grad_scale=grad_scale),
-        grid=lay.grid(lead) + (n_t, n_t),
-        in_specs=[lay.spec("q", b, _q_row(causal)),
+                          q_scale=q_scale, grad_scale=grad_scale, band=band),
+        grid=lay.grid(lead) + (n_t, n_k),
+        in_specs=[lay.spec("q", b, q_row),
                   lay.spec("k", b, krow), lay.spec("v", b, krow),
-                  lay.spec("o", b, _q_row(causal)),
-                  lay.spec("stat", b, _q_row(causal)),
-                  lay.spec("stat", b, _q_row(causal))],
+                  lay.spec("o", b, q_row),
+                  lay.spec("stat", b, q_row),
+                  lay.spec("stat", b, q_row)],
         out_specs=[lay.spec("dqk", b, krow), lay.spec("o", b, krow)],
         out_shape=[qk_like, o_like],
         scratch_shapes=[pltpu.VMEM((b, D), jnp.float32),
@@ -699,27 +837,29 @@ def _bwd(q, k, v, o, lse, do, *, H: Optional[int], causal: bool,
                      axis=3).reshape(q.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_core(q, k, v, causal: bool, sm_scale: float, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_core(q, k, v, causal: bool, sm_scale: float, interpret: bool,
+                window: Optional[int] = None):
     """q/k/v: [BH, T, D] -> o [BH, T, D]."""
-    return _flash_core_fwd(q, k, v, causal, sm_scale, interpret,
+    return _flash_core_fwd(q, k, v, causal, sm_scale, interpret, window,
                            with_lse=False)[0]
 
 
-def _flash_core_fwd(q, k, v, causal, sm_scale, interpret, with_lse=True):
+def _flash_core_fwd(q, k, v, causal, sm_scale, interpret, window=None,
+                    with_lse=True):
     o, lse = _fwd(q, k, v, H=None, causal=causal, q_scale=sm_scale * LOG2E,
-                  interpret=interpret, with_lse=with_lse)
+                  interpret=interpret, with_lse=with_lse, window=window)
     # lse stays in the narrow [BH, T, _STAT_LANES] wire format in the
     # residuals (slicing to one lane and re-broadcasting in backward would
     # cost two device copies to save 7 f32 lanes).
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(causal, sm_scale, interpret, res, do):
+def _flash_core_bwd(causal, sm_scale, interpret, window, res, do):
     q, k, v, o, lse = res
     return _bwd(q, k, v, o, lse, do, H=None, causal=causal,
                 q_scale=sm_scale * LOG2E, grad_scale=sm_scale,
-                interpret=interpret)
+                interpret=interpret, window=window)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -784,12 +924,13 @@ def flash_attention_qkv(qkv, n_heads: int, *, causal: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
-                                             "interpret"))
-def _flash_bhtd(q, k, v, causal: bool, sm_scale: float, interpret: bool):
+                                             "interpret", "window"))
+def _flash_bhtd(q, k, v, causal: bool, sm_scale: float, interpret: bool,
+                window: Optional[int] = None):
     """q/k/v: [BH, T, D] -> [BH, T, D]. Differentiable (custom VJP with
     Pallas backward kernels — the score matrix never touches HBM in
     either direction)."""
-    return _flash_core(q, k, v, causal, sm_scale, interpret)
+    return _flash_core(q, k, v, causal, sm_scale, interpret, window)
 
 
 # Above roughly this many bytes of [B, H, T, T] f32 scores, the dense XLA
@@ -812,7 +953,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     backend: str = "auto",
                     interpret: Optional[bool] = None,
-                    fallback: bool = True):
+                    fallback: bool = True,
+                    window: Optional[int] = None):
     """Multi-head attention: XLA by default, Pallas kernel for long context.
 
     Args:
@@ -828,12 +970,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
       fallback: what ``backend="pallas"`` does with a shape the kernels do
         not tile: True (the packed block's callers), the XLA path below,
         whose [B, H, T, T] float32 scores exist in HBM; False, a
-        ValueError (the layers described by ``MLA``: they never fall
-        back silently).
+        ValueError (the layers described by ``MLA`` and the windowed
+        layers: they never fall back silently).
+      window: causal attention over a sliding window: query i sees key j
+        iff i - window < j <= i (``window`` keys, itself among them). The
+        kernels visit only the tile pairs that touch the band
+        (``_band``); a window of T or more is plain causal attention.
 
     Which shapes tile: T a multiple of 128 and Dv a multiple of 128, D any
-    width. q and k of a width that is no multiple of 128 enter the kernels
-    padded with zero columns to the next one (192 -> 256: on a 128-wide
+    width, and a window of at least 128. q and k of a width that is no
+    multiple of 128 enter the kernels padded with zero columns to the next
+    one (192 -> 256: on a 128-wide
     MXU the contraction takes two passes either way), and their gradients
     leave cut back; the scale stays that of the true width. Differentiable on
     every path (the Pallas path via a custom VJP whose dq/dk/dv are
@@ -843,7 +990,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
     Dv = v.shape[-1]
     if sm_scale is None:
         sm_scale = float(D) ** -0.5
-    tilable = qkv_flash_tilable(T, Dv)
+    if window is not None:
+        if not causal:
+            raise ValueError("flash_attention: a window bounds CAUSAL "
+                             "attention from below; pass causal=True")
+        if window < 1:
+            raise ValueError(f"flash_attention: window={window} holds no key")
+        if window >= T:
+            window = None
+    tilable = qkv_flash_tilable(T, Dv) and (window is None
+                                            or window >= BLOCK_K)
     if backend == "auto":
         score_bytes = 4 * B * H * T * T
         backend = "pallas" if (tilable
@@ -851,11 +1007,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
             else "xla"
     if backend == "pallas" and not tilable and not fallback:
         raise ValueError(
-            f"flash_attention: the kernels tile T % 128 == 0 and a value "
-            f"width that is a multiple of 128; got T={T}, q/k width {D}, "
-            f"v width {Dv}, and no fallback to [T, T] scores was allowed")
+            f"flash_attention: the kernels tile T % 128 == 0, a value "
+            f"width that is a multiple of 128 and a window of at least 128; "
+            f"got T={T}, q/k width {D}, v width {Dv}, window {window}, and "
+            f"no fallback to [T, T] scores was allowed")
     if backend == "xla" or not tilable:
-        return _xla_attention(q, k, v, causal, sm_scale)
+        return _xla_attention(q, k, v, causal, sm_scale, window)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -866,16 +1023,21 @@ def flash_attention(q, k, v, *, causal: bool = False,
         return x
 
     out = _flash_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v), causal, sm_scale,
-                      interpret)
+                      interpret, window)
     return out.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
 
 
-def _xla_attention(q, k, v, causal, sm_scale):
+def _xla_attention(q, k, v, causal, sm_scale, window=None):
+    """Dense attention, [B, H, T, T] float32 scores; ``window`` as
+    :func:`flash_attention`'s (causal)."""
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * sm_scale
     if causal:
         pos = jnp.arange(q.shape[1])
-        scores = jnp.where(pos[:, None] >= pos[None, :], scores, -1e30)
+        seen = pos[:, None] >= pos[None, :]
+        if window is not None:
+            seen = seen & (pos[:, None] - pos[None, :] < window)
+        scores = jnp.where(seen, scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)

@@ -95,7 +95,20 @@ class LatentAttention:
     rope_theta: float = 0.0
 
 
-LAYER_KINDS = ("attn", "gdn", "kda", "mla")
+@dataclasses.dataclass(frozen=True)
+class SlidingWindow:
+    """Sliding-window attention ("swa" layers): the projected attention the
+    configuration's fields describe (grouped heads, q/k norm, the output
+    gate), each query seeing the ``window`` keys up to and including its
+    own (``ops/pallas_attention.flash_attention(window=)``), positions by
+    RoPE of base ``rope_theta`` (0: none). The model's "attn" layers keep
+    ``TransformerConfig.rope_theta``: 0 there makes them full attention
+    without positions beside the windowed ones."""
+    window: int
+    rope_theta: float = 0.0
+
+
+LAYER_KINDS = ("attn", "gdn", "kda", "mla", "swa")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,6 +138,7 @@ class TransformerConfig:
     gdn: Optional[GatedDeltaNet] = None
     kda: Optional[KimiDeltaAttention] = None    # describes "kda" layers
     mla: Optional[LatentAttention] = None       # describes "mla" layers
+    swa: Optional[SlidingWindow] = None         # describes "swa" layers
     # The first ``dense_layers`` layers end in a dense gated-SiLU
     # feed-forward of width ``dense_ff`` whatever their mixer and whatever
     # ``n_experts`` says of the others (a leading dense layer before
@@ -139,6 +153,11 @@ class TransformerConfig:
     shared_expert_ff: int = 0   # >0: a gated expert of this width every
     #                             token takes, behind a sigmoid gate, beside
     #                             the routed ones (never part of a share)
+    post_norms: bool = False    # an RMSNorm on the mixer's and on the
+    #                             feed-forward's output before each is added
+    #                             to the stream (leaves post_ln1, post_ln2)
+    embed_scale: float = 1.0    # the input embedding's rows times this (an
+    #                             untied table is then drawn at its inverse)
     # Experts (n_experts > 0): each token keeps its moe_top_k; the weights
     # are renormalised over the kept ones or not. experts_held of the
     # n_experts live in this program, from first_expert on, spread over
@@ -201,7 +220,8 @@ def _packed_qkv(cfg: TransformerConfig) -> bool:
     """The dense block's attention: one packed ``wqkv`` of equal head
     counts and nothing between the projection and the flash kernel."""
     return not (cfg.n_kv_heads or cfg.d_head or cfg.qk_norm
-                or cfg.rope_theta or cfg.indexer or cfg.attn_gate)
+                or cfg.rope_theta or cfg.indexer or cfg.attn_gate
+                or cfg.swa)
 
 
 def layer_kind(cfg: TransformerConfig, i: int) -> str:
@@ -228,7 +248,8 @@ def _check_pattern(cfg: TransformerConfig):
         if g.n_v_heads % g.n_k_heads:
             raise ValueError(f"gdn: {g.n_v_heads} value heads do not divide "
                              f"over {g.n_k_heads} key heads")
-    for kind, described in (("kda", cfg.kda), ("mla", cfg.mla)):
+    for kind, described in (("kda", cfg.kda), ("mla", cfg.mla),
+                            ("swa", cfg.swa)):
         if kind in cfg.layer_pattern and described is None:
             raise ValueError(f"layer_pattern has {kind!r} layers and "
                              f"cfg.{kind} does not describe them")
@@ -245,7 +266,8 @@ def _extended(cfg: TransformerConfig) -> bool:
     """Whether the configuration uses what the layer pattern brought (its
     parameters are drawn from more keys a layer)."""
     return bool(cfg.layer_pattern or cfg.norm_offset or cfg.shared_expert_ff
-                or cfg.attn_gate or _wide_draw(cfg))
+                or cfg.attn_gate or cfg.post_norms or cfg.embed_scale != 1.0
+                or _wide_draw(cfg))
 
 
 def _wide_draw(cfg: TransformerConfig) -> bool:
@@ -291,7 +313,7 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         # token's own row would be a fiftieth of the stream after the
         # first block, and every token's hidden state nearly the same.
         "embed": norm(next(ki), (cfg.vocab, d),
-                      0.02 if cfg.tied_head else 1.0),
+                      0.02 if cfg.tied_head else 1.0 / cfg.embed_scale),
         "lnf": gain((d,)),
         "layers": [],
     }
@@ -299,6 +321,8 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         params["head"] = norm(next(ki), (cfg.vocab, d), d ** -0.5)
     for i in range(cfg.n_layers):
         layer = {"ln1": gain((d,)), "ln2": gain((d,))}
+        if cfg.post_norms:
+            layer.update(post_ln1=gain((d,)), post_ln2=gain((d,)))
         if layer_kind(cfg, i) == "gdn":
             g = cfg.gdn
             nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
@@ -424,6 +448,8 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
         up, down = ("w1",), ("w2",)
     for i in range(cfg.n_layers):
         layer = {"ln1": P(), "ln2": P()}
+        if cfg.post_norms:
+            layer.update(post_ln1=P(), post_ln2=P())
         kind = layer_kind(cfg, i)
         if kind in ("gdn", "kda", "mla"):
             layer.update({name: P() for name in {
@@ -486,6 +512,35 @@ def mla_from_interleaved(params, cfg: TransformerConfig,
     return dict(params, layers=layers)
 
 
+def gate_from_projection(params, cfg: TransformerConfig,
+                         inverse: bool = False):
+    """Parameters whose attention output gate is a projection of its own
+    (a leaf ``w_attn_gate`` [d, H dh] beside ``wq`` [d, H dh], heads-major
+    both: the afmoe block's ``gate_proj``) as this file's, whose ``wq``
+    holds each head's query columns and then its gate's
+    (``TransformerConfig.attn_gate``): a fixed permutation of columns, so
+    that both compute the same function. ``inverse``: this file's
+    parameters as such a checkpoint."""
+    if not cfg.attn_gate:
+        return params
+    H, dh = cfg.n_heads, _d_head(cfg)
+    layers = []
+    for layer in params["layers"]:
+        if "wq" in layer and inverse:
+            d = layer["wq"].shape[0]
+            both = layer["wq"].reshape(d, H, 2, dh)
+            layer = dict(layer, wq=both[:, :, 0].reshape(d, H * dh),
+                         w_attn_gate=both[:, :, 1].reshape(d, H * dh))
+        elif "wq" in layer:
+            layer = dict(layer)
+            d, gate = layer["wq"].shape[0], layer.pop("w_attn_gate")
+            layer["wq"] = jnp.stack(
+                [layer["wq"].reshape(d, H, dh), gate.reshape(d, H, dh)],
+                axis=2).reshape(d, H * 2 * dh)
+        layers.append(layer)
+    return dict(params, layers=layers)
+
+
 def _rms_norm(x, scale, offset: bool = False, eps: float = 1e-6):
     """RMSNorm, float32 inside; ``offset``: the weight multiplies as
     ``1 + scale`` (``TransformerConfig.norm_offset``)."""
@@ -521,6 +576,12 @@ def _rope(x, theta: float, fraction: float = 1.0):
                            axis=-1).astype(x.dtype)
 
 
+def _output_gate(o, gate):
+    """The attention's output gate, ``o * sigmoid(gate)``, float32 inside
+    (``TransformerConfig.attn_gate``)."""
+    return o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+
+
 def _l2_norm(x):
     """x / |x| over the last dim (float32 inside, eps 1e-6 under the
     root)."""
@@ -550,7 +611,8 @@ def _check_mesh(cfg: TransformerConfig, axes):
     """Refuse the mesh axes a configuration's layers cannot take."""
     for kind, why in (("gdn", "state runs over the whole sequence"),
                       ("kda", "state runs over the whole sequence"),
-                      ("mla", "latent is expanded for all heads at once")):
+                      ("mla", "latent is expanded for all heads at once"),
+                      ("swa", "band is not split over chips")):
         if kind in _kinds(cfg) and ("sp" in axes or "tp" in axes):
             raise NotImplementedError(
                 f"a {kind!r} layer's {why} and its heads are not sharded: "
@@ -629,9 +691,12 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                                    ).astype(cfg.dtype)
         return attn.reshape(B, T, n_heads_local * d_head)
 
-    def _projected_attention(layer, h, extras):
+    def _projected_attention(layer, h, extras, rope_theta=cfg.rope_theta,
+                             window=None):
         """Separate q/k/v projections: grouped key/value heads, per-head
-        RMSNorm, RoPE, and dense or indexer-selected attention."""
+        RMSNorm, RoPE of base ``rope_theta``, and dense, windowed
+        (``window`` keys; never the [T, T] scores under the "pallas"
+        backend) or indexer-selected attention."""
         from ..ops.pallas_attention import flash_attention
         from ..ops.sparse_attention import dsa_attention
         B, T, _ = h.shape
@@ -648,9 +713,9 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
         if cfg.qk_norm:
             q = _rms_norm(q, layer["q_norm"], offset)
             k = _rms_norm(k, layer["k_norm"], offset)
-        if cfg.rope_theta:
-            q = _rope(q, cfg.rope_theta, cfg.rope_fraction)
-            k = _rope(k, cfg.rope_theta, cfg.rope_fraction)
+        if rope_theta:
+            q = _rope(q, rope_theta, cfg.rope_fraction)
+            k = _rope(k, rope_theta, cfg.rope_fraction)
         if cfg.indexer:
             ix = cfg.indexer
             hs = lax.stop_gradient(h)
@@ -670,12 +735,15 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                 extras["mask"] = mask
         else:
             group = n_heads_local // kv_local
-            attn = flash_attention(q, jnp.repeat(k, group, axis=2),
-                                   jnp.repeat(v, group, axis=2), causal=True,
-                                   backend=cfg.attn_backend)
+            qkv = (q, jnp.repeat(k, group, axis=2),
+                   jnp.repeat(v, group, axis=2))
+            attn = flash_attention(*qkv, causal=True,
+                                   backend=cfg.attn_backend,
+                                   fallback=window is None, window=window)
+            if with_masks:
+                extras["attn_in"], extras["attn_o"] = qkv, attn
         if cfg.attn_gate:
-            attn = attn.astype(jnp.float32) * jax.nn.sigmoid(
-                gate.astype(jnp.float32))
+            attn = _output_gate(attn, gate)
         return attn.astype(cfg.dtype).reshape(B, T, n_heads_local * d_head)
 
     def _gdn_mixer(li, layer, h, extras):
@@ -839,6 +907,13 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             proj = _kda_mixer(li, layer, h, extras)
         elif kind == "mla":
             proj = _mla_mixer(layer, h, extras)
+        elif kind == "swa":
+            from ..ops.pallas_attention import record_tile_pairs
+            record_tile_pairs(li, h.shape[1], cfg.swa.window)
+            with jax.named_scope("attn.swa"):
+                proj = _projected_attention(
+                    layer, h, extras, cfg.swa.rope_theta,
+                    cfg.swa.window) @ layer["wo"].astype(cfg.dtype)
         else:
             # Among layers of several kinds the softmax layer has a scope
             # of its own.
@@ -848,6 +923,8 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                     @ layer["wo"].astype(cfg.dtype)
             if has_tp:
                 proj = lax.psum(proj, "tp")           # row-parallel combine
+        if cfg.post_norms:
+            proj = _rms_norm(proj, layer["post_ln1"], offset, eps)
         x = x + proj
         h = _rms_norm(x, layer["ln2"], offset, eps)
         gated = cfg.mlp == "swiglu"
@@ -867,6 +944,8 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             y = y.reshape(B, T, cfg.d_model)
             if cfg.shared_expert_ff:
                 y = y + shared_expert(layer, h, cfg.dtype)
+            if cfg.post_norms:
+                y = _rms_norm(y, layer["post_ln2"], offset, eps)
             return x + y, extras
         # Among expert layers a dense feed-forward has a scope of its own.
         with (jax.named_scope("ffn.dense") if cfg.dense_layers
@@ -879,6 +958,8 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             down = up @ layer[w_down].astype(cfg.dtype)
         if has_tp:
             down = lax.psum(down, "tp")
+        if cfg.post_norms:
+            down = _rms_norm(down, layer["post_ln2"], offset, eps)
         return x + down, extras
 
     def _layer(li):
@@ -888,7 +969,10 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
                 fwd, policy=jax.checkpoint_policies.dots_saveable)
         return fwd
 
-    x = params["embed"][tokens].astype(cfg.dtype)     # [B, T, D]
+    x = params["embed"][tokens]                       # [B, T, D]
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
+    x = x.astype(cfg.dtype)
     carry = None
     per_layer = []
     for k, layer in enumerate(params["layers"]):

@@ -380,6 +380,39 @@ def test_flash_kernels_compile_at_a_key_width_of_their_own(one_chip, T):
     assert f"[32,{T},{T}]" not in text
 
 
+@pytest.mark.parametrize("plan,backward", [
+    ("resident", {"flash_bwd"}), ("streamed", {"flash_bwd"}),
+    ("split", {"flash_bwd_dq", "flash_bwd_dkv"})])
+@pytest.mark.parametrize("T,window", [(8192, 2048), (4096, 1000)],
+                         ids=["T8192-w2048", "T4096-w1000"])
+def test_windowed_flash_kernels_compile_in_every_schedule(
+        one_chip, monkeypatch, plan, backward, T, window):
+    """The banded schedule (sliding-window layers) in each of the gate's
+    schedules, forward and backward, 32 heads of 128: the Trinity cell's
+    window, a multiple of the tile, and one that is not (two edge pairs a
+    q tile). The plan is forced; residents take the 64 MiB rung."""
+    def forced(T, D, itemsize, *, b, bwd, packed=False):
+        if plan == "split":
+            return ("split", None) if bwd else ("streamed", None)
+        return plan if bwd else "resident", pa._VMEM_LIMIT
+    monkeypatch.setattr(pa, "_plan", forced)
+    jax.clear_caches()
+    x = jax.ShapeDtypeStruct((1, T, 32, D), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(
+            q, k, v, causal=True, backend="pallas", interpret=False,
+            fallback=False, window=window).astype(jnp.float32))
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
+    jax.clear_caches()
+    names = {ln.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for ln in text.splitlines() if "tpu_custom_call" in ln}
+    assert names == {"flash_fwd"} | backward
+    assert f"[32,{T},{T}]" not in text
+
+
 @pytest.mark.parametrize("B,T,Cx,C,W,dtype", [
     (2, 8192, 12288, 8192, 4, jnp.bfloat16),   # Qwen3-Next: q, k, v of qkvz
     (1, 8192, 12288, 12288, 4, jnp.bfloat16),  # Kimi-Linear: qkv whole
