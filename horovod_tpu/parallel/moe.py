@@ -200,7 +200,7 @@ def _chunks_bwd(k, res, g):
         return (dx.at[token].add(d_rows.astype(jnp.float32)),
                 d_ws if dws is None else jax.tree_util.tree_map(
                     jnp.add, dws, d_ws),
-                dweight.at[c].set(d_weight_c))
+                lax.dynamic_update_index_in_dim(dweight, d_weight_c, c, 0))
     dx, dws, dweight = _first_then_entered(
         add, (jnp.zeros(x.shape, jnp.float32), None, jnp.zeros_like(weight)),
         order, ends)
@@ -225,16 +225,25 @@ def _held_experts(x, ids, gates, w_gate, w_up, w_down, first, n_experts):
     all its rows, the chunks past the last row held here are never entered
     (``_chunks``), and the grouped products visit only the tiles that hold
     a group's rows: the work follows the load the router sends, and a step
-    takes longer when the held experts are popular."""
+    takes longer when the held experts are popular.
+
+    The bookkeeping has no scatter and no gather of single values, which
+    XLA's TPU backend works a value at a time (on a v5e, 8.7 ns an
+    assignment for a scatter-add count and 7.1 for a gather; PERF.md §5):
+    ONE stable sort carries the weights into the assignments' order beside
+    their indices, and each held expert's assignments are counted by a
+    dense compare-and-sum."""
     M, D = x.shape
     k, held = ids.shape[1], w_up.shape[0]
     local = ids.reshape(-1) - first
     here = (local >= 0) & (local < held)
     group = jnp.where(here, local, held).astype(jnp.int32)
-    order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    _, order, weight = lax.sort(
+        (group, lax.iota(jnp.int32, group.size), gates.reshape(-1)),
+        num_keys=1, is_stable=True)
+    sizes = jnp.sum(group == jnp.arange(held, dtype=jnp.int32)[:, None],
+                    axis=1, dtype=jnp.int32)
     ends = jnp.cumsum(sizes)
-    weight = gates.reshape(-1)[order]
 
     n_rows = M * k
     unit = _GMM_ROWS if n_rows % _GMM_ROWS == 0 else 8
@@ -301,12 +310,19 @@ def moe_ffn(x, router_w, w_up, w_down, *, w_gate=None, top_k: int = 1,
         else:
             ids = lax.top_k(scores + lax.stop_gradient(
                 select_bias.astype(jnp.float32)), top_k)[1]
-            top = jnp.take_along_axis(scores, ids, axis=-1)
+            # The kept scores by a dense compare-and-sum over the experts:
+            # XLA's TPU backend works a gather of N·k values one at a time.
+            top = jnp.sum(jnp.where(
+                ids[..., None] == jnp.arange(E, dtype=ids.dtype),
+                scores[:, None, :], 0.0), axis=-1)
         gates = top / jnp.sum(top, -1, keepdims=True) if renormalize else top
         if scale != 1.0:
             gates = gates * scale
-        share = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(
-            1.0 / (N * top_k))
+        # Assignments per expert by a dense compare-and-sum: XLA's TPU
+        # scatter-add works its N·k values one at a time.
+        counts = jnp.sum(ids[..., None] == jnp.arange(E, dtype=ids.dtype),
+                         axis=(0, 1), dtype=jnp.int32)
+        share = counts.astype(jnp.float32) / (N * top_k)
         aux = jnp.sum(share * jnp.mean(probs, axis=0)) * E
     ranks = 1 if axis_name is None else lax.axis_size(axis_name)
     spread = ranks > 1

@@ -502,6 +502,208 @@ def test_a_small_share_drops_nothing_whatever_its_load(n, held, n_chunks,
     _close_at_each_leafs_scale(grads, want_grads)
 
 
+def _scatter_counted_held_experts(x, ids, gates, w_gate, w_up, w_down,
+                                  first, n_experts):
+    """``moe._held_experts`` with the bookkeeping it had before it lost its
+    scatters and gathers: ``argsort`` of the groups, ``bincount`` of them
+    (a scatter-add), the weights gathered by the sort's permutation."""
+    from horovod_tpu.parallel import moe
+    M, _ = x.shape
+    k, held = ids.shape[1], w_up.shape[0]
+    local = ids.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).astype(jnp.int32)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    weight = gates.reshape(-1)[order]
+    n_rows = M * k
+    unit = moe._GMM_ROWS if n_rows % moe._GMM_ROWS == 0 else 8
+    cap = min(n_rows, max(unit, -(-n_rows * held * 3 // (n_experts * 2)
+                                  // unit) * unit))
+    n_chunks = -(-n_rows // cap)
+    pad = n_chunks * cap - n_rows
+    order = jnp.pad(order, (0, pad)).reshape(n_chunks, cap)
+    weight = jnp.pad(weight, (0, pad)).reshape(n_chunks, cap)
+    y = moe._chunks(k, x, (w_gate, w_up, w_down), weight, order, sizes, ends)
+    return y.astype(x.dtype), sizes, moe._entered(ends, cap)
+
+
+# The shares and loads of ``test_a_small_share_drops_nothing_whatever_its_load``
+# as (n, held, first, rows sent), and every expert held (one chunk).
+BOOKKEEPING = {
+    **{f"{share}-{load}": (n, held, 5 if n > 8 else 2, sent)
+       for share, (n, held) in (("a_half", (8, 4)), ("an_eighth", (16, 2)),
+                                ("a_thirty_second", (64, 2)))
+       for load, sent in (("balanced", 0), ("one_chunk_over", None),
+                          ("nearly_every_token", 256 - 6))},
+    "every_expert_held": (8, 8, 0, 0),
+}
+
+
+def _bookkeeping_case(name):
+    """(x, router, w, n, first) of a case of ``BOOKKEEPING``: the rows sent
+    as ``test_a_small_share_drops_nothing_whatever_its_load`` sends them;
+    every expert held as ``test_ep2_router_gradient_against_the_reference``
+    builds its eight."""
+    n, held, first, sent = BOOKKEEPING[name]
+    if held == n:
+        x, router, w = _routed(n, n // 2, 0, 0)
+        return (x, router, {k: jnp.concatenate([v, v[::-1] * 0.5])
+                            for k, v in w.items()}, n, first)
+    if sent is None:
+        cap = max(8, -(-256 * held * 3 // (n * 2) // 8) * 8)
+        sent = min(1.5 * cap, (cap + 256) / 2)
+    x, router, w = _routed(n, held, first, sent)
+    return x, router, w, n, first
+
+
+def _top2(x, router):
+    """``moe_ffn``'s softmax router, top-2 renormalised: (probs, ids,
+    gates)."""
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), -1)
+    top, ids = jax.lax.top_k(probs, 2)
+    return probs, ids, top / jnp.sum(top, -1, keepdims=True)
+
+
+@pytest.mark.parametrize("case", list(BOOKKEEPING))
+def test_the_bookkeeping_without_scatters_is_bit_for_bit_the_same(case):
+    """One stable sort that carries the weights, and counts by a dense
+    compare-and-sum, in place of ``argsort``, ``bincount`` and a gather:
+    the same permutation, so y, the assignments per held expert, the chunks
+    entered and the gradients of x, of every expert weight and of the
+    assignments' weights are equal bit for bit."""
+    from horovod_tpu.parallel import moe
+    x, router, w, n, first = _bookkeeping_case(case)
+    _, ids, gates = _top2(x, router)
+
+    def run(held_experts):
+        def loss(x, w, gates):
+            y, sizes, chunks = held_experts(
+                x, ids, gates, w["w_gate"], w["w_up"], w["w_down"], first, n)
+            return jnp.sum(y * jnp.cos(y)), (y, sizes, chunks)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))(x, w, gates)
+
+    (_, got), got_grads = run(moe._held_experts)
+    (_, want), want_grads = run(_scatter_counted_held_experts)
+    assert int(jnp.sum(got[1])) > 0
+    for a, b in zip(jax.tree_util.tree_leaves((got, got_grads)),
+                    jax.tree_util.tree_leaves((want, want_grads))):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", list(BOOKKEEPING))
+def test_the_balance_loss_counts_what_the_scatter_counted(case):
+    """``moe_ffn``'s balance loss from assignments counted by a compare-
+    and-sum: its share of each expert is the ``bincount`` of the kept ids
+    over N·k, and the loss the one the scatter-add of N·k constants gave,
+    to rounding; ``held_load`` is the held groups' ``bincount`` exactly."""
+    x, router, w, n, first = _bookkeeping_case(case)
+    held = w["w_up"].shape[0]
+    _, stats = moe_ffn(x, router, w["w_up"], w["w_down"], w_gate=w["w_gate"],
+                       top_k=2, renormalize=True, first_expert=first)
+    probs, ids, _ = _top2(x, router)
+    np.testing.assert_array_equal(stats["ids"], ids)
+    flat = ids.reshape(-1)
+    scattered = jnp.zeros((n,), jnp.float32).at[flat].add(1.0 / flat.size)
+    np.testing.assert_allclose(
+        stats["aux"], jnp.sum(scattered * jnp.mean(probs, axis=0)) * n,
+        rtol=1e-6)
+    np.testing.assert_array_equal(
+        stats["held_load"],
+        jnp.bincount(flat, length=n)[first:first + held].astype(jnp.int32))
+
+
+def _biased_sigmoid(x, router, w, first):
+    """``moe_ffn``'s keyword arguments for sigmoid scores picked through a
+    selection bias (top-2, renormalised, x 2.5), and the same routing as
+    the kept scores were taken before they were read off a dense compare:
+    gathered by ``take_along_axis``, (ids, gates)."""
+    n = router.shape[1]
+    bias = jnp.linspace(-0.05, 0.05, n, dtype=jnp.float32)
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    ids = jax.lax.top_k(scores + bias, 2)[1]
+    top = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = top / jnp.sum(top, -1, keepdims=True) * 2.5
+    if w["w_up"].shape[0] < n:
+        gates = jax.lax.stop_gradient(gates)
+    return (dict(top_k=2, renormalize=True, first_expert=first,
+                 score="sigmoid", select_bias=bias, scale=2.5), ids, gates)
+
+
+@pytest.mark.parametrize("case", list(BOOKKEEPING))
+def test_the_kept_scores_under_a_selection_bias_are_the_gathered_ones(case):
+    """Sigmoid scores picked through a selection bias: the kept scores read
+    off the dense compare of the ids against the experts, in place of
+    ``take_along_axis``, give y and the gradients of x, of every expert
+    weight and of the router equal bit for bit (the router's are zeros in
+    a share, whose weights are constants to the backward)."""
+    from horovod_tpu.parallel import moe
+    x, router, w, n, first = _bookkeeping_case(case)
+
+    def got(x, w, router):
+        kw = _biased_sigmoid(x, router, w, first)[0]
+        y, _ = moe_ffn(x, router, w["w_up"], w["w_down"], w_gate=w["w_gate"],
+                       **kw)
+        return jnp.sum(y * jnp.cos(y)), y
+
+    def want(x, w, router):
+        _, ids, gates = _biased_sigmoid(x, router, w, first)
+        y = moe._held_experts(x, ids, gates, w["w_gate"], w["w_up"],
+                              w["w_down"], first, n)[0]
+        return jnp.sum(y * jnp.cos(y)), y
+
+    runs = [jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))(
+        x, w, router) for f in (got, want)]
+    assert float(jnp.abs(runs[0][0][1]).max()) > 0
+    for a, b in zip(*map(jax.tree_util.tree_leaves, runs)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+
+
+def _result_types(op, text):
+    """The result type of every ``stablehlo.<op>`` in lowered text (generic
+    form: a scatter's update region comes before its signature)."""
+    signature = r"\(.*?\)" if op == "gather" else r"\(.*?\}\) : \(.*?\)"
+    return re.findall(rf'"stablehlo\.{op}"{signature} -> (tensor<[^>]*>)',
+                      text, re.S)
+
+
+@pytest.mark.parametrize("router_kind", ["softmax", "sigmoid_biased"])
+@pytest.mark.parametrize("case", ["a_half-balanced", "an_eighth-balanced",
+                                  "a_thirty_second-balanced"])
+def test_the_only_scatters_are_the_rows_scatter_adds(case, router_kind):
+    """A share's value and gradient, lowered, under either router: every
+    scatter is a scatter-add of rows into [M, D] (y forward, dx backward,
+    chunk 0's and the loop's), and no gather has a result of M·k single
+    values (the weights are carried through the sort, and the kept scores
+    under a selection bias read off a compare); nor is the balance loss's
+    share a scatter."""
+    x, router, w, n, first = _bookkeeping_case(case)
+    M, D = x.shape
+    kw = (dict(top_k=2, renormalize=True, first_expert=first)
+          if router_kind == "softmax"
+          else _biased_sigmoid(x, router, w, first)[0])
+
+    def loss(x, w):
+        y, stats = moe_ffn(x, router, w["w_up"], w["w_down"],
+                           w_gate=w["w_gate"], **kw)
+        return jnp.sum(y * jnp.cos(y)) + stats["aux"]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        x, w).as_text()
+    assert _result_types("scatter", text) == [f"tensor<{M}x{D}xf32>"] * 4
+    gathers = _result_types("gather", text)
+    assert gathers and all(re.fullmatch(rf"tensor<\d+x{D}xf(32|64)>", t)
+                           for t in gathers), gathers
+    assert text.count('"stablehlo.sort"') == 1
+
+
 def test_chunks_that_hold_no_row_are_not_in_the_program():
     """The gradient of the six-chunk share, lowered: ONE loop each way
     (its trip count is the load) and nothing conditional, so no chunk is a
@@ -640,17 +842,21 @@ def test_serving_refuses_the_new_kinds():
 # 936eb950..., 0094aa78... on its parent): a share's chunks after the first
 # are a loop under a custom VJP (``parallel/moe._chunks``), where they were
 # turns of a scan under ``jax.checkpoint``. The drawn parameters held.
+# The same three were replaced again by design when the expert layer's
+# bookkeeping lost its scatters and gathers (9fb6b3a3..., c4f4eb67...,
+# 816ee8f0... on its parent, commit ba93722): one stable sort carries the
+# weights, and the assignments are counted by compare-and-sum.
 BEFORE = {
     "lm": ("dfa287d6c7f2df30b47a56f3a974f52d6c5439d08b6458204ab7a720766602c7",
            "3b085b22eeff759f2bc5510aee823ac7371bdff9ed119cdc635d6a1cfe64f42b"),
     "keye_shaped": (
-        "9fb6b3a3f270a8103fa880e3153434f95ba7e1e58c0e8c82f3d9a3caceb3c6e7",
+        "58237b18ae78094a8d1cab22ac176b94d8abe09690897f3f5c0e219d21e4eb12",
         "4e2db50b6056ce5652824f4e44e1891ddad1472eef652f54a0e99bc6e07a846d"),
     "gdn_shaped": (
-        "c4f4eb6782b437617a9487b2126893997ae3104d3baf8167afdebd66eca9f5fb",
+        "affdf708749e432f6cff317837f7c0d0cecf2fc6771b4f319b19bd7a7e7853f4",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
     "gdn_shaped_kernels": (
-        "816ee8f08b643649c9f21b37cef80105bd3b57bbc51e2e5799b7b3e558831ca7",
+        "3c9e9ba24eee4d0d9dd32cad9949a1ec5a52276cf1b68f03281e8035f1562df1",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
 }
 DESCRIPTIONS = {

@@ -216,9 +216,12 @@ def test_a_wrong_reading_of_the_layout_does_not_agree(monkeypatch, wrong):
 
 # θ = 0: the unrotated mixer's lowered program, as the parent commit of
 # PR 38 lowered it (the same function of this file's toy, x64 on as in the
-# suite, float32), by sha256 of the lowered text.
+# suite, float32), by sha256 of the lowered text. The toy holds an expert
+# layer, whose bookkeeping lost its scatters and gathers by design after
+# commit ba93722 (14af0c6d... there): the hash is the unrotated mixer's
+# beside the new bookkeeping.
 UNROTATED_SHA = \
-    "14af0c6dd7cf846c696638cfd80f8e64d46f7e5edcd106da418cb843a023f917"
+    "3d51796573df302411077b54ea89782c8741a2feb56e8b9d775cb03717582417"
 
 
 def lowered_sha(cfg):
