@@ -988,6 +988,15 @@ def _aux_total(per_layer):
                jnp.zeros((), jnp.float32))
 
 
+def _balance_counter():
+    from ..obs.registry import registry
+    return registry().counter(
+        "hvd_moe_balance_loss_total",
+        "traces of the training loss of a model with expert layers, by "
+        "whether the load-balancing loss was built into it (no: its weight "
+        "is a static zero)", labels=("built",))
+
+
 def forward_hidden(params, tokens, cfg: TransformerConfig, mesh: Mesh):
     """Runs INSIDE shard_map: ``tokens`` [B_local, T_local] int32.
     Returns (final hidden states [B_local, T_local, d_model] — the
@@ -1520,6 +1529,13 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
     "Overlap & wire formats") runs the data-parallel gradient averages in
     reduced wire precision with fp32 scales and fp32 result accumulation.
 
+    ``aux_weight`` weighs the expert layers' load-balancing loss in the
+    loss. A Python number equal to 0 builds no balance loss at all: XLA
+    does not fold ``0.0 * x`` of floats (x may be NaN), so a zero weight
+    written into the loss would keep the loss's forward and pay the
+    router's backward for a gradient of zeros. The step's losses and
+    parameters are the same either way.
+
     ``overlap``: ``None`` (the default) and ``True`` reduce each layer's
     gradients INSIDE the backward wherever they cross chips (a sync axis
     with more than one member, ``zero=False``, ``accum_steps == 1``): one
@@ -1594,6 +1610,10 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
         return reduce_in_backward(layer, x, carry, layer_syncs, _bucket(k),
                                   wire=dist_opt.update.wire_dtype)
 
+    # The docstring's ``aux_weight``: a static zero builds no balance loss.
+    with_balance = not (isinstance(aux_weight, (int, float))
+                        and aux_weight == 0)
+
     def _loss_fn(params, tokens, labels):
         x, per_layer = _forward_layers(
             params, tokens, cfg, mesh,
@@ -1602,7 +1622,12 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh,
             nll = chunked_nll(x, _unembedding(params, cfg), labels, cfg)
         else:
             nll = dense_nll(_logits(x, params, cfg), labels)
-        loss = jnp.mean(nll) + aux_weight * _aux_total(per_layer)
+        loss = jnp.mean(nll)
+        if any("aux" in e for e in per_layer):
+            _balance_counter().labels(
+                built="yes" if with_balance else "no").inc()
+        if with_balance:
+            loss = loss + aux_weight * _aux_total(per_layer)
         if cfg.indexer:
             # Mean over layers and rows of the indexer's KL: it trains the
             # indexer alone, the NLL everything else (ops/sparse_attention).

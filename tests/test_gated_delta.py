@@ -845,18 +845,23 @@ def test_serving_refuses_the_new_kinds():
 # The same three were replaced again by design when the expert layer's
 # bookkeeping lost its scatters and gathers (9fb6b3a3..., c4f4eb67...,
 # 816ee8f0... on its parent, commit ba93722): one stable sort carries the
-# weights, and the assignments are counted by compare-and-sum.
+# weights, and the assignments are counted by compare-and-sum. All three
+# are built at ``aux_weight=0.0`` and were replaced again by design when a
+# static zero weight stopped building the balance loss (58237b18...,
+# affdf708..., 3c9e9ba2... on its parent, commit 8aae949): no router
+# backward, no share and no mean of the probabilities. The ``lm`` entry,
+# built at the default weight, held.
 BEFORE = {
     "lm": ("dfa287d6c7f2df30b47a56f3a974f52d6c5439d08b6458204ab7a720766602c7",
            "3b085b22eeff759f2bc5510aee823ac7371bdff9ed119cdc635d6a1cfe64f42b"),
     "keye_shaped": (
-        "58237b18ae78094a8d1cab22ac176b94d8abe09690897f3f5c0e219d21e4eb12",
+        "702ac09c72d10f229c49e443f299da77fa06eaafe78c281a1be014c0428d9a31",
         "4e2db50b6056ce5652824f4e44e1891ddad1472eef652f54a0e99bc6e07a846d"),
     "gdn_shaped": (
-        "affdf708749e432f6cff317837f7c0d0cecf2fc6771b4f319b19bd7a7e7853f4",
+        "489e8b4f303de4f64f61142ea0d314e567248c12dbdf62e232fcebc9cd0bcc1f",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
     "gdn_shaped_kernels": (
-        "3c9e9ba24eee4d0d9dd32cad9949a1ec5a52276cf1b68f03281e8035f1562df1",
+        "593158f5f1dc5372011419aed88fdeafa8d2255dd1542f99a669e6842f48569e",
         "0160a1c24e722d75c6e593c53e04b9b046be8f483611eb864e13f6de1ab3d248"),
 }
 DESCRIPTIONS = {
