@@ -18,7 +18,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,9 +108,6 @@ class SlidingWindow:
     rope_theta: float = 0.0
 
 
-LAYER_KINDS = ("attn", "gdn", "kda", "mla", "swa")
-
-
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 1024
@@ -129,13 +126,12 @@ class TransformerConfig:
     mlp: str = "gelu"           # "gelu" (w1, w2) | "swiglu" (w_gate, w_up, w_down)
     tied_head: bool = True      # False: an unembedding of its own ("head")
     indexer: Optional[Indexer] = None   # sparse attention over selected keys
-    # -- Layers of several kinds. ``layer_pattern`` is one period of kinds,
-    # repeated over the depth: "attn" (the softmax-attention mixer the
-    # fields above describe) or "gdn" (the linear-attention mixer ``gdn``
-    # describes); () = every layer "attn". Whatever its mixer, a layer ends
-    # in the same feed-forward or expert layer.
+    # -- Layers of several kinds. ``layer_pattern`` is one period of kinds
+    # (``_KINDS``), repeated over the depth; () = every layer "attn", the
+    # softmax attention the fields above describe. Whatever its mixer, a
+    # layer ends in the same feed-forward or expert layer.
     layer_pattern: Tuple[str, ...] = ()
-    gdn: Optional[GatedDeltaNet] = None
+    gdn: Optional[GatedDeltaNet] = None         # describes "gdn" layers
     kda: Optional[KimiDeltaAttention] = None    # describes "kda" layers
     mla: Optional[LatentAttention] = None       # describes "mla" layers
     swa: Optional[SlidingWindow] = None         # describes "swa" layers
@@ -231,28 +227,18 @@ def layer_kind(cfg: TransformerConfig, i: int) -> str:
     return cfg.layer_pattern[i % len(cfg.layer_pattern)]
 
 
-def _kinds(cfg: TransformerConfig):
-    return {layer_kind(cfg, i) for i in range(cfg.n_layers)}
-
-
 def _check_pattern(cfg: TransformerConfig):
-    unknown = set(cfg.layer_pattern) - set(LAYER_KINDS)
+    unknown = set(cfg.layer_pattern) - set(_KINDS)
     if unknown:
         raise ValueError(f"layer_pattern names {sorted(unknown)}; the kinds "
-                         f"are {LAYER_KINDS}")
-    if "gdn" in cfg.layer_pattern:
-        g = cfg.gdn
-        if g is None:
-            raise ValueError("layer_pattern has 'gdn' layers and cfg.gdn "
-                             "does not describe them")
-        if g.n_v_heads % g.n_k_heads:
-            raise ValueError(f"gdn: {g.n_v_heads} value heads do not divide "
-                             f"over {g.n_k_heads} key heads")
-    for kind, described in (("kda", cfg.kda), ("mla", cfg.mla),
-                            ("swa", cfg.swa)):
-        if kind in cfg.layer_pattern and described is None:
-            raise ValueError(f"layer_pattern has {kind!r} layers and "
-                             f"cfg.{kind} does not describe them")
+                         f"are {tuple(_KINDS)}")
+    for kind, entry in _KINDS.items():
+        if entry.field and kind in cfg.layer_pattern:
+            described = getattr(cfg, entry.field)
+            if described is None:
+                raise ValueError(f"layer_pattern has {kind!r} layers and "
+                                 f"cfg.{entry.field} does not describe them")
+            entry.check(described)
     if cfg.dense_layers and not (cfg.dense_ff and cfg.mlp == "swiglu"):
         raise ValueError("dense_layers are gated-SiLU feed-forwards of "
                          "width dense_ff: they need dense_ff > 0 and "
@@ -262,21 +248,24 @@ def _check_pattern(cfg: TransformerConfig):
                          "ones: it needs n_experts > 0 and mlp='swiglu'")
 
 
-def _extended(cfg: TransformerConfig) -> bool:
-    """Whether the configuration uses what the layer pattern brought (its
-    parameters are drawn from more keys a layer)."""
-    return bool(cfg.layer_pattern or cfg.norm_offset or cfg.shared_expert_ff
-                or cfg.attn_gate or cfg.post_norms or cfg.embed_scale != 1.0
-                or _wide_draw(cfg))
-
-
-def _wide_draw(cfg: TransformerConfig) -> bool:
-    """Whether the configuration uses what the "kda" and "mla" kinds
-    brought (a layer draws from more keys still)."""
-    return bool({"kda", "mla"} & set(cfg.layer_pattern) or cfg.dense_layers
+def _keys_split(cfg: TransformerConfig) -> Tuple[int, int]:
+    """How many keys a seed is split into, (the model's own, each
+    layer's): what fixes the numbers a seed gives every configuration, so
+    it grew only with what a configuration uses. The dense block (packed
+    ``wqkv``, GELU, tied head): (4, 6); a described block: 12 a layer; with
+    what the layer pattern brought: 16; with what the "kda" and "mla"
+    kinds brought: 24."""
+    wide = bool({"kda", "mla"} & set(cfg.layer_pattern) or cfg.dense_layers
                 or cfg.moe_select_bias or cfg.moe_score != "softmax"
                 or cfg.moe_scale != 1.0 or not cfg.shared_expert_gate
                 or cfg.norm_eps != 1e-6)
+    extended = wide or bool(
+        cfg.layer_pattern or cfg.norm_offset or cfg.shared_expert_ff
+        or cfg.attn_gate or cfg.post_norms or cfg.embed_scale != 1.0)
+    if not extended and _packed_qkv(cfg) and cfg.mlp == "gelu" \
+            and cfg.tied_head:
+        return 4, 6
+    return 5, 24 if wide else 16 if extended else 12
 
 
 def _dense_ffn(cfg: TransformerConfig, i: int) -> bool:
@@ -288,196 +277,108 @@ def _held(cfg: TransformerConfig) -> int:
     return cfg.experts_held or cfg.n_experts
 
 
-def init_params(rng, cfg: TransformerConfig) -> Dict:
-    """Global (unsharded-shape) parameter pytree; place with
-    :func:`param_specs` + ``jax.device_put`` before use. Layer ``i``'s
-    entry has the leaves of its kind (:func:`layer_kind`)."""
+class _Draw:
+    """Draws a model's leaves from the keys a seed was split into, one key
+    a leaf in turn (``shard``: :class:`_Specs`)."""
+
+    ones, zeros = staticmethod(jnp.ones), staticmethod(jnp.zeros)
+
+    def __init__(self, cfg: TransformerConfig, keys):
+        self._keys, self._next = keys, iter(range(len(keys)))
+        # A norm's weight multiplies as it stands (from one) or as 1 + w
+        # (from zero): the same function at birth.
+        self.gain = jnp.zeros if cfg.norm_offset else jnp.ones
+
+    def normal(self, shape, scale, shard=None):
+        return jax.random.normal(self._keys[next(self._next)], shape) * scale
+
+    def log_uniform(self, shape, low, high):
+        return jnp.log(jax.random.uniform(self._keys[next(self._next)], shape,
+                                          minval=low, maxval=high))
+
+
+class _Specs:
+    """:class:`_Draw`'s walk giving each leaf's PartitionSpec on a mesh:
+    ``shard`` "col" / "row" splits its out- / in-dim over tp (Megatron
+    column / row), "experts" its experts over ep; any other leaf is
+    replicated."""
+
+    def __init__(self, mesh: Mesh):
+        tp, ep = (a if a in _axes(mesh) else None for a in ("tp", "ep"))
+        self._shards = {"col": P(None, tp), "row": P(tp, None),
+                        "experts": P(ep, None, None)}
+        self.ones = self.zeros = self.gain = self.log_uniform = \
+            lambda *shape_and_range: P()
+
+    def normal(self, shape, scale, shard=None):
+        return self._shards.get(shard, P())
+
+
+def _walk(cfg: TransformerConfig, draw):
+    """The parameter tree, each leaf from ``draw`` in the order a seed
+    draws them."""
     _check_pattern(cfg)
-    # The dense block draws what it always drew from a seed, and so does
-    # the described block of one kind.
-    dense = (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head
-             and not _extended(cfg))
-    per_layer = 6 if dense else 24 if _wide_draw(cfg) \
-        else 16 if _extended(cfg) else 12
-    k = jax.random.split(rng, (4 if dense else 5) + per_layer * cfg.n_layers)
-    ki = iter(range(len(k)))
-    norm = lambda key, shape, s: (jax.random.normal(k[key], shape) * s)  # noqa: E731
-    d, dh = cfg.d_model, _d_head(cfg)
-    # A norm's weight multiplies as it stands (from one) or as 1 + w (from
-    # zero): the same function at birth.
-    gain = jnp.zeros if cfg.norm_offset else jnp.ones
+    d = cfg.d_model
     params: Dict[str, Any] = {
         # A tied table is the unembedding too: 0.02 keeps its logits
         # small. An untied one feeds the residual stream alone, whose
         # branches (fan-in scaled) add outputs of unit size: at 0.02 a
         # token's own row would be a fiftieth of the stream after the
         # first block, and every token's hidden state nearly the same.
-        "embed": norm(next(ki), (cfg.vocab, d),
-                      0.02 if cfg.tied_head else 1.0 / cfg.embed_scale),
-        "lnf": gain((d,)),
+        "embed": draw.normal((cfg.vocab, d),
+                             0.02 if cfg.tied_head else 1.0 / cfg.embed_scale),
+        "lnf": draw.gain((d,)),
         "layers": [],
     }
     if not cfg.tied_head:
-        params["head"] = norm(next(ki), (cfg.vocab, d), d ** -0.5)
+        params["head"] = draw.normal((cfg.vocab, d), d ** -0.5)
     for i in range(cfg.n_layers):
-        layer = {"ln1": gain((d,)), "ln2": gain((d,))}
+        layer = {"ln1": draw.gain((d,)), "ln2": draw.gain((d,))}
         if cfg.post_norms:
-            layer.update(post_ln1=gain((d,)), post_ln2=gain((d,)))
-        if layer_kind(cfg, i) == "gdn":
-            g = cfg.gdn
-            nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
-            # Columns [q | k | v | z], heads-major inside each; [b | a].
-            layer["gdn_wqkvz"] = norm(next(ki), (d, 2 * nk + 2 * nv),
-                                      d ** -0.5)
-            layer["gdn_wba"] = norm(next(ki), (d, 2 * g.n_v_heads), d ** -0.5)
-            layer["gdn_conv"] = norm(next(ki), (g.conv_width, 2 * nk + nv),
-                                     g.conv_width ** -0.5)
-            # As the published module draws them: A uniform in (0, 16),
-            # kept as its logarithm; the step's bias at one.
-            layer["gdn_a_log"] = jnp.log(jax.random.uniform(
-                k[next(ki)], (g.n_v_heads,), minval=1e-3, maxval=16.0))
-            layer["gdn_dt_bias"] = jnp.ones((g.n_v_heads,))
-            layer["gdn_norm"] = jnp.ones((g.d_v,))      # plain weight
-            layer["gdn_wout"] = norm(next(ki), (nv, d), nv ** -0.5)
-        elif layer_kind(cfg, i) == "kda":
-            a = cfg.kda
-            n, r = a.n_heads * a.d_head, a.d_head
-            # Columns [q | k | v], heads-major inside each.
-            layer["kda_wqkv"] = norm(next(ki), (d, 3 * n), d ** -0.5)
-            layer["kda_conv"] = norm(next(ki), (a.conv_width, 3 * n),
-                                     a.conv_width ** -0.5)
-            # The low-rank pairs of the decay (f) and of the output gate.
-            layer["kda_wf_down"] = norm(next(ki), (d, r), d ** -0.5)
-            layer["kda_wf_up"] = norm(next(ki), (r, n), r ** -0.5)
-            layer["kda_wg_down"] = norm(next(ki), (d, r), d ** -0.5)
-            layer["kda_wg_up"] = norm(next(ki), (r, n), r ** -0.5)
-            layer["kda_wbeta"] = norm(next(ki), (d, a.n_heads), d ** -0.5)
-            # As the published module draws them: A uniform in (1, 16), a
-            # head, kept as its logarithm; the step's bias, a channel, at
-            # one.
-            layer["kda_a_log"] = jnp.log(jax.random.uniform(
-                k[next(ki)], (a.n_heads,), minval=1.0, maxval=16.0))
-            layer["kda_dt_bias"] = jnp.ones((n,))
-            layer["kda_norm"] = jnp.ones((a.d_head,))
-            layer["kda_wout"] = norm(next(ki), (n, d), n ** -0.5)
-        elif layer_kind(cfg, i) == "mla":
-            m, H = cfg.mla, cfg.n_heads
-            layer["mla_wq"] = norm(next(ki), (d, H * (m.d_nope + m.d_shared)),
-                                   d ** -0.5)
-            # Columns [latent | shared key part].
-            layer["mla_wkva"] = norm(next(ki), (d, m.kv_rank + m.d_shared),
-                                     d ** -0.5)
-            layer["mla_kv_norm"] = gain((m.kv_rank,))
-            # Columns heads-major, each head's [key part | value].
-            layer["mla_wkvb"] = norm(next(ki),
-                                     (m.kv_rank, H * (m.d_nope + m.d_v)),
-                                     m.kv_rank ** -0.5)
-            layer["mla_wo"] = norm(next(ki), (H * m.d_v, d),
-                                   (H * m.d_v) ** -0.5)
-        elif _packed_qkv(cfg):
-            layer["wqkv"] = norm(next(ki), (d, 3 * d), d ** -0.5)
-            layer["wo"] = norm(next(ki), (d, d), d ** -0.5)
-        else:
-            # Head-major columns, as wqkv: a tp column slice holds whole
-            # heads (with attn_gate, each head's q then its gate).
-            nq, nkv = cfg.n_heads * dh, _kv_heads(cfg) * dh
-            layer["wq"] = norm(next(ki), (d, nq * (2 if cfg.attn_gate else 1)),
-                               d ** -0.5)
-            layer["wk"] = norm(next(ki), (d, nkv), d ** -0.5)
-            layer["wv"] = norm(next(ki), (d, nkv), d ** -0.5)
-            layer["wo"] = norm(next(ki), (nq, d), nq ** -0.5)
-            if cfg.qk_norm:
-                layer["q_norm"] = gain((dh,))
-                layer["k_norm"] = gain((dh,))
-        if cfg.indexer and layer_kind(cfg, i) == "attn":
-            ix = cfg.indexer
-            layer["idx_wq"] = norm(next(ki), (d, ix.n_heads * ix.d_head),
-                                   d ** -0.5)
-            layer["idx_wk"] = norm(next(ki), (d, ix.d_head), d ** -0.5)
-            layer["idx_ww"] = norm(next(ki), (d, ix.n_heads), d ** -0.5)
-            layer["idx_k_scale"] = jnp.ones((ix.d_head,))
-            layer["idx_k_bias"] = jnp.zeros((ix.d_head,))
+            layer.update(post_ln1=draw.gain((d,)), post_ln2=draw.gain((d,)))
+        layer.update(_KINDS[layer_kind(cfg, i)].leaves(cfg, draw))
         # Experts: a leading dim of the experts held, sharded over ep.
         experts = not _dense_ffn(cfg, i)
         lead = (_held(cfg),) if experts else ()
         ff = cfg.d_ff if experts or not cfg.dense_layers else cfg.dense_ff
+        up, down = ("experts", "experts") if experts else ("col", "row")
         if experts:
-            layer["router"] = norm(next(ki), (d, cfg.n_experts), d ** -0.5)
+            layer["router"] = draw.normal((d, cfg.n_experts), d ** -0.5)
             if cfg.moe_select_bias:
-                layer["router_bias"] = jnp.zeros((cfg.n_experts,))
+                layer["router_bias"] = draw.zeros((cfg.n_experts,))
         if cfg.mlp == "swiglu":
-            layer["w_gate"] = norm(next(ki), lead + (d, ff), d ** -0.5)
-            layer["w_up"] = norm(next(ki), lead + (d, ff), d ** -0.5)
-            layer["w_down"] = norm(next(ki), lead + (ff, d), ff ** -0.5)
+            layer["w_gate"] = draw.normal(lead + (d, ff), d ** -0.5, up)
+            layer["w_up"] = draw.normal(lead + (d, ff), d ** -0.5, up)
+            layer["w_down"] = draw.normal(lead + (ff, d), ff ** -0.5, down)
         else:
-            layer["w1"] = norm(next(ki), lead + (d, ff), d ** -0.5)
-            layer["w2"] = norm(next(ki), lead + (ff, d), ff ** -0.5)
+            layer["w1"] = draw.normal(lead + (d, ff), d ** -0.5, up)
+            layer["w2"] = draw.normal(lead + (ff, d), ff ** -0.5, down)
         if cfg.shared_expert_ff and experts:
             f = cfg.shared_expert_ff
-            layer["shared_gate"] = norm(next(ki), (d, f), d ** -0.5)
-            layer["shared_up"] = norm(next(ki), (d, f), d ** -0.5)
-            layer["shared_down"] = norm(next(ki), (f, d), f ** -0.5)
+            layer["shared_gate"] = draw.normal((d, f), d ** -0.5)
+            layer["shared_up"] = draw.normal((d, f), d ** -0.5)
+            layer["shared_down"] = draw.normal((f, d), f ** -0.5)
             if cfg.shared_expert_gate:
-                layer["shared_w"] = norm(next(ki), (d, 1), d ** -0.5)
+                layer["shared_w"] = draw.normal((d, 1), d ** -0.5)
         params["layers"].append(layer)
     return params
 
 
-_GDN_LEAVES = ("gdn_wqkvz", "gdn_wba", "gdn_conv", "gdn_a_log", "gdn_dt_bias",
-               "gdn_norm", "gdn_wout")
-_KDA_LEAVES = ("kda_wqkv", "kda_conv", "kda_wf_down", "kda_wf_up",
-               "kda_wg_down", "kda_wg_up", "kda_wbeta", "kda_a_log",
-               "kda_dt_bias", "kda_norm", "kda_wout")
-_MLA_LEAVES = ("mla_wq", "mla_wkva", "mla_kv_norm", "mla_wkvb", "mla_wo")
-_SHARED_LEAVES = ("shared_gate", "shared_up", "shared_down", "shared_w")
+def init_params(rng, cfg: TransformerConfig) -> Dict:
+    """Global (unsharded-shape) parameter pytree; place with
+    :func:`param_specs` + ``jax.device_put`` before use. Layer ``i``'s
+    entry has the leaves of its kind (:func:`layer_kind`)."""
+    own, per_layer = _keys_split(cfg)
+    return _walk(cfg, _Draw(cfg, jax.random.split(
+        rng, own + per_layer * cfg.n_layers)))
 
 
 def param_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     """PartitionSpec tree matching :func:`init_params`: Megatron column
     (out-dim) / row (in-dim) sharding over tp; experts over ep; everything
-    else replicated (dp/sp replicate params; a "gdn", "kda" or "mla"
-    layer's mixer and the shared expert are replicated whole)."""
-    tp = "tp" if "tp" in _axes(mesh) else None
-    ep = "ep" if "ep" in _axes(mesh) else None
-    col, row = P(None, tp), P(tp, None)   # heads / ff columns shard over tp
-    specs: Dict[str, Any] = {"embed": P(), "lnf": P(), "layers": []}
-    if not cfg.tied_head:
-        specs["head"] = P()
-    up, down = ("w_gate", "w_up"), ("w_down",)
-    if cfg.mlp != "swiglu":
-        up, down = ("w1",), ("w2",)
-    for i in range(cfg.n_layers):
-        layer = {"ln1": P(), "ln2": P()}
-        if cfg.post_norms:
-            layer.update(post_ln1=P(), post_ln2=P())
-        kind = layer_kind(cfg, i)
-        if kind in ("gdn", "kda", "mla"):
-            layer.update({name: P() for name in {
-                "gdn": _GDN_LEAVES, "kda": _KDA_LEAVES,
-                "mla": _MLA_LEAVES}[kind]})
-        elif _packed_qkv(cfg):
-            layer.update(wqkv=col, wo=row)
-        else:
-            layer.update(wq=col, wk=col, wv=col, wo=row)
-            if cfg.qk_norm:
-                layer.update(q_norm=P(), k_norm=P())
-        if cfg.indexer and layer_kind(cfg, i) == "attn":
-            layer.update({name: P() for name in (
-                "idx_wq", "idx_wk", "idx_ww", "idx_k_scale", "idx_k_bias")})
-        if not _dense_ffn(cfg, i):
-            layer["router"] = P()
-            if cfg.moe_select_bias:
-                layer["router_bias"] = P()
-            layer.update({name: P(ep, None, None) for name in up + down})
-            if cfg.shared_expert_ff:
-                layer.update({name: P() for name in _SHARED_LEAVES
-                              if name != "shared_w"
-                              or cfg.shared_expert_gate})
-        else:
-            layer.update({name: col for name in up})
-            layer.update({name: row for name in down})
-        specs["layers"].append(layer)
-    return specs
+    else replicated (dp/sp replicate params; a mixer's leaves are
+    replicated unless its kind names their shard)."""
+    return _walk(cfg, _Specs(mesh))
 
 
 def mla_from_interleaved(params, cfg: TransformerConfig,
@@ -607,19 +508,414 @@ def shared_expert(layer, h, dtype):
                 * gate).astype(dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class _Ctx:
+    """What a mixer sees beside its layer: the configuration, the mesh's
+    share of the heads, and ``with_masks`` (hand back in ``extras`` what
+    went into the attention or the rule and what came out)."""
+    cfg: TransformerConfig
+    has_tp: bool
+    has_sp: bool
+    tp_size: int
+    n_heads_local: int
+    d_head: int
+    with_masks: bool
+
+
+def _attn_leaves(cfg: TransformerConfig, draw, indexer=True) -> Dict:
+    """The softmax attention's projections: one packed ``wqkv`` (the dense
+    block) or separate ones; and the indexer's, if any and ``indexer``."""
+    d, dh = cfg.d_model, _d_head(cfg)
+    if _packed_qkv(cfg):
+        return {"wqkv": draw.normal((d, 3 * d), d ** -0.5, "col"),
+                "wo": draw.normal((d, d), d ** -0.5, "row")}
+    # Head-major columns, as wqkv: a tp column slice holds whole heads
+    # (with attn_gate, each head's q then its gate).
+    nq, nkv = cfg.n_heads * dh, _kv_heads(cfg) * dh
+    layer = {"wq": draw.normal((d, nq * (2 if cfg.attn_gate else 1)),
+                               d ** -0.5, "col"),
+             "wk": draw.normal((d, nkv), d ** -0.5, "col"),
+             "wv": draw.normal((d, nkv), d ** -0.5, "col"),
+             "wo": draw.normal((nq, d), nq ** -0.5, "row")}
+    if cfg.qk_norm:
+        layer.update(q_norm=draw.gain((dh,)), k_norm=draw.gain((dh,)))
+    if cfg.indexer and indexer:
+        ix = cfg.indexer
+        layer.update(
+            idx_wq=draw.normal((d, ix.n_heads * ix.d_head), d ** -0.5),
+            idx_wk=draw.normal((d, ix.d_head), d ** -0.5),
+            idx_ww=draw.normal((d, ix.n_heads), d ** -0.5),
+            idx_k_scale=draw.ones((ix.d_head,)),
+            idx_k_bias=draw.zeros((ix.d_head,)))
+    return layer
+
+
+def _packed_attention(layer, h, ctx: _Ctx):
+    """The dense block's attention from its packed ``wqkv``: h [B, T, D]
+    -> [B, T, H·dh] of this tp shard's heads."""
+    from ..ops.pallas_attention import (flash_attention,
+                                        flash_attention_qkv,
+                                        qkv_flash_tilable)
+    cfg, n_heads_local, d_head = ctx.cfg, ctx.n_heads_local, ctx.d_head
+    qkv = h @ layer["wqkv"].astype(cfg.dtype)     # [B, T, 3·D/tp]
+    B, T, _ = qkv.shape
+    # HEAD-major column layout [D, H, 3, dh]: a tp column-slice holds
+    # whole heads (each with its own q,k,v), so the sharded model
+    # computes the SAME function as tp=1 from the same weights
+    # (checkpoints stay portable across mesh shapes).
+    if (not ctx.has_sp and cfg.attn_backend == "pallas"
+            and qkv_flash_tilable(T, d_head)):
+        # Packed path: the kernel consumes the projection output
+        # directly (head-major columns) and returns [B, T, H·dh] — no
+        # [B,T,H,dh] <-> [BH,T,dh] transposes on either side
+        # (~11 ms/step of layout copies at the LM bench config).
+        return flash_attention_qkv(qkv, n_heads_local,
+                                   causal=True).astype(cfg.dtype)
+    qkv = qkv.reshape(B, T, n_heads_local, 3, d_head)
+    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    if ctx.has_sp:
+        attn = ring_attention(q, k, v, axis_name="sp", causal=True)
+    else:
+        # Single-shard attention: the Pallas blockwise kernel by
+        # default (scores never hit HBM in forward OR backward);
+        # untilable shapes fall back to XLA dense inside.
+        attn = flash_attention(q, k, v, causal=True,
+                               backend=cfg.attn_backend).astype(cfg.dtype)
+    return attn.reshape(B, T, n_heads_local * d_head)
+
+
+def _projected_attention(layer, h, extras, ctx: _Ctx, rope_theta,
+                         window=None):
+    """Separate q/k/v projections: grouped key/value heads, per-head
+    RMSNorm, RoPE of base ``rope_theta``, and dense, windowed (``window``
+    keys; never the [T, T] scores under the "pallas" backend) or
+    indexer-selected attention."""
+    from ..ops.pallas_attention import flash_attention
+    from ..ops.sparse_attention import dsa_attention
+    cfg, n_heads_local, d_head = ctx.cfg, ctx.n_heads_local, ctx.d_head
+    B, T, _ = h.shape
+    kv_local = _kv_heads(cfg) // ctx.tp_size
+
+    def heads(w, n, width=d_head):
+        return (h @ layer[w].astype(cfg.dtype)).reshape(B, T, n, width)
+    if cfg.attn_gate:
+        q = heads("wq", n_heads_local, 2 * d_head)
+        q, gate = q[..., :d_head], q[..., d_head:]
+    else:
+        q = heads("wq", n_heads_local)
+    k, v = heads("wk", kv_local), heads("wv", kv_local)
+    if cfg.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], cfg.norm_offset)
+        k = _rms_norm(k, layer["k_norm"], cfg.norm_offset)
+    if rope_theta:
+        q = _rope(q, rope_theta, cfg.rope_fraction)
+        k = _rope(k, rope_theta, cfg.rope_fraction)
+    if cfg.indexer:
+        ix = cfg.indexer
+        hs = lax.stop_gradient(h)
+        with jax.named_scope("attn.indexer"):
+            qi = (hs @ layer["idx_wq"].astype(cfg.dtype)).reshape(
+                B, T, ix.n_heads, ix.d_head)
+            ki = _layer_norm(hs @ layer["idx_wk"].astype(cfg.dtype),
+                             layer["idx_k_scale"], layer["idx_k_bias"])
+            if cfg.rope_theta:
+                qi, ki = _rope(qi, cfg.rope_theta), _rope(ki, cfg.rope_theta)
+            w = (hs @ layer["idx_ww"].astype(cfg.dtype)).astype(
+                jnp.float32) * (ix.n_heads * ix.d_head) ** -0.5
+        attn, extras["kl_sum"], extras["kl"], mask = dsa_attention(
+            q, k, v, qi, ki, w, topk=ix.topk, backend=cfg.attn_backend)
+        if ctx.with_masks:
+            extras["mask"] = mask
+    else:
+        group = n_heads_local // kv_local
+        qkv = (q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2))
+        attn = flash_attention(*qkv, causal=True, backend=cfg.attn_backend,
+                               fallback=window is None, window=window)
+        if ctx.with_masks:
+            extras["attn_in"], extras["attn_o"] = qkv, attn
+    if cfg.attn_gate:
+        attn = _output_gate(attn, gate)
+    return attn.astype(cfg.dtype).reshape(B, T, n_heads_local * d_head)
+
+
+def _attn_mixer(li, layer, h, extras, ctx: _Ctx):
+    cfg = ctx.cfg
+    # Among layers of several kinds the softmax layer has a scope of its
+    # own.
+    with (jax.named_scope("attn.full") if cfg.layer_pattern
+          else contextlib.nullcontext()):
+        attn = (_packed_attention(layer, h, ctx) if _packed_qkv(cfg) else
+                _projected_attention(layer, h, extras, ctx, cfg.rope_theta))
+        proj = attn @ layer["wo"].astype(cfg.dtype)
+    if ctx.has_tp:
+        proj = lax.psum(proj, "tp")               # row-parallel combine
+    return proj
+
+
+def _swa_mixer(li, layer, h, extras, ctx: _Ctx):
+    from ..ops.pallas_attention import record_tile_pairs
+    cfg, swa = ctx.cfg, ctx.cfg.swa
+    record_tile_pairs(li, h.shape[1], swa.window)
+    with jax.named_scope("attn.swa"):
+        return _projected_attention(
+            layer, h, extras, ctx, swa.rope_theta,
+            swa.window) @ layer["wo"].astype(cfg.dtype)
+
+
+def _gdn_leaves(cfg: TransformerConfig, draw) -> Dict:
+    g, d = cfg.gdn, cfg.d_model
+    nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
+    # Columns [q | k | v | z], heads-major inside each; [b | a].
+    return {"gdn_wqkvz": draw.normal((d, 2 * nk + 2 * nv), d ** -0.5),
+            "gdn_wba": draw.normal((d, 2 * g.n_v_heads), d ** -0.5),
+            "gdn_conv": draw.normal((g.conv_width, 2 * nk + nv),
+                                    g.conv_width ** -0.5),
+            # As the published module draws them: A uniform in (0, 16),
+            # kept as its logarithm; the step's bias at one.
+            "gdn_a_log": draw.log_uniform((g.n_v_heads,), 1e-3, 16.0),
+            "gdn_dt_bias": draw.ones((g.n_v_heads,)),
+            "gdn_norm": draw.ones((g.d_v,)),      # plain weight
+            "gdn_wout": draw.normal((nv, d), nv ** -0.5)}
+
+
+def _check_gdn(g: GatedDeltaNet):
+    if g.n_v_heads % g.n_k_heads:
+        raise ValueError(f"gdn: {g.n_v_heads} value heads do not divide "
+                         f"over {g.n_k_heads} key heads")
+
+
+def _gdn_mixer(li, layer, h, extras, ctx: _Ctx):
+    """The Gated DeltaNet mixer: h [B, T, D] -> [B, T, D]."""
+    from ..ops.causal_conv import causal_conv_silu
+    from ..ops.gated_delta import gated_delta_rule, record_saved
+    cfg = ctx.cfg
+    g, f32 = cfg.gdn, jnp.float32
+    B, T, _ = h.shape
+    nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
+    with jax.named_scope("gdn.proj"):
+        qkvz = h @ layer["gdn_wqkvz"].astype(cfg.dtype)
+        ba = (h @ layer["gdn_wba"].astype(cfg.dtype)).astype(f32)
+    # What lies between the projections and the rule, and between the
+    # rule and the output projection, is elementwise over 12288 columns a
+    # row: it is recomputed in the backward from the projections' outputs
+    # (taken whole: a slice would be a copy; the convolution reads its
+    # 2 nk + nv columns of qkvz where they lie), not saved (3.4 GB over
+    # three layers at 2 x 8192 rows, compiled for a v5e).
+    @jax.checkpoint
+    def conv_and_gates(qkvz, ba, taps, a_log, dt_bias):
+        with jax.named_scope("gdn.conv"):
+            qkv = causal_conv_silu(qkvz, taps, backend=g.backend, layer=li)
+        with jax.named_scope("gdn.scan"):
+            q = qkv[..., :nk].reshape(B, T, g.n_k_heads, g.d_k)
+            k = qkv[..., nk:2 * nk].reshape(B, T, g.n_k_heads, g.d_k)
+            v = qkv[..., 2 * nk:].reshape(B, T, g.n_v_heads, g.d_v)
+            beta = jax.nn.sigmoid(ba[..., :g.n_v_heads])
+            decay = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                ba[..., g.n_v_heads:] + dt_bias.astype(f32))
+            return (_l2_norm(q) * g.d_k ** -0.5, _l2_norm(k), v, decay, beta)
+
+    @jax.checkpoint
+    def gated_norm(o, qkvz, scale):
+        z = qkvz[..., 2 * nk + nv:].reshape(B, T, g.n_v_heads, g.d_v)
+        o = _rms_norm(o.astype(f32), scale) * jax.nn.silu(z.astype(f32))
+        return o.astype(cfg.dtype).reshape(B, T, nv)
+
+    q, k, v, decay, beta = conv_and_gates(
+        qkvz, ba, layer["gdn_conv"], layer["gdn_a_log"], layer["gdn_dt_bias"])
+    with jax.named_scope("gdn.scan"):
+        record_saved(li, q.shape, g.n_v_heads, g.d_v,
+                     jnp.dtype(cfg.dtype).itemsize, g.chunk)
+        # Each key head serves n_v_heads / n_k_heads consecutive value
+        # heads (inside the rule).
+        o = gated_delta_rule(q, k, v, decay, beta, chunk=g.chunk,
+                             backend=g.backend, layer=li)
+        if ctx.with_masks:
+            extras["gdn_o"] = o
+    with jax.named_scope("gdn.out"):
+        return gated_norm(o, qkvz, layer["gdn_norm"]) \
+            @ layer["gdn_wout"].astype(cfg.dtype)
+
+
+def _kda_leaves(cfg: TransformerConfig, draw) -> Dict:
+    a, d = cfg.kda, cfg.d_model
+    n, r = a.n_heads * a.d_head, a.d_head
+    # Columns [q | k | v], heads-major inside each; then the low-rank
+    # pairs of the decay (f) and of the output gate.
+    return {"kda_wqkv": draw.normal((d, 3 * n), d ** -0.5),
+            "kda_conv": draw.normal((a.conv_width, 3 * n),
+                                    a.conv_width ** -0.5),
+            "kda_wf_down": draw.normal((d, r), d ** -0.5),
+            "kda_wf_up": draw.normal((r, n), r ** -0.5),
+            "kda_wg_down": draw.normal((d, r), d ** -0.5),
+            "kda_wg_up": draw.normal((r, n), r ** -0.5),
+            "kda_wbeta": draw.normal((d, a.n_heads), d ** -0.5),
+            # As the published module draws them: A uniform in (1, 16), a
+            # head, kept as its logarithm; the step's bias, a channel, at
+            # one.
+            "kda_a_log": draw.log_uniform((a.n_heads,), 1.0, 16.0),
+            "kda_dt_bias": draw.ones((n,)),
+            "kda_norm": draw.ones((a.d_head,)),
+            "kda_wout": draw.normal((n, d), n ** -0.5)}
+
+
+def _kda_mixer(li, layer, h, extras, ctx: _Ctx):
+    """The Kimi Delta Attention mixer: h [B, T, D] -> [B, T, D]."""
+    from ..ops.causal_conv import causal_conv_silu
+    from ..ops.gated_delta import gated_delta_rule, record_saved
+    cfg = ctx.cfg
+    a, f32, eps = cfg.kda, jnp.float32, cfg.norm_eps
+    B, T, _ = h.shape
+    H, dh = a.n_heads, a.d_head
+    n = H * dh
+
+    def dot(x, w):
+        return x @ layer[w].astype(cfg.dtype)
+    with jax.named_scope("kda.proj"):
+        qkv = dot(h, "kda_wqkv")
+        f_low, g_low = dot(h, "kda_wf_down"), dot(h, "kda_wg_down")
+        b = dot(h, "kda_wbeta").astype(f32)
+    # As in the Gated DeltaNet mixer, what is elementwise between a
+    # projection and the rule, and between the rule and the output
+    # projection, is recomputed in the backward. The low-rank pairs' second
+    # halves are in there too: what is kept of the decay and of the output
+    # gate is their d_head-wide first half, not H x d_head columns a row.
+    @jax.checkpoint
+    def conv_and_gates(qkv, f_low, b, taps, wf_up, a_log, dt_bias):
+        with jax.named_scope("kda.proj"):
+            f = (f_low @ wf_up.astype(cfg.dtype)).astype(f32)
+        with jax.named_scope("kda.conv"):
+            qkv = causal_conv_silu(qkv, taps, backend=a.backend, layer=li)
+        with jax.named_scope("kda.scan"):
+            q, k, v = (qkv[..., j * n:(j + 1) * n].reshape(B, T, H, dh)
+                       for j in range(3))
+            decay = -jnp.exp(a_log.astype(f32))[:, None] \
+                * jax.nn.softplus(f.reshape(B, T, H, dh)
+                                  + dt_bias.astype(f32).reshape(H, dh))
+            return (_l2_norm(q) * dh ** -0.5, _l2_norm(k), v, decay,
+                    jax.nn.sigmoid(b))
+
+    @jax.checkpoint
+    def gated_norm(o, g_low, wg_up, scale):
+        with jax.named_scope("kda.proj"):
+            gate = (g_low @ wg_up.astype(cfg.dtype)).astype(f32)
+        with jax.named_scope("kda.out"):
+            o = _rms_norm(o.astype(f32), scale, eps=eps) \
+                * jax.nn.sigmoid(gate.reshape(B, T, H, dh))
+            return o.astype(cfg.dtype).reshape(B, T, n)
+
+    q, k, v, decay, beta = conv_and_gates(
+        qkv, f_low, b, layer["kda_conv"], layer["kda_wf_up"],
+        layer["kda_a_log"], layer["kda_dt_bias"])
+    with jax.named_scope("kda.scan"):
+        record_saved(li, q.shape, H, dh, jnp.dtype(cfg.dtype).itemsize,
+                     a.chunk, per_channel=True)
+        o = gated_delta_rule(q, k, v, decay, beta, chunk=a.chunk,
+                             backend=a.backend, layer=li)
+        if ctx.with_masks:
+            extras["kda_in"], extras["kda_o"] = (q, k, v, decay, beta), o
+    out = gated_norm(o, g_low, layer["kda_wg_up"], layer["kda_norm"])
+    with jax.named_scope("kda.out"):
+        return dot(out, "kda_wout")
+
+
+def _mla_leaves(cfg: TransformerConfig, draw) -> Dict:
+    m, H, d = cfg.mla, cfg.n_heads, cfg.d_model
+    return {"mla_wq": draw.normal((d, H * (m.d_nope + m.d_shared)),
+                                  d ** -0.5),
+            # Columns [latent | shared key part].
+            "mla_wkva": draw.normal((d, m.kv_rank + m.d_shared), d ** -0.5),
+            "mla_kv_norm": draw.gain((m.kv_rank,)),
+            # Columns heads-major, each head's [key part | value].
+            "mla_wkvb": draw.normal((m.kv_rank, H * (m.d_nope + m.d_v)),
+                                    m.kv_rank ** -0.5),
+            "mla_wo": draw.normal((H * m.d_v, d), (H * m.d_v) ** -0.5)}
+
+
+def _mla_mixer(li, layer, h, extras, ctx: _Ctx):
+    """Latent attention: h [B, T, D] -> [B, T, D].
+    Never the [T, T] scores: a shape the flash kernels cannot tile is an
+    error under ``attn_backend="pallas"``."""
+    from ..ops.pallas_attention import flash_attention
+    cfg = ctx.cfg
+    m, H = cfg.mla, cfg.n_heads
+    B, T, _ = h.shape
+
+    def dot(x, w):
+        return x @ layer[w].astype(cfg.dtype)
+    with jax.named_scope("attn.mla"):
+        q = dot(h, "mla_wq").reshape(B, T, H, m.d_nope + m.d_shared)
+        latent = dot(h, "mla_wkva")
+        kv = dot(_rms_norm(latent[..., :m.kv_rank], layer["mla_kv_norm"],
+                           cfg.norm_offset, cfg.norm_eps), "mla_wkvb").reshape(
+            B, T, H, m.d_nope + m.d_v)
+        shared = latent[:, :, None, m.kv_rank:]
+        if m.rope_theta:
+            # The shared key part is rotated ONCE a token, before it goes
+            # to the heads: its gradient is summed over the heads first.
+            with jax.named_scope("mla.rope"):
+                q = jnp.concatenate(
+                    [q[..., :m.d_nope],
+                     _rope(q[..., m.d_nope:], m.rope_theta)], axis=-1)
+                shared = _rope(shared, m.rope_theta)
+        # One shared key part a token, the same for every head.
+        shared = jnp.broadcast_to(shared, (B, T, H, m.d_shared))
+        k = jnp.concatenate([kv[..., :m.d_nope], shared], axis=-1)
+        v = kv[..., m.d_nope:]
+        o = flash_attention(q, k, v, causal=True, backend=cfg.attn_backend,
+                            fallback=False)
+        if ctx.with_masks:
+            extras["mla_in"], extras["mla_o"] = (q, k, v), o
+        return dot(o.astype(cfg.dtype).reshape(B, T, H * m.d_v), "mla_wo")
+
+
+class _Kind(NamedTuple):
+    """A layer kind, all this file knows of it: ``field``, the
+    :class:`TransformerConfig` field that describes its layers (None: the
+    configuration's own fields do), which ``check`` holds to more; the mesh
+    axes it ``refuses``, and ``why``; ``leaves(cfg, draw)``, its mixer's
+    parameters in the order a seed draws them (:class:`_Draw`);
+    ``forward(li, layer, h, extras, ctx)``, the mixer, h [B, T, D] ->
+    [B, T, D] under the kind's own scopes and counters."""
+    field: Optional[str]
+    leaves: Callable[[TransformerConfig, Any], Dict]
+    forward: Callable[..., Any]
+    refuses: Tuple[str, ...] = ()
+    why: str = ""
+    check: Callable[[Any], None] = lambda described: None
+
+
+# Why a layer whose state runs along the sequence refuses sp and tp.
+_STATE_WHY = ("layer's state runs over the whole sequence and its heads "
+              "are not sharded: no sp and no tp (dp and ep only)")
+
+# The kinds ``layer_pattern`` may name.
+_KINDS = {
+    "attn": _Kind(None, _attn_leaves, _attn_mixer),
+    "gdn": _Kind("gdn", _gdn_leaves, _gdn_mixer, ("sp", "tp"),
+                 f"a 'gdn' {_STATE_WHY}", _check_gdn),
+    "kda": _Kind("kda", _kda_leaves, _kda_mixer, ("sp", "tp"),
+                 f"a 'kda' {_STATE_WHY}"),
+    "mla": _Kind("mla", _mla_leaves, _mla_mixer, ("sp", "tp"),
+                 "an 'mla' layer expands its latent for every head over the "
+                 "whole sequence: no sp and no tp (dp and ep only)"),
+    "swa": _Kind("swa", functools.partial(_attn_leaves, indexer=False),
+                 _swa_mixer, ("sp", "tp"),
+                 "an 'swa' layer's band is not passed around an sp ring and "
+                 "its output is not summed over tp: no sp and no tp (dp and "
+                 "ep only)"),
+}
+
+
 def _check_mesh(cfg: TransformerConfig, axes):
     """Refuse the mesh axes a configuration's layers cannot take."""
-    for kind, why in (("gdn", "state runs over the whole sequence"),
-                      ("kda", "state runs over the whole sequence"),
-                      ("mla", "latent is expanded for all heads at once"),
-                      ("swa", "band is not split over chips")):
-        if kind in _kinds(cfg) and ("sp" in axes or "tp" in axes):
-            raise NotImplementedError(
-                f"a {kind!r} layer's {why} and its heads are not sharded: "
-                f"no sp and no tp (dp and ep only)")
+    kinds = {layer_kind(cfg, i) for i in range(cfg.n_layers)}
+    for kind, entry in _KINDS.items():
+        if kind in kinds and set(entry.refuses) & set(axes):
+            raise NotImplementedError(entry.why)
     if cfg.dense_layers and "tp" in axes:
         raise NotImplementedError(
             "dense_layers beside expert layers are not sharded over tp")
+    # The "attn" kind's refusals follow from the fields that describe it.
     if not _packed_qkv(cfg) and "sp" in axes:
         raise NotImplementedError(
             "ring attention over sp takes the dense block only (no RoPE "
@@ -639,7 +935,7 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     differentiates, and per row, [B, T], which it does not), the routing
     load ``held_load`` / ``absent`` and the experts each token kept
     ``ids``, and with ``with_masks`` the int8 selection ``mask`` and a
-    "gdn" or "kda" layer's rule output ``gdn_o`` / ``kda_o`` [B, T, Hv, dv]
+    delta-rule layer's rule output ``gdn_o`` / ``kda_o`` [B, T, Hv, dv]
     and an "mla" layer's attention output ``mla_o`` [B, T, H, d_v], with
     what went into them: ``kda_in`` (q, k, v, g, beta as the rule takes
     them) and ``mla_in`` (q, k, v as the attention does).
@@ -651,278 +947,17 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     the lowest layer to the highest."""
     axes = _axes(mesh)
     _check_mesh(cfg, axes)
-    has_tp = "tp" in axes
-    has_sp = "sp" in axes
     tp_size = mesh.shape.get("tp", 1)
-    n_heads_local = cfg.n_heads // tp_size
-    d_head = _d_head(cfg)
+    ctx = _Ctx(cfg=cfg, has_tp="tp" in axes, has_sp="sp" in axes,
+               tp_size=tp_size,
+               n_heads_local=cfg.n_heads // tp_size, d_head=_d_head(cfg),
+               with_masks=with_masks)
     offset, eps = cfg.norm_offset, cfg.norm_eps
-
-    def _attention(layer, h, extras):
-        from ..ops.pallas_attention import (flash_attention,
-                                            flash_attention_qkv,
-                                            qkv_flash_tilable)
-        if not _packed_qkv(cfg):
-            return _projected_attention(layer, h, extras)
-        qkv = h @ layer["wqkv"].astype(cfg.dtype)     # [B, T, 3·D/tp]
-        B, T, _ = qkv.shape
-        # HEAD-major column layout [D, H, 3, dh]: a tp column-slice holds
-        # whole heads (each with its own q,k,v), so the sharded model
-        # computes the SAME function as tp=1 from the same weights
-        # (checkpoints stay portable across mesh shapes).
-        if (not has_sp and cfg.attn_backend == "pallas"
-                and qkv_flash_tilable(T, d_head)):
-            # Packed path: the kernel consumes the projection output
-            # directly (head-major columns) and returns [B, T, H·dh] — no
-            # [B,T,H,dh] <-> [BH,T,dh] transposes on either side
-            # (~11 ms/step of layout copies at the LM bench config).
-            return flash_attention_qkv(qkv, n_heads_local,
-                                       causal=True).astype(cfg.dtype)
-        qkv = qkv.reshape(B, T, n_heads_local, 3, d_head)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        if has_sp:
-            attn = ring_attention(q, k, v, axis_name="sp", causal=True)
-        else:
-            # Single-shard attention: the Pallas blockwise kernel by
-            # default (scores never hit HBM in forward OR backward);
-            # untilable shapes fall back to XLA dense inside.
-            attn = flash_attention(q, k, v, causal=True,
-                                   backend=cfg.attn_backend
-                                   ).astype(cfg.dtype)
-        return attn.reshape(B, T, n_heads_local * d_head)
-
-    def _projected_attention(layer, h, extras, rope_theta=cfg.rope_theta,
-                             window=None):
-        """Separate q/k/v projections: grouped key/value heads, per-head
-        RMSNorm, RoPE of base ``rope_theta``, and dense, windowed
-        (``window`` keys; never the [T, T] scores under the "pallas"
-        backend) or indexer-selected attention."""
-        from ..ops.pallas_attention import flash_attention
-        from ..ops.sparse_attention import dsa_attention
-        B, T, _ = h.shape
-        kv_local = _kv_heads(cfg) // tp_size
-
-        def heads(w, n, width=d_head):
-            return (h @ layer[w].astype(cfg.dtype)).reshape(B, T, n, width)
-        if cfg.attn_gate:
-            q = heads("wq", n_heads_local, 2 * d_head)
-            q, gate = q[..., :d_head], q[..., d_head:]
-        else:
-            q = heads("wq", n_heads_local)
-        k, v = heads("wk", kv_local), heads("wv", kv_local)
-        if cfg.qk_norm:
-            q = _rms_norm(q, layer["q_norm"], offset)
-            k = _rms_norm(k, layer["k_norm"], offset)
-        if rope_theta:
-            q = _rope(q, rope_theta, cfg.rope_fraction)
-            k = _rope(k, rope_theta, cfg.rope_fraction)
-        if cfg.indexer:
-            ix = cfg.indexer
-            hs = lax.stop_gradient(h)
-            with jax.named_scope("attn.indexer"):
-                qi = (hs @ layer["idx_wq"].astype(cfg.dtype)).reshape(
-                    B, T, ix.n_heads, ix.d_head)
-                ki = _layer_norm(hs @ layer["idx_wk"].astype(cfg.dtype),
-                                 layer["idx_k_scale"], layer["idx_k_bias"])
-                if cfg.rope_theta:
-                    qi, ki = _rope(qi, cfg.rope_theta), _rope(ki,
-                                                              cfg.rope_theta)
-                w = (hs @ layer["idx_ww"].astype(cfg.dtype)).astype(
-                    jnp.float32) * (ix.n_heads * ix.d_head) ** -0.5
-            attn, extras["kl_sum"], extras["kl"], mask = dsa_attention(
-                q, k, v, qi, ki, w, topk=ix.topk, backend=cfg.attn_backend)
-            if with_masks:
-                extras["mask"] = mask
-        else:
-            group = n_heads_local // kv_local
-            qkv = (q, jnp.repeat(k, group, axis=2),
-                   jnp.repeat(v, group, axis=2))
-            attn = flash_attention(*qkv, causal=True,
-                                   backend=cfg.attn_backend,
-                                   fallback=window is None, window=window)
-            if with_masks:
-                extras["attn_in"], extras["attn_o"] = qkv, attn
-        if cfg.attn_gate:
-            attn = _output_gate(attn, gate)
-        return attn.astype(cfg.dtype).reshape(B, T, n_heads_local * d_head)
-
-    def _gdn_mixer(li, layer, h, extras):
-        """The Gated DeltaNet mixer: h [B, T, D] -> [B, T, D]."""
-        from ..ops.causal_conv import causal_conv_silu
-        from ..ops.gated_delta import gated_delta_rule, record_saved
-        g, f32 = cfg.gdn, jnp.float32
-        B, T, _ = h.shape
-        nk, nv = g.n_k_heads * g.d_k, g.n_v_heads * g.d_v
-        with jax.named_scope("gdn.proj"):
-            qkvz = h @ layer["gdn_wqkvz"].astype(cfg.dtype)
-            ba = (h @ layer["gdn_wba"].astype(cfg.dtype)).astype(f32)
-        # What lies between the projections and the rule, and between the
-        # rule and the output projection, is elementwise over 12288
-        # columns a row: it is recomputed in the backward from the
-        # projections' outputs (taken whole: a slice would be a copy; the
-        # convolution reads its 2 nk + nv columns of qkvz where they lie),
-        # not saved (3.4 GB over three layers at 2 x 8192 rows, compiled
-        # for a v5e).
-        @jax.checkpoint
-        def conv_and_gates(qkvz, ba, taps, a_log, dt_bias):
-            with jax.named_scope("gdn.conv"):
-                qkv = causal_conv_silu(qkvz, taps, backend=g.backend,
-                                       layer=li)
-            with jax.named_scope("gdn.scan"):
-                q = qkv[..., :nk].reshape(B, T, g.n_k_heads, g.d_k)
-                k = qkv[..., nk:2 * nk].reshape(B, T, g.n_k_heads, g.d_k)
-                v = qkv[..., 2 * nk:].reshape(B, T, g.n_v_heads, g.d_v)
-                beta = jax.nn.sigmoid(ba[..., :g.n_v_heads])
-                decay = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
-                    ba[..., g.n_v_heads:] + dt_bias.astype(f32))
-                return (_l2_norm(q) * g.d_k ** -0.5, _l2_norm(k), v, decay,
-                        beta)
-
-        @jax.checkpoint
-        def gated_norm(o, qkvz, scale):
-            z = qkvz[..., 2 * nk + nv:].reshape(B, T, g.n_v_heads, g.d_v)
-            o = _rms_norm(o.astype(f32), scale) * jax.nn.silu(z.astype(f32))
-            return o.astype(cfg.dtype).reshape(B, T, nv)
-
-        q, k, v, decay, beta = conv_and_gates(
-            qkvz, ba, layer["gdn_conv"], layer["gdn_a_log"],
-            layer["gdn_dt_bias"])
-        with jax.named_scope("gdn.scan"):
-            record_saved(li, q.shape, g.n_v_heads, g.d_v,
-                         jnp.dtype(cfg.dtype).itemsize, g.chunk)
-            # Each key head serves n_v_heads / n_k_heads consecutive value
-            # heads (inside the rule).
-            o = gated_delta_rule(q, k, v, decay, beta, chunk=g.chunk,
-                                 backend=g.backend, layer=li)
-            if with_masks:
-                extras["gdn_o"] = o
-        with jax.named_scope("gdn.out"):
-            return gated_norm(o, qkvz, layer["gdn_norm"]) \
-                @ layer["gdn_wout"].astype(cfg.dtype)
-
-    def _kda_mixer(li, layer, h, extras):
-        """The Kimi Delta Attention mixer: h [B, T, D] -> [B, T, D]."""
-        from ..ops.causal_conv import causal_conv_silu
-        from ..ops.gated_delta import gated_delta_rule, record_saved
-        a, f32 = cfg.kda, jnp.float32
-        B, T, _ = h.shape
-        H, dh = a.n_heads, a.d_head
-        n = H * dh
-
-        def dot(x, w):
-            return x @ layer[w].astype(cfg.dtype)
-        with jax.named_scope("kda.proj"):
-            qkv = dot(h, "kda_wqkv")
-            f_low, g_low = dot(h, "kda_wf_down"), dot(h, "kda_wg_down")
-            b = dot(h, "kda_wbeta").astype(f32)
-        # As in the "gdn" mixer, what is elementwise between a projection
-        # and the rule, and between the rule and the output projection, is
-        # recomputed in the backward. The low-rank pairs' second halves
-        # are in there too: what is kept of the decay and of the output
-        # gate is their d_head-wide first half, not H x d_head columns
-        # a row.
-        @jax.checkpoint
-        def conv_and_gates(qkv, f_low, b, taps, wf_up, a_log, dt_bias):
-            with jax.named_scope("kda.proj"):
-                f = (f_low @ wf_up.astype(cfg.dtype)).astype(f32)
-            with jax.named_scope("kda.conv"):
-                qkv = causal_conv_silu(qkv, taps, backend=a.backend,
-                                       layer=li)
-            with jax.named_scope("kda.scan"):
-                q, k, v = (qkv[..., j * n:(j + 1) * n].reshape(B, T, H, dh)
-                           for j in range(3))
-                decay = -jnp.exp(a_log.astype(f32))[:, None] \
-                    * jax.nn.softplus(f.reshape(B, T, H, dh)
-                                      + dt_bias.astype(f32).reshape(H, dh))
-                return (_l2_norm(q) * dh ** -0.5, _l2_norm(k), v, decay,
-                        jax.nn.sigmoid(b))
-
-        @jax.checkpoint
-        def gated_norm(o, g_low, wg_up, scale):
-            with jax.named_scope("kda.proj"):
-                gate = (g_low @ wg_up.astype(cfg.dtype)).astype(f32)
-            with jax.named_scope("kda.out"):
-                o = _rms_norm(o.astype(f32), scale, eps=eps) \
-                    * jax.nn.sigmoid(gate.reshape(B, T, H, dh))
-                return o.astype(cfg.dtype).reshape(B, T, n)
-
-        q, k, v, decay, beta = conv_and_gates(
-            qkv, f_low, b, layer["kda_conv"], layer["kda_wf_up"],
-            layer["kda_a_log"], layer["kda_dt_bias"])
-        with jax.named_scope("kda.scan"):
-            record_saved(li, q.shape, H, dh, jnp.dtype(cfg.dtype).itemsize,
-                         a.chunk, per_channel=True)
-            o = gated_delta_rule(q, k, v, decay, beta, chunk=a.chunk,
-                                 backend=a.backend, layer=li)
-            if with_masks:
-                extras["kda_in"], extras["kda_o"] = (q, k, v, decay, beta), o
-        out = gated_norm(o, g_low, layer["kda_wg_up"], layer["kda_norm"])
-        with jax.named_scope("kda.out"):
-            return dot(out, "kda_wout")
-
-    def _mla_mixer(layer, h, extras):
-        """Latent attention: h [B, T, D] -> [B, T, D].
-        Never the [T, T] scores: a shape the flash kernels cannot tile is
-        an error under ``attn_backend="pallas"``."""
-        from ..ops.pallas_attention import flash_attention
-        m, H = cfg.mla, cfg.n_heads
-        B, T, _ = h.shape
-
-        def dot(x, w):
-            return x @ layer[w].astype(cfg.dtype)
-        with jax.named_scope("attn.mla"):
-            q = dot(h, "mla_wq").reshape(B, T, H, m.d_nope + m.d_shared)
-            latent = dot(h, "mla_wkva")
-            kv = dot(_rms_norm(latent[..., :m.kv_rank], layer["mla_kv_norm"],
-                               offset, eps), "mla_wkvb").reshape(
-                B, T, H, m.d_nope + m.d_v)
-            shared = latent[:, :, None, m.kv_rank:]
-            if m.rope_theta:
-                # The shared key part is rotated ONCE a token, before it
-                # goes to the heads: its gradient is summed over the heads
-                # first.
-                with jax.named_scope("mla.rope"):
-                    q = jnp.concatenate(
-                        [q[..., :m.d_nope],
-                         _rope(q[..., m.d_nope:], m.rope_theta)], axis=-1)
-                    shared = _rope(shared, m.rope_theta)
-            # One shared key part a token, the same for every head.
-            shared = jnp.broadcast_to(shared, (B, T, H, m.d_shared))
-            k = jnp.concatenate([kv[..., :m.d_nope], shared], axis=-1)
-            v = kv[..., m.d_nope:]
-            o = flash_attention(q, k, v, causal=True,
-                                backend=cfg.attn_backend, fallback=False)
-            if with_masks:
-                extras["mla_in"], extras["mla_o"] = (q, k, v), o
-            return dot(o.astype(cfg.dtype).reshape(B, T, H * m.d_v),
-                       "mla_wo")
 
     def _layer_fwd(li, layer, x):
         extras = {}
         h = _rms_norm(x, layer["ln1"], offset, eps)
-        kind = layer_kind(cfg, li)
-        if kind == "gdn":
-            proj = _gdn_mixer(li, layer, h, extras)
-        elif kind == "kda":
-            proj = _kda_mixer(li, layer, h, extras)
-        elif kind == "mla":
-            proj = _mla_mixer(layer, h, extras)
-        elif kind == "swa":
-            from ..ops.pallas_attention import record_tile_pairs
-            record_tile_pairs(li, h.shape[1], cfg.swa.window)
-            with jax.named_scope("attn.swa"):
-                proj = _projected_attention(
-                    layer, h, extras, cfg.swa.rope_theta,
-                    cfg.swa.window) @ layer["wo"].astype(cfg.dtype)
-        else:
-            # Among layers of several kinds the softmax layer has a scope
-            # of its own.
-            with (jax.named_scope("attn.full") if cfg.layer_pattern
-                  else contextlib.nullcontext()):
-                proj = _attention(layer, h, extras) \
-                    @ layer["wo"].astype(cfg.dtype)
-            if has_tp:
-                proj = lax.psum(proj, "tp")           # row-parallel combine
+        proj = _KINDS[layer_kind(cfg, li)].forward(li, layer, h, extras, ctx)
         if cfg.post_norms:
             proj = _rms_norm(proj, layer["post_ln1"], offset, eps)
         x = x + proj
@@ -944,23 +979,23 @@ def _forward_layers(params, tokens, cfg: TransformerConfig, mesh: Mesh,
             y = y.reshape(B, T, cfg.d_model)
             if cfg.shared_expert_ff:
                 y = y + shared_expert(layer, h, cfg.dtype)
-            if cfg.post_norms:
-                y = _rms_norm(y, layer["post_ln2"], offset, eps)
-            return x + y, extras
-        # Among expert layers a dense feed-forward has a scope of its own.
-        with (jax.named_scope("ffn.dense") if cfg.dense_layers
-              else contextlib.nullcontext()):
-            up = h @ layer[w_up].astype(cfg.dtype)
-            if gated:
-                up = jax.nn.silu(h @ layer["w_gate"].astype(cfg.dtype)) * up
-            else:
-                up = jax.nn.gelu(up)
-            down = up @ layer[w_down].astype(cfg.dtype)
-        if has_tp:
-            down = lax.psum(down, "tp")
+        else:
+            # Among expert layers a dense feed-forward has a scope of its
+            # own.
+            with (jax.named_scope("ffn.dense") if cfg.dense_layers
+                  else contextlib.nullcontext()):
+                up = h @ layer[w_up].astype(cfg.dtype)
+                if gated:
+                    up = jax.nn.silu(h @ layer["w_gate"].astype(cfg.dtype)) \
+                        * up
+                else:
+                    up = jax.nn.gelu(up)
+                y = up @ layer[w_down].astype(cfg.dtype)
+            if ctx.has_tp:
+                y = lax.psum(y, "tp")
         if cfg.post_norms:
-            down = _rms_norm(down, layer["post_ln2"], offset, eps)
-        return x + down, extras
+            y = _rms_norm(y, layer["post_ln2"], offset, eps)
+        return x + y, extras
 
     def _layer(li):
         fwd = functools.partial(_layer_fwd, li)
@@ -1156,8 +1191,7 @@ def _check_dense(cfg: TransformerConfig, what: str):
             f"{what} supports dense FFNs only (cfg.n_experts="
             f"{cfg.n_experts}); the MoE dispatch has no incremental-decode "
             f"path yet")
-    if not (_packed_qkv(cfg) and cfg.mlp == "gelu" and cfg.tied_head) \
-            or _extended(cfg):
+    if _keys_split(cfg) != (4, 6):     # the dense block's keys alone
         raise NotImplementedError(
             f"{what} runs the dense block only (packed wqkv, GELU, tied "
             f"head): grouped key/value heads, q/k norm, RoPE, a gated "

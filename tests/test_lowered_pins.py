@@ -1,4 +1,4 @@
-"""The lowered training step of the four expert-layer cells, for a
+"""The lowered training step of the five expert-layer cells, for a
 described v5e, hashed by ``benchmarks/lowered_sha.py`` with the kernels'
 debug locations taken out: what PR 37 left, which made the expert layer's
 chunks after the first a loop whose trip count is the load, forward and
@@ -49,7 +49,13 @@ LATENT = {
     "kanana2_mla_train_8k_1chip":
         "a8232a5937ea6a2f53d8eda45bfb40e5a737558a1a6afc64df91a62439610e1f",
 }
-LOWERED = {**HYBRID, **KEYE, **LATENT}
+# The Trinity cell: three window layers to one full layer, as it lowered
+# on commit f3d735e, before the layer kinds became one table.
+WINDOWED = {
+    "trinity_swa_train_8k_1chip":
+        "73034e664479c1331e6c529c7d007e4bc9b8339257d5f477620f180b6592a131",
+}
+LOWERED = {**HYBRID, **KEYE, **LATENT, **WINDOWED}
 
 
 @pytest.fixture(scope="module")
